@@ -8,7 +8,7 @@ sys.path.insert(0, ".")
 def main():
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     from paddle_tpu._testing import force_cpu
-    force_cpu(pop_tpu=True)
+    force_cpu()
     import numpy as np
     import jax
     import jax.numpy as jnp

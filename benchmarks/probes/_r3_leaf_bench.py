@@ -10,7 +10,7 @@ import numpy as np
 
 def parity():
     from paddle_tpu._testing import force_cpu
-    force_cpu(pop_tpu=True)
+    force_cpu()
     import jax
     import jax.numpy as jnp
     from paddle_tpu.models.gpt import GPTConfig

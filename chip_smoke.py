@@ -1,0 +1,512 @@
+"""Chip smoke: the quickest proof that the main path still starts on a TPU.
+
+    python chip_smoke.py            # one chip
+    python chip_smoke.py --chips 4  # one host, four chips, one process
+
+Drives GPT-3 1.3B (h2048, L24, 16x128 heads, vocab 50304, bf16, random
+seeded weights) through the entry points a user calls:
+
+* kernels — every in-tree Pallas attention kernel, forward and backward,
+  compiled for the chip at the shape of the regime it is routed for and
+  compared with a float32 ``jax.numpy`` attention;
+* train   — ``gpt_hybrid.setup`` + a few steps at B4xS1024 on a fixed batch;
+* serve   — ``ContinuousBatchingSession`` answering sixteen requests, with
+  request 0 checked against ``DecodeSession.generate``.
+
+A phase that fails raises; nothing is caught and summarised. Without a TPU
+backend the script exits non-zero before building anything — there is no CPU
+branch. ``tests/test_chip_smoke.py`` drives the same phase functions at tiny
+size on the CPU mesh. No number printed here is a benchmark.
+
+The last line of stdout is one JSON object:
+``{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}``.
+"""
+from __future__ import annotations
+
+import argparse
+import gc
+import json
+import math
+import os
+import sys
+import time
+from importlib import metadata
+
+
+#: lax.scan unroll of the 24-layer stack in the full-width train phases
+#: (and bench.py's flagship rung): the full unroll compiles in ~70 s and
+#: fits the chip — CHANGES.md, PR 21
+SCAN_UNROLL = 24
+
+
+# --------------------------------------------------------------- reporting
+
+def device_report():
+    """Print what is installed and attached; return the device dict of the
+    final JSON line. Creates the backend."""
+    import jax
+    import jaxlib
+
+    try:
+        libtpu = metadata.version("libtpu")
+    except metadata.PackageNotFoundError:
+        libtpu = "not installed"
+    dev = jax.devices()[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(jax.devices())}
+    print(f"[smoke] jax={jax.__version__} jaxlib={jaxlib.__version__} "
+          f"libtpu={libtpu} default_backend={jax.default_backend()} "
+          f"device_kind={dev.device_kind!r} device_count={device['count']}",
+          flush=True)
+    return device
+
+
+def _attn_counters(delta):
+    """attn.* counter movement of one obs.window(), as {label: count}."""
+    out = {}
+    for c in delta.changed():
+        if c["name"].startswith("attn."):
+            labels = ",".join(f"{k}={v}" for k, v in
+                              sorted(c["labels"].items()))
+            out[f"{c['name']}{{{labels}}}"] = int(c["value"])
+    return out
+
+
+def _peak_bytes(device):
+    """(peak_bytes_in_use, peak_bytes_reserved) of the process so far, or
+    (None, None) where the backend reports nothing. On the v5e runtime
+    live arrays are counted under *in_use* and a running program's
+    temporaries under *reserved*; their sum is what must fit the chip."""
+    stats = device.memory_stats() or {}
+    return stats.get("peak_bytes_in_use"), stats.get("peak_bytes_reserved")
+
+
+# ------------------------------------------------------------------ kernels
+
+#: (kernel, [B,H,S,D], causal, (block_q, block_kv) or None) — the shapes of
+#: the regime each kernel is routed for (flash_attention_maybe's chain)
+KERNEL_CASES = (
+    ("simple", (4, 16, 1024, 128), True, None),
+    ("causal_skip", (4, 8, 2048, 128), True, None),
+    ("qblock", (4, 8, 2048, 128), False, None),
+    ("blocked", (2, 8, 4096, 128), True, (512, 512)),
+    ("blocked", (2, 8, 4096, 128), False, (512, 512)),
+    ("blocked", (2, 8, 4096, 128), True, (256, 512)),
+    ("blocked", (2, 8, 4096, 128), False, (256, 512)),
+    ("blocked", (2, 8, 4096, 128), True, (512, 1024)),
+    ("blocked", (2, 8, 4096, 128), False, (512, 1024)),
+    ("library_flash", (2, 8, 4096, 128), True, None),
+)
+
+
+def _kernel_fn(name, causal, blocks, interpret):
+    """The kernel under its own entry point, in [B,H,S,D] layout."""
+    import importlib
+
+    import jax.numpy as jnp
+
+    if name == "library_flash":
+        if interpret:
+            raise ValueError("the library flash wrapper has no interpret "
+                             "mode; leave it out of interpret runs")
+        from paddle_tpu.ops.pallas.flash_attention import flash_attention
+
+        def run(q, k, v):           # wrapper layout is [B,S,H,D]
+            qt, kt, vt = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
+            return jnp.swapaxes(
+                flash_attention(qt, kt, vt, causal=causal), 1, 2)
+        return run
+    module = {"simple": "simple_attention", "causal_skip": "causal_attention",
+              "qblock": "simple_attention2", "blocked": "blocked_flash"}[name]
+    m = importlib.import_module(f"paddle_tpu.ops.pallas.{module}")
+    kw = {"block_q": blocks[0], "block_kv": blocks[1]} if blocks else {}
+    return lambda q, k, v: m.attention_bhsd(q, k, v, causal=causal,
+                                            interpret=interpret, **kw)
+
+
+def _reference_attention(q, k, v, causal):
+    """float32 jax.numpy attention, [B,H,S,D]; HIGHEST precision so the
+    chip does not run the reference's matmuls in bf16 passes."""
+    import jax
+    import jax.numpy as jnp
+
+    hi = jax.lax.Precision.HIGHEST
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k, precision=hi) \
+        / math.sqrt(q.shape[-1])
+    if causal:
+        sq, sk = q.shape[2], k.shape[2]
+        mask = jnp.tril(jnp.ones((sq, sk), bool), sk - sq)
+        s = jnp.where(mask[None, None], s, -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v, precision=hi)
+
+
+def check_kernel(name, shape, causal, blocks, interpret=False, tol=2e-2,
+                 dtype=None):
+    """One kernel, forward and backward, against the float32 reference.
+    Errors are max-abs, normalised by the reference's max-abs; ``tol`` is
+    the bf16 tolerance. Returns the four errors; raises on a mismatch (a
+    kernel the compiler refuses raises from the call itself)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    dtype = dtype or jnp.bfloat16
+    kq, kk, kv, kw = jax.random.split(jax.random.PRNGKey(0), 4)
+    q, k, v, w = (jax.random.normal(key, shape, dtype)
+                  for key in (kq, kk, kv, kw))
+    run = _kernel_fn(name, causal, blocks, interpret)
+
+    def fwd_bwd(fn):
+        def f(q, k, v):
+            out, vjp = jax.vjp(fn, q, k, v)
+            return (out,) + vjp(w.astype(out.dtype))
+        return jax.jit(f)
+
+    got = fwd_bwd(run)(q, k, v)
+    want = fwd_bwd(lambda q, k, v: _reference_attention(q, k, v, causal))(
+        q.astype(jnp.float32), k.astype(jnp.float32),
+        v.astype(jnp.float32))
+    errs = {}
+    for label, g, r in zip(("out", "dq", "dk", "dv"), got, want):
+        g = np.asarray(g, np.float32)
+        r = np.asarray(r, np.float32)
+        if not np.all(np.isfinite(g)):
+            raise AssertionError(f"{name} {label}: non-finite values")
+        errs[label] = float(np.max(np.abs(g - r)) / np.max(np.abs(r)))
+    tag = f"{name} {list(shape)} causal={causal}" + \
+        (f" blocks={blocks}" if blocks else "")
+    print(f"[smoke] kernel {tag}: " +
+          " ".join(f"{k}={e:.2e}" for k, e in errs.items()), flush=True)
+    bad = {k: e for k, e in errs.items() if not e <= tol}
+    if bad:
+        raise AssertionError(f"kernel {tag} off its float32 reference "
+                             f"beyond {tol}: {bad}")
+    return errs
+
+
+def kernel_phase(cases=KERNEL_CASES, interpret=False, tol=2e-2, dtype=None):
+    """Every case of ``cases`` through check_kernel; the first failure
+    raises."""
+    t0 = time.perf_counter()
+    results = [check_kernel(*case, interpret=interpret, tol=tol,
+                            dtype=dtype) for case in cases]
+    print(f"[smoke] kernel phase: {len(results)} kernel variants matched "
+          f"their float32 reference in {time.perf_counter() - t0:.1f}s "
+          "(compile included)", flush=True)
+    return results
+
+
+# -------------------------------------------------------------------- train
+
+def _seeded_ids(cfg, batch, seq, seed):
+    import jax.numpy as jnp
+    import numpy as np
+    return jnp.asarray(np.random.RandomState(seed).randint(
+        0, cfg.vocab_size, (batch, seq)))
+
+
+def train_phase(cfg, batch, seq, steps, *, scan_unroll, warmup=2, dp=1,
+                tp=1, sp=False, zero1=True, devices=None,
+                expect_kernel=None, seed=0):
+    """``gpt_hybrid.setup`` + ``warmup`` + ``steps`` train steps on one fixed
+    seeded batch, bf16 params/compute/moments, remat_policy="names".
+
+    Checks: step-0 loss within 0.5 of ln(vocab); every loss finite; the last
+    loss below the first; nothing traced or compiled after warm-up; when
+    ``expect_kernel`` is given, that Pallas kernel was dispatched and no
+    attention dispatch fell back on an error. Returns a dict of what it saw.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu.observability as obs
+    from paddle_tpu.models.gpt_hybrid import ParallelConfig, setup
+
+    devices = list(devices if devices is not None else jax.devices()[:1])
+    pcfg = ParallelConfig(dp=dp, pp=1, tp=tp, sp=sp, zero1=zero1,
+                          remat=True, remat_policy="names",
+                          scan_unroll=scan_unroll,
+                          param_dtype=jnp.bfloat16,
+                          compute_dtype=jnp.bfloat16, moment_dtype=None)
+    ids = _seeded_ids(cfg, batch, seq, seed)
+    tag = f"train dp={dp} tp={tp} sp={sp} B{batch}xS{seq} " \
+          f"unroll={scan_unroll}"
+
+    with obs.window() as w:
+        mesh, params, opt_state, step = setup(cfg, pcfg, seed=seed,
+                                              devices=devices)
+        losses = []
+        with mesh:
+            t0 = time.perf_counter()
+            with obs.count_compiles() as cold:
+                params, opt_state, loss = step(params, opt_state,
+                                               (ids, ids))
+                jax.block_until_ready(loss)
+            first_step_s = time.perf_counter() - t0
+            losses.append(loss)
+            for _ in range(warmup - 1):
+                params, opt_state, loss = step(params, opt_state,
+                                               (ids, ids))
+                losses.append(loss)
+            jax.block_until_ready(loss)
+            with obs.count_compiles() as compiles, \
+                    obs.count_traces() as traces:
+                t0 = time.perf_counter()
+                for _ in range(steps):
+                    params, opt_state, loss = step(params, opt_state,
+                                                   (ids, ids))
+                    losses.append(loss)
+                jax.block_until_ready(loss)
+                steady_s = time.perf_counter() - t0
+    losses = [float(x) for x in losses]
+    dispatch = _attn_counters(w.delta)
+    peak = _peak_bytes(devices[0])
+
+    print(f"[smoke] {tag}: attention dispatch {dispatch or '{}'}")
+    print(f"[smoke] {tag}: compile {cold.seconds:.1f}s in {cold()} "
+          f"executables (first step {first_step_s:.1f}s wall)")
+    print(f"[smoke] {tag}: steady {steady_s / steps * 1e3:.1f} ms/step "
+          f"over {steps} steps (smoke observation, not a benchmark)")
+    print(f"[smoke] {tag}: peak_bytes_in_use {peak[0]} + "
+          f"peak_bytes_reserved {peak[1]} (this process, so far; None = "
+          "not reported)")
+    print(f"[smoke] {tag}: losses {[round(x, 4) for x in losses]}",
+          flush=True)
+
+    want0 = math.log(cfg.vocab_size)
+    if not all(math.isfinite(x) for x in losses):
+        raise AssertionError(f"{tag}: non-finite loss in {losses}")
+    if abs(losses[0] - want0) > 0.5:
+        raise AssertionError(f"{tag}: step-0 loss {losses[0]:.4f} is not "
+                             f"within 0.5 of ln(vocab)={want0:.4f}")
+    if not losses[-1] < losses[0]:
+        raise AssertionError(f"{tag}: loss did not fall: {losses}")
+    if compiles() or traces():
+        raise AssertionError(
+            f"{tag}: {traces()} traces / {compiles()} compiles after "
+            "warm-up (expected none)")
+    errors = {k: n for k, n in dispatch.items()
+              if k.startswith("attn.dispatch_fallback") and "error" in k}
+    if errors:
+        raise AssertionError(f"{tag}: attention dispatch errors {errors}")
+    if expect_kernel is not None and not dispatch.get(
+            f"attn.dispatch{{kernel={expect_kernel}}}"):
+        raise AssertionError(
+            f"{tag}: expected the {expect_kernel!r} Pallas kernel to be "
+            f"dispatched, saw {dispatch}")
+
+    out = {"losses": losses, "dispatch": dispatch,
+           "param_devices": len(
+               params["blocks"]["qkv_w"].sharding.device_set),
+           "bytes_in_use": [(d.memory_stats() or {}).get("bytes_in_use")
+                            for d in devices]}
+    # free the train state: the next phase needs the memory
+    del params, opt_state, step, loss
+    gc.collect()
+    jax.clear_caches()
+    return out
+
+
+def reference_step0_loss(cfg, batch, seq, seed=0):
+    """Step-0 loss of the same seeded weights and batch on ONE device,
+    forward only (no optimizer state) — what a multi-chip run's step-0 loss
+    must agree with."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.models.gpt_hybrid import (ParallelConfig, build_mesh,
+                                              init_params, loss_fn)
+
+    pcfg = ParallelConfig(dp=1, pp=1, tp=1, remat=False,
+                          param_dtype=jnp.bfloat16,
+                          compute_dtype=jnp.bfloat16)
+    mesh = build_mesh(pcfg, jax.devices()[:1])
+    params = init_params(cfg, pcfg, jax.random.PRNGKey(seed))
+    ids = _seeded_ids(cfg, batch, seq, seed)
+    with mesh:
+        loss = float(jax.jit(
+            lambda p, b: loss_fn(p, b, cfg, pcfg, mesh))(params, (ids, ids)))
+    del params
+    gc.collect()
+    jax.clear_caches()
+    return loss
+
+
+def multichip_phase(cfg, batch, seq, steps, *, scan_unroll, layouts,
+                    n_devices=4, expect_kernel=None):
+    """The train phase on ``n_devices`` chips in this process, once per
+    layout. Checks per layout: every device holds state (bytes_in_use
+    non-zero, max within 1.3x of min), a parameter leaf lives on all
+    devices, and step-0 loss agrees with the one-device reference to 1e-2.
+    """
+    import jax
+
+    devices = jax.devices()[:n_devices]
+    if len(devices) < n_devices:
+        raise RuntimeError(f"need {n_devices} devices, have {len(devices)}")
+    ref = reference_step0_loss(cfg, batch, seq)
+    print(f"[smoke] one-device reference step-0 loss (B{batch}xS{seq}): "
+          f"{ref:.4f}", flush=True)
+    results = []
+    for layout in layouts:
+        r = train_phase(cfg, batch, seq, steps, scan_unroll=scan_unroll,
+                        devices=devices, expect_kernel=expect_kernel,
+                        **layout)
+        tag = f"multichip {layout}"
+        print(f"[smoke] {tag}: bytes_in_use per device {r['bytes_in_use']}, "
+              f"param leaf on {r['param_devices']} devices, step-0 loss "
+              f"{r['losses'][0]:.4f} vs one-device {ref:.4f}", flush=True)
+        used = r["bytes_in_use"]
+        if all(u is None for u in used):
+            print(f"[smoke] {tag}: backend reports no memory stats; "
+                  "per-device bytes not checked")
+        elif not all(used) or max(used) > 1.3 * min(used):
+            raise AssertionError(f"{tag}: devices do not hold roughly "
+                                 f"equal state: {used}")
+        if r["param_devices"] != n_devices:
+            raise AssertionError(f"{tag}: parameter leaf on "
+                                 f"{r['param_devices']} devices")
+        if abs(r["losses"][0] - ref) > 1e-2:
+            raise AssertionError(f"{tag}: step-0 loss {r['losses'][0]} vs "
+                                 f"one-device {ref}")
+        results.append(r)
+    return results
+
+
+# -------------------------------------------------------------------- serve
+
+def serve_phase(cfg, *, max_slots, max_length, decode_block, n_requests,
+                prompt_range, budget_range, seed=0):
+    """A ContinuousBatchingSession over a bf16 GPTForCausalLM(cfg) answers
+    ``n_requests`` seeded requests (the first ``max_slots`` up front, the
+    rest after the first step — overlapping lifetimes).
+
+    Checks: every request ends DONE with exactly its budget;
+    serving.step_retries and serving.quarantined stay 0; request 0's greedy
+    continuation agrees with DecodeSession.generate on the same prompt at
+    token 0 (the first differing index is printed).
+    """
+    import jax
+    import numpy as np
+
+    import paddle_tpu as paddle
+    import paddle_tpu.observability as obs
+    from paddle_tpu.inference.decode import (ContinuousBatchingSession,
+                                             DecodeSession, RequestState)
+    from paddle_tpu.models.gpt import GPTForCausalLM
+
+    paddle.seed(seed)
+    model = GPTForCausalLM(cfg).bfloat16()
+    rng = np.random.RandomState(seed)
+    reqs = [(rng.randint(0, cfg.vocab_size,
+                         (int(rng.randint(*prompt_range)),)).astype(np.int32),
+             int(rng.randint(*budget_range))) for _ in range(n_requests)]
+
+    t0 = time.perf_counter()
+    with obs.count_compiles() as compiles:
+        with obs.window() as w, ContinuousBatchingSession(
+                model, max_slots=max_slots, max_length=max_length,
+                decode_block=decode_block) as cbs:
+            rids = [cbs.submit(p, b) for p, b in reqs[:max_slots]]
+            cbs.step()
+            rids += [cbs.submit(p, b) for p, b in reqs[max_slots:]]
+            results = cbs.results()
+        serve_s = time.perf_counter() - t0
+        prompt0, budget0 = reqs[0]
+        with DecodeSession(model, max_length) as ds:
+            ref = np.asarray(ds.generate(prompt0[None], budget0,
+                                         seed=seed).numpy())[0]
+
+    for rid, (prompt, budget) in zip(rids, reqs):
+        res = results[rid]
+        n_new = len(res.ids) - len(prompt)
+        if res.state is not RequestState.DONE or n_new != budget:
+            raise AssertionError(
+                f"serve: request {rid} ended {res.state} with {n_new} "
+                f"of {budget} tokens (error={res.error})")
+    retries = w.value("serving.step_retries", default=0) or 0
+    quarantined = w.value("serving.quarantined", default=0) or 0
+    got = results[rids[0]].ids
+    n0 = len(prompt0)
+    diff = next((i for i in range(budget0)
+                 if got[n0 + i] != ref[n0 + i]), None)
+    total = sum(b for _p, b in reqs)
+    print(f"[smoke] serve: {n_requests} requests DONE, {total} tokens in "
+          f"{serve_s:.1f}s wall incl. compile ({compiles()} executables, "
+          f"{compiles.seconds:.1f}s compiling; smoke observation, not a "
+          "benchmark)")
+    print(f"[smoke] serve: step_retries={int(retries)} "
+          f"quarantined={int(quarantined)}")
+    print("[smoke] serve: request 0 vs DecodeSession.generate: " +
+          (f"identical over {budget0} tokens" if diff is None
+           else f"first difference at generated token {diff} of {budget0}"),
+          flush=True)
+    if retries or quarantined:
+        raise AssertionError(f"serve: step_retries={retries} "
+                             f"quarantined={quarantined} (expected 0)")
+    if diff == 0:
+        raise AssertionError("serve: request 0 differs from "
+                             "DecodeSession.generate at token 0")
+    del model
+    gc.collect()
+    jax.clear_caches()
+    return {"requests": n_requests, "tokens": total, "first_diff": diff,
+            "serve_s": serve_s}
+
+
+# --------------------------------------------------------------------- main
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                    help="1: kernels + train + serve on one chip (default); "
+                         "4: the train path on four chips, dp=4/zero1 and "
+                         "dp=2 x tp=2 + sp")
+    args = ap.parse_args(argv)
+
+    device = device_report()
+    if device["platform"] != "tpu":
+        sys.exit(f"chip_smoke: the attached device is "
+                 f"{device['platform']!r}, not 'tpu' — this script only "
+                 "runs on a chip")
+
+    from paddle_tpu import compile_cache, native
+    from paddle_tpu.models.gpt import GPTConfig
+    from paddle_tpu.ops.pallas import autotune
+
+    cache_dir = compile_cache.enable()
+    entries0 = compile_cache.entry_count(cache_dir)
+    print(f"[smoke] compile cache {cache_dir}: {entries0} entries at start")
+    native.get_lib()
+    print(f"[smoke] native library: {native.status()}")
+    table = autotune._cache_path()
+    print(f"[smoke] attention autotune table {table}: " +
+          ("PRESENT (overrides the static chain)" if os.path.exists(table)
+           else "absent (the static chain decides)"), flush=True)
+
+    # the flagship width; bench.py's train configuration
+    cfg = GPTConfig(vocab_size=50304, hidden_size=2048, num_layers=24,
+                    num_heads=16, max_seq_len=1024)
+    if args.chips == 4:
+        multichip_phase(cfg, batch=16, seq=1024, steps=3,
+                        scan_unroll=SCAN_UNROLL, expect_kernel="simple",
+                        layouts=({"dp": 4, "zero1": True},
+                                 {"dp": 2, "tp": 2, "sp": True}))
+    else:
+        kernel_phase()
+        train_phase(cfg, batch=4, seq=1024, steps=4,
+                    scan_unroll=SCAN_UNROLL, expect_kernel="simple")
+        serve_phase(GPTConfig.gpt3_1p3b(), max_slots=8, max_length=512,
+                    decode_block=16, n_requests=16,
+                    prompt_range=(32, 128), budget_range=(64, 128))
+
+    print(f"[smoke] compile cache {cache_dir}: "
+          f"{compile_cache.entry_count(cache_dir)} entries at end "
+          f"({entries0} at start)")
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+
+
+if __name__ == "__main__":
+    main()

@@ -9,7 +9,9 @@ hardware, and (b) `profile_measure`, which times a jitted callable on
 the attached device — the measured path the reference gets from its
 benchmark table."""
 from .cost_model import (  # noqa: F401
-    CostModel, TPU_SPECS, OpCost, gpt_flops_per_token, mfu)
+    CostModel, DEVICE_KIND_TO_CHIP, TPU_SPECS, OpCost,
+    attached_chip_spec, gpt_flops_per_token, mfu, spec_for_device_kind)
 
-__all__ = ["CostModel", "TPU_SPECS", "OpCost", "gpt_flops_per_token",
-           "mfu"]
+__all__ = ["CostModel", "DEVICE_KIND_TO_CHIP", "TPU_SPECS", "OpCost",
+           "attached_chip_spec", "gpt_flops_per_token", "mfu",
+           "spec_for_device_kind"]

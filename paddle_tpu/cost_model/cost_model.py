@@ -9,14 +9,43 @@ from typing import Dict, Optional, Sequence
 
 import numpy as np
 
-# Public per-chip peak specs (bf16 matmul FLOP/s, HBM B/s, ICI B/s per
-# link). Sources: cloud.google.com/tpu/docs system-architecture pages.
+# Published per-chip peaks (bf16 matmul FLOP/s, HBM B/s, ICI B/s per
+# link), keyed by nickname for planning WITHOUT hardware
+# (distributed/planner.py). Source of every row: the Google Cloud TPU
+# documentation's system-architecture page of that generation
+# (cloud.google.com/tpu/docs/{v4,v5e,v5p,v6e}); v5e: 197 TFLOP/s bf16,
+# 819 GB/s HBM.
 TPU_SPECS: Dict[str, Dict[str, float]] = {
     "v4":  {"flops": 275e12, "hbm_bw": 1.2e12,  "ici_bw": 50e9},
     "v5e": {"flops": 197e12, "hbm_bw": 0.82e12, "ici_bw": 50e9},
     "v5p": {"flops": 459e12, "hbm_bw": 2.76e12, "ici_bw": 100e9},
     "v6e": {"flops": 918e12, "hbm_bw": 1.64e12, "ici_bw": 100e9},
 }
+
+#: ``jax.devices()[0].device_kind`` -> TPU_SPECS row, for anything that
+#: divides a MEASUREMENT by a peak. Only strings read off a real chip
+#: belong here ("TPU v5 lite": the v5e chip tool, PR 21); a kind that
+#: is missing is an error, never a default.
+DEVICE_KIND_TO_CHIP: Dict[str, str] = {
+    "TPU v5 lite": "v5e",
+}
+
+
+def spec_for_device_kind(device_kind: str) -> Dict[str, float]:
+    """Peak spec of the chip jax reports as ``device_kind``; raises
+    KeyError for a kind the table does not hold."""
+    if device_kind not in DEVICE_KIND_TO_CHIP:
+        raise KeyError(
+            f"no peak spec for device_kind {device_kind!r}; known: "
+            f"{sorted(DEVICE_KIND_TO_CHIP)}. Add the string the chip "
+            "reports to cost_model.DEVICE_KIND_TO_CHIP with its source.")
+    return TPU_SPECS[DEVICE_KIND_TO_CHIP[device_kind]]
+
+
+def attached_chip_spec() -> Dict[str, float]:
+    """Peak spec of the attached accelerator (creates the backend)."""
+    import jax
+    return spec_for_device_kind(jax.devices()[0].device_kind)
 
 
 def gpt_flops_per_token(cfg, seq_len: int) -> float:
@@ -33,9 +62,10 @@ def gpt_flops_per_token(cfg, seq_len: int) -> float:
 
 
 def mfu(tokens_per_s: float, flops_per_token: float,
-        chip: str = "v5e") -> float:
-    """Achieved model-flops utilization against one chip's bf16 peak."""
-    return tokens_per_s * flops_per_token / TPU_SPECS[chip]["flops"]
+        peak_flops: float) -> float:
+    """Achieved model-flops utilization against a bf16 peak FLOP/s
+    (``attached_chip_spec()["flops"]`` for a measurement)."""
+    return tokens_per_s * flops_per_token / peak_flops
 
 
 @dataclass

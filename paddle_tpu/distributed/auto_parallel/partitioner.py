@@ -461,7 +461,7 @@ class PipelinePartition:
         from paddle_tpu.parallel.pipeline_1f1b import (
             pipeline_train_1f1b, pipeline_train_zbh1,
             pipeline_train_zbvpp)
-        from paddle_tpu.core.compat import shard_map
+        from jax import shard_map
         blk_specs = tuple(P("pp") for _ in stacked)
         pipe_fn = {"zbh1": pipeline_train_zbh1,
                    "zbvpp": pipeline_train_zbvpp,

@@ -8,9 +8,9 @@ dist-attrs and scores programs per-op. TPU-native version: the search
 space is the hybrid-parallel config itself — (dp, tp, pp, sp, zero
 stage, remat, microbatches) over a chip mesh — and the objective is a
 roofline + ring-collective model (cost_model.CostModel) calibrated
-against this repo's own recorded bench points (BENCH_r01.json /
-NOTES.md), because on TPU the per-op scheduling the reference plans is
-owned by XLA; what's left to plan is exactly this config.
+against measured (params, tokens/sec/chip) points, because on TPU the
+per-op scheduling the reference plans is owned by XLA; what's left to
+plan is exactly this config.
 
 Use:
     spec = ModelSpec.gpt(n_params=1.3e9, layers=24, hidden=2048,
@@ -20,9 +20,10 @@ Use:
     best = plans[0]          # -> PlanCandidate(dp=8, zero=1, ...)
 
 `Planner.calibrate(points)` refits the MFU efficiency from measured
-(params, tokens/sec/chip) pairs; the default is fit from the round-1
-bench records (GPT-1.3B: 14.57k tok/s/chip, GPT-350M-class: 50k —
-0.577 / 0.533 MFU on v5e).
+(params, tokens/sec/chip) pairs; the default points (GPT-1.3B: 14.57k
+tok/s/chip, GPT-350M-class: 50k — 0.577 / 0.533 MFU on v5e) come from
+an earlier chip record, deleted in PR 21, and have not been re-measured
+on the current code.
 """
 from __future__ import annotations
 
@@ -58,10 +59,10 @@ class ModelSpec:
         return cls(float(n), L, h, cfg.num_heads, cfg.max_seq_len, v)
 
 
-#: calibration points recorded on this repo's own hardware
-#: (BENCH_r01.json driver capture + NOTES.md continuation runs); the
-#: full spec rides along so calibration charges the same FLOP formula
-#: (incl. attention) the estimator uses
+#: default calibration points (an earlier chip record, deleted in PR 21
+#: — not re-measured on the current code); the full spec rides along so
+#: calibration charges the same FLOP formula (incl. attention) the
+#: estimator uses
 _V5E_CALIBRATION = [
     # GPT-1.3B B4 S1024 remat=names fused-CE: 14.57k tok/s/chip
     (ModelSpec.gpt(1.3e9, 24, 2048, 16, 1024, 50257), 14_570.0),
@@ -90,7 +91,8 @@ class PlanCandidate:
         the GSPMD engine runs the ring via a top-level tp shard_map;
         at pp>1 it rides the manual-tp stage body (round 5 —
         models/gpt_manual_tp.py; the nested-region formulation stays
-        Shardy-walled, benchmarks/probes/_cm_repro.py). Consumed by
+        Shardy-walled — the canary test_cm_under_pp_upstream_wall in
+        tests/test_collective_matmul.py). Consumed by
         to_parallel_config()."""
         return self.sp and self.tp > 1
 

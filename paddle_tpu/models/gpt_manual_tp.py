@@ -6,9 +6,9 @@ device-varying pipeline-stage predicates. With tp left GSPMD-auto, the
 partitioner inserts tp collectives INSIDE those branches with replica
 groups of its choosing — which deadlocks the mesh (round-4 finding:
 half the devices wait at the in-branch collective, half at the ring
-permute). Round 5 established (benchmarks/probes/_r5_cond_collective_probe.py,
-benchmarks/probes/_r5_zb_tp_derisk.py) that EXPLICIT collectives over a
-manual 'tp' axis are safe inside those branches: the predicate varies
+permute). Round 5 established (tests/test_pipeline_zb_tp.py pins it)
+that EXPLICIT collectives over a manual 'tp' axis are safe inside
+those branches: the predicate varies
 only over 'pp', so every member of a tp subgroup takes the same branch
 and the collective's participants always rendezvous.
 
@@ -35,10 +35,10 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax import shard_map as _shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .gpt import GPTConfig
-from paddle_tpu.core.compat import shard_map as _shard_map
 
 
 # ------------------------- block (manual tp) -------------------------
@@ -67,7 +67,7 @@ def block_manual_tp(x, lp, cfg: GPTConfig, pcfg, tp_axis="tp"):
     — tp is ALREADY manual here, so no nested region and no Shardy
     wall: this is how collective-matmul overlap reaches pp>1, closing
     the round-4 'cm under pp' hole; the GSPMD engines' nested
-    formulation stays walled, see benchmarks/probes/_cm_repro.py).
+    formulation stays walled, see gpt_hybrid._use_cm).
     All collectives are explicit and legal inside the zero-bubble
     cond-gated phases (tp-uniform predicates).
     """
@@ -77,9 +77,9 @@ def block_manual_tp(x, lp, cfg: GPTConfig, pcfg, tp_axis="tp"):
     # lowers to ONE collective-permute spanning the whole mesh (the tp
     # pairs of every pp row merged into a single op), so inside a
     # cond-gated zero-bubble phase the idle pp stages never arrive and
-    # the op cross-matches or deadlocks (round-5 probe:
-    # benchmarks/probes/_r5_cond_collective_probe.py leg E). psum/all_gather/
-    # psum_scatter lower to SUBGROUP replica_groups and stay legal.
+    # the op cross-matches or deadlocks (round-5 finding).
+    # psum/all_gather/psum_scatter lower to SUBGROUP replica_groups and
+    # stay legal.
     cm = bool(pcfg.collective_matmul) and sp \
         and pcfg.pp_schedule == "1f1b"
     nh_local = cfg.num_heads // pcfg.tp

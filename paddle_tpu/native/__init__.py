@@ -1,12 +1,14 @@
 """Native host runtime bindings (ctypes over paddle_native.cc).
 
 Builds the shared library on first use with g++ (cached next to the
-source); all entry points degrade gracefully to numpy when the toolchain
-or library is unavailable.
+source, keyed on a hash of ``src/*.cc``); all entry points degrade to
+numpy when the toolchain or library is unavailable, and ``status()``
+says which of the two is in use and why.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -16,6 +18,9 @@ import numpy as np
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC_DIR = os.path.join(_DIR, "src")
 _SO = os.path.join(_DIR, "libpaddle_native.so")
+#: hash of the sources the library beside it was built from (mtimes do
+#: not survive a copied or freshly checked-out tree)
+_SO_HASH = _SO + ".srchash"
 
 
 def _sources():
@@ -24,20 +29,57 @@ def _sources():
         if f.endswith(".cc"))
 
 
+def _source_hash(srcs) -> str:
+    h = hashlib.sha256()
+    for path in srcs:
+        h.update(os.path.basename(path).encode())
+        with open(path, "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()
+
+
 _lib = None
 _lib_failed = False  # cache build/load failure: don't retry every call
+_status = "not loaded"
 _lock = threading.Lock()
 
 
-def _build():
+def _build(srcs, src_hash):
+    # build beside the target and rename: a concurrent process never
+    # loads a half-written library
+    tmp = f"{_SO}.{os.getpid()}.tmp"
     cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread",
-           *_sources(), "-o", _SO]
-    subprocess.run(cmd, check=True, capture_output=True)
+           *srcs, "-o", tmp]
+    try:
+        subprocess.run(cmd, check=True, capture_output=True)
+        os.replace(tmp, _SO)
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
+    with open(_SO_HASH, "w") as f:
+        f.write(src_hash)
+
+
+def _built_hash():
+    try:
+        with open(_SO_HASH) as f:
+            return f.read().strip()
+    except OSError:
+        return None
+
+
+def status() -> str:
+    """How the native library came to be in this process: ``built``
+    (compiled now), ``loaded`` (an up-to-date build was found),
+    ``unavailable: <why>`` (the numpy paths are in use) or ``not
+    loaded`` (nothing asked for it yet)."""
+    return _status
 
 
 def get_lib():
-    """Load (building if needed) the native library; None if unavailable."""
-    global _lib, _lib_failed
+    """Load (building if needed) the native library; None if
+    unavailable — ``status()`` then carries the reason."""
+    global _lib, _lib_failed, _status
     if _lib is not None or _lib_failed:
         return _lib
     with _lock:
@@ -45,14 +87,20 @@ def get_lib():
             return _lib
         try:
             srcs = _sources()
-            if not os.path.exists(_SO) or any(
-                    os.path.getmtime(_SO) < os.path.getmtime(s)
-                    for s in srcs):
-                _build()
+            src_hash = _source_hash(srcs)
+            if os.path.exists(_SO) and _built_hash() == src_hash:
+                how = "loaded"
+            else:
+                _build(srcs, src_hash)
+                how = "built"
             lib = ctypes.CDLL(_SO)
-        except Exception:
+        except (OSError, subprocess.CalledProcessError) as e:
             _lib_failed = True
+            detail = e.stderr.decode(errors="replace")[-500:] \
+                if getattr(e, "stderr", None) else str(e)
+            _status = f"unavailable: {type(e).__name__}: {detail}"
             return None
+        _status = how
         lib.pn_collate.argtypes = [
             ctypes.POINTER(ctypes.c_void_p), ctypes.c_int64,
             ctypes.c_void_p, ctypes.c_int64, ctypes.c_int32]

@@ -48,20 +48,18 @@ def _xla_attention(q, k, v, mask=None, causal=False, scale=None,
 
 
 def _maybe_pallas_attention(q, k, v, causal, scale):
-    """Use the Pallas flash kernel when on TPU and shapes are tile-friendly."""
-    try:
-        if q.dtype not in (jnp.float32, jnp.bfloat16):
-            return None
-        if jax.default_backend() != "tpu":
-            return None
-        if q.shape[1] % 128 != 0 or k.shape[1] % 128 != 0:
-            return None
-        if q.shape[-1] not in (64, 128, 256):
-            return None
-        from paddle_tpu.ops.pallas.flash_attention import flash_attention
-        return flash_attention(q, k, v, causal=causal, scale=scale)
-    except Exception:
+    """Use the Pallas flash kernel when on TPU and shapes are tile-friendly
+    (None = gated off; a kernel error raises)."""
+    if q.dtype not in (jnp.float32, jnp.bfloat16):
         return None
+    if jax.default_backend() != "tpu":
+        return None
+    if q.shape[1] % 128 != 0 or k.shape[1] % 128 != 0:
+        return None
+    if q.shape[-1] not in (64, 128, 256):
+        return None
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+    return flash_attention(q, k, v, causal=causal, scale=scale)
 
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None,

@@ -136,6 +136,9 @@ CATALOG = {
     "attn.dispatch_fallback": _m(
         "counter", "shape-gate rejections falling back to XLA",
         ("reason",)),
+    "attn.autotune_candidate_errors": _m(
+        "counter", "autotune candidates the compiler or runtime "
+        "refused (text kept in the table entry)", ("kernel",)),
     # ------------------------------------------------------ autotuner
     "autotuner.trials": _m("counter",
                            "auto-tuner candidates measured", ("source",)),

@@ -1,22 +1,22 @@
-"""Compile-cache tracking over jax's jit internals.
+"""Compile-cache tracking over ``jax.monitoring``'s public events.
 
-Two services:
+jax records a duration event every time it traces a jitted function to
+a jaxpr (``/jax/core/compile/jaxpr_trace_duration``) and every time it
+asks the backend for an executable
+(``/jax/core/compile/backend_compile_duration`` — fired on in-memory
+jit-cache MISSES only; a hit in the persistent on-disk cache still
+fires, with the retrieval time as its duration). Two services ride on
+them:
 
-1. A persistent, transparent hook around XLA compilation
-   (``install()``, idempotent) that counts every executable build into
-   the metrics registry — ``jit.xla_compiles`` — so a production run
-   can answer "how many recompiles so far?" from ``dump()`` alone.
+1. ``install()`` (idempotent, called at ``import observability``):
+   a process-lifetime listener that counts every executable build into
+   ``jit.xla_compiles``, so a production run can answer "how many
+   recompiles so far?" from ``dump()`` alone.
 
 2. ``count_compiles()`` / ``count_traces()`` context managers yielding
-   a CALLABLE count, replacing the drifted
-   ``jax._src.test_util.count_jit_compilation_cache_miss`` API the
-   perf-gate tests were written against (that helper now yields a bare
-   list on this jax, so ``compiles()`` raises TypeError). The
-   mechanism mirrors jtu's: wrap ``pxla._cached_compilation`` for
-   compile events and re-``lu.cache``-wrap ``_create_pjit_jaxpr`` for
-   tracing-cache misses, restoring the original on exit. Nesting with
-   the persistent hook (or with jtu's own counters) composes — each
-   layer delegates to whatever callable it captured.
+   a CALLABLE count (``with count_compiles() as c: ...; assert c() ==
+   0``); the count object also carries ``.seconds``, the summed event
+   durations — the compile time chip_smoke.py reports.
 
 Per-FUNCTION compile/cache-hit accounting lives in
 ``paddle_tpu.jit.StaticFunction`` (calls / probes / graph breaks /
@@ -28,92 +28,69 @@ from __future__ import annotations
 import threading
 from contextlib import contextmanager
 
+from jax import monitoring
+
 from . import metrics as _met
+
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+JAXPR_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
 
 _install_lock = threading.Lock()
 _installed = False
 
 
 class _Count:
-    """Callable current-count (the pre-drift jtu contract: tests do
-    ``with count_compiles() as c: ...; assert c() == 0``)."""
+    """Callable current-count plus the summed event seconds."""
 
-    __slots__ = ("n",)
+    __slots__ = ("n", "seconds")
 
     def __init__(self):
         self.n = 0
+        self.seconds = 0.0
 
     def __call__(self) -> int:
         return self.n
 
 
-def _pxla():
-    from jax._src.interpreters import pxla
-    return pxla
-
-
 def install() -> None:
-    """Wrap XLA compilation once; every compile increments
-    ``jit.xla_compiles`` (when metrics are enabled). Safe to call from
-    import paths — failures (jax internals moved) are swallowed and
-    the registry simply never sees the counter."""
+    """Register the ``jit.xla_compiles`` listener once."""
     global _installed
     with _install_lock:
         if _installed:
             return
-        try:
-            pxla = _pxla()
-            orig = pxla._cached_compilation
-            ctr = _met.REGISTRY.counter("jit.xla_compiles")
+        ctr = _met.REGISTRY.counter("jit.xla_compiles")
 
-            def compile_and_count(*args, **kwargs):
-                if _met._ENABLED:
-                    ctr.inc()
-                return orig(*args, **kwargs)
+        def on_duration(event, duration_secs, **_kw):
+            if event == BACKEND_COMPILE_EVENT and _met._ENABLED:
+                ctr.inc()
 
-            pxla._cached_compilation = compile_and_count
-            _installed = True
-        except Exception:
-            pass
+        monitoring.register_event_duration_secs_listener(on_duration)
+        _installed = True
 
 
 @contextmanager
+def _count_event(name):
+    count = _Count()
+
+    def on_duration(event, duration_secs, **_kw):
+        if event == name:
+            count.n += 1
+            count.seconds += duration_secs
+
+    monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        yield count
+    finally:
+        monitoring.unregister_event_duration_listener(on_duration)
+
+
 def count_compiles():
-    """Count XLA executable builds (jit compilation-cache misses)
-    within the context; yields a callable returning the count."""
-    pxla = _pxla()
-    orig = pxla._cached_compilation
-    count = _Count()
-
-    def compile_and_count(*args, **kwargs):
-        count.n += 1
-        return orig(*args, **kwargs)
-
-    pxla._cached_compilation = compile_and_count
-    try:
-        yield count
-    finally:
-        pxla._cached_compilation = orig
+    """Count XLA executable builds (in-memory jit-cache misses) within
+    the context; yields a callable returning the count."""
+    return _count_event(BACKEND_COMPILE_EVENT)
 
 
-@contextmanager
 def count_traces():
-    """Count jit tracing-cache misses (retraces) within the context;
-    yields a callable returning the count. Repeat calls that hit the
-    tracing cache do not count — the wrapper is itself lu.cache'd,
-    exactly like the jax test-util original."""
-    from jax._src import pjit as pjit_lib
-    from jax._src import linear_util as lu
-    orig = pjit_lib._create_pjit_jaxpr
-    count = _Count()
-
-    @lu.cache
-    def create_pjit_jaxpr_and_count(*args):
-        count.n += 1
-        return orig(*args)
-
-    pjit_lib._create_pjit_jaxpr = create_pjit_jaxpr_and_count
-    try:
-        yield count
-    finally:
-        pjit_lib._create_pjit_jaxpr = orig
+    """Count jit traces (tracing-cache misses) within the context;
+    yields a callable returning the count."""
+    return _count_event(JAXPR_TRACE_EVENT)

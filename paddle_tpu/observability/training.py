@@ -11,20 +11,20 @@ from __future__ import annotations
 from typing import Optional
 
 from . import metrics as _met
-from paddle_tpu.cost_model import TPU_SPECS as _TPU_SPECS
-
-#: bf16 peak FLOP/s of one v5e chip — bench.py's MFU denominator
-DEFAULT_PEAK_FLOPS = _TPU_SPECS["v5e"]["flops"]
+from paddle_tpu.cost_model import attached_chip_spec as _attached_chip_spec
 
 _flops_per_token: Optional[float] = None
-_peak_flops: float = DEFAULT_PEAK_FLOPS
+#: None = the attached device's bf16 peak, looked up by device_kind on
+#: the first MFU computation (an unknown device raises there)
+_peak_flops: Optional[float] = None
 
 
 def configure(flops_per_token: Optional[float] = None,
               peak_flops: Optional[float] = None) -> None:
     """Declare the model's cost so record_step can derive MFU.
     flops_per_token: e.g. cost_model.gpt_flops_per_token(cfg, seq);
-    peak_flops: accelerator peak (default: one v5e chip bf16)."""
+    peak_flops: accelerator peak (default: the attached device's, via
+    cost_model.attached_chip_spec)."""
     global _flops_per_token, _peak_flops
     if flops_per_token is not None:
         _flops_per_token = float(flops_per_token)
@@ -35,6 +35,7 @@ def configure(flops_per_token: Optional[float] = None,
 def record_step(dt_s: float, samples: Optional[int] = None,
                 tokens: Optional[int] = None) -> None:
     """Record one optimizer step: wall time, throughput, MFU."""
+    global _peak_flops
     if not _met._ENABLED:
         return
     r = _met.REGISTRY
@@ -49,5 +50,7 @@ def record_step(dt_s: float, samples: Optional[int] = None,
         if dt_s > 0:
             r.gauge("train.tokens_per_s").set(tokens / dt_s)
             if _flops_per_token:
+                if _peak_flops is None:
+                    _peak_flops = _attached_chip_spec()["flops"]
                 r.gauge("train.mfu").set(
                     (tokens / dt_s) * _flops_per_token / _peak_flops)

@@ -15,7 +15,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-# Measured on v5e at B8/S1024/V50k: ONE big chunk wins (15.6 ms
+# Measured on an earlier chip record (deleted in PR 21; not re-measured
+# on the current code) at B8/S1024/V50k: ONE big chunk wins (15.6 ms
 # fwd+bwd vs 19.2 at C=2048 vs 18.2 for the non-custom-vjp path) —
 # the scan carry costs more than the transient [C, V] tile; the
 # durable win is the custom-vjp recompute (no logits residual).

@@ -108,18 +108,12 @@ def supported(q_shape, skv, dtype, causal=True):
     return _pick_block(s) is not None and _pick_block(skv) is not None
 
 
-def _compiler_params(interpret):
+def _compiler_params():
     """(b, h, q) are parallel (megacore may split them); kv / inner q
     are 'arbitrary' — scratch accumulators carry state across them."""
-    if interpret:
-        return {}
-    try:
-        pltpu = _pltpu()
-        return {"compiler_params": pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"))}
-    except Exception:
-        return {}
+    return _pltpu().CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel",
+                             "arbitrary"))
 
 
 def _masked_tile(s, q0, k0, bq, bkv):
@@ -219,7 +213,7 @@ def _fwd(q, k, v, sm_scale, causal, interpret, bq, bkv):
                         pltpu.VMEM((bq, 128), jnp.float32),
                         pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
-        **_compiler_params(interpret),
+        compiler_params=_compiler_params(),
     )(q, k, v)
     return o, lse
 
@@ -333,7 +327,7 @@ def _bwd_dq(q, k, v, o, lse, do, sm_scale, causal, interpret, bq, bkv):
         scratch_shapes=[pltpu.VMEM((bq, 128), jnp.float32),
                         pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
-        **_compiler_params(interpret),
+        compiler_params=_compiler_params(),
     )(q, k, v, o, lse, do)
 
 
@@ -370,7 +364,7 @@ def _bwd_dkv(q, k, v, o, lse, do, sm_scale, causal, interpret, bq, bkv):
         scratch_shapes=[pltpu.VMEM((bkv, d), jnp.float32),
                         pltpu.VMEM((bkv, d), jnp.float32)],
         interpret=interpret,
-        **_compiler_params(interpret),
+        compiler_params=_compiler_params(),
     )(q, k, v, o, lse, do)
     return dk, dv
 

@@ -41,13 +41,10 @@ def _gate_reason(q, k):
 
 def _count(metric, **labels):
     """Trace-time dispatch counter (single-branch no-op when telemetry
-    is off; never lets an observability failure break dispatch)."""
-    try:
-        from paddle_tpu import observability as obs
-        if obs.enabled():
-            obs.counter(metric, **labels).inc()
-    except Exception:
-        pass
+    is off)."""
+    from paddle_tpu import observability as obs
+    if obs.enabled():
+        obs.counter(metric, **labels).inc()
 
 
 def flash_attention(q, k, v, causal: bool = False,
@@ -83,82 +80,81 @@ def flash_attention_maybe(q, k, v, causal=False, scale=None):
     (None routes the caller to plain XLA attention — shapes the gates
     reject, e.g. a head dim that is not a multiple of the 64-lane
     width, FALL BACK rather than raise, and the fallback is counted on
-    the ``attn.dispatch_fallback`` observability counter).
+    the ``attn.dispatch_fallback`` observability counter). An error
+    inside an accepted kernel is an error: nothing here catches it.
 
-    Static chain (v5e measurements; the autotune table, when warm,
-    overrides it): monolithic simple kernel where the whole (b, h)
+    Static chain (ordered by timings from an earlier chip record, deleted
+    in PR 21 — not measured on the current code; the autotune table, when
+    warm, overrides it): monolithic simple kernel where the whole (b, h)
     slice fits VMEM (S<=1024), causal-skip strip kernel where the
     [S,S] scores no longer fit (S<=2048), q-block kernel for the
     non-causal middle tier, then the q×kv-blocked flash kernel for the
     MAC-bound long-S regime (S>=4096 — VMEM residency O(block^2), no
     S-cap), with the jax library flash kernel as the final tier."""
-    try:
-        if jax.default_backend() != "tpu":
-            return None
-        if not _supported(q, k, v):
-            _count("attn.dispatch_fallback", reason=_gate_reason(q, k))
-            return None
-        from paddle_tpu.ops.pallas import autotune
-        from paddle_tpu.ops.pallas import blocked_flash as bfk
-        from paddle_tpu.ops.pallas import causal_attention as cak
-        from paddle_tpu.ops.pallas import simple_attention as sa
-        from paddle_tpu.ops.pallas import simple_attention2 as sa2
-        # measured winner (runtime autotune cache / first-call timing)
-        # takes precedence over the static chain below
-        tuned = autotune.decide(q, k, causal)
-        if tuned is not None:
-            _count("attn.dispatch", kernel=tuned)
-            if tuned == "xla":
-                return None
-            return autotune.run(tuned, q, k, v, causal, scale)
-        # Dispatch order (v5e measurements): at S<=1024 the full-S^2
-        # monolithic kernel wins (VPU-bound; causal skipping does not
-        # pay: 49.1k vs 50.6k tok/s e2e). Where the whole [S,S] score
-        # matrix no longer fits (S=2048), the causal-skip strip kernel
-        # beats the q-block kernel ~1.8x (4.33 vs 7.85 ms/layer
-        # fwd+bwd) because attention MACs dominate at long S.
-        bhsd = (q.shape[0], q.shape[2], q.shape[1], q.shape[3])
-        if q.shape[1] == k.shape[1] and sa.supported(bhsd, q.dtype):
-            qt = jnp.swapaxes(q, 1, 2)
-            kt = jnp.swapaxes(k, 1, 2)
-            vt = jnp.swapaxes(v, 1, 2)
-            _count("attn.dispatch", kernel="simple")
-            out = sa.attention_bhsd(qt, kt, vt, causal=causal,
-                                    scale=scale)
-            return jnp.swapaxes(out, 1, 2)
-        if causal and q.shape[1] == k.shape[1] \
-                and cak.supported(bhsd, q.dtype):
-            qt = jnp.swapaxes(q, 1, 2)
-            kt = jnp.swapaxes(k, 1, 2)
-            vt = jnp.swapaxes(v, 1, 2)
-            _count("attn.dispatch", kernel="causal_skip")
-            out = cak.attention_bhsd(qt, kt, vt, causal=True,
-                                     scale=scale)
-            return jnp.swapaxes(out, 1, 2)
-        if q.shape[1] == k.shape[1] and sa2.supported(bhsd, q.dtype):
-            # middle tier: q streams in blocks, k/v whole in VMEM
-            # (3.30 vs 3.64 ms/layer vs library flash at S=2048 —
-            # benchmarks/probes/_qblock_bench.py)
-            qt = jnp.swapaxes(q, 1, 2)
-            kt = jnp.swapaxes(k, 1, 2)
-            vt = jnp.swapaxes(v, 1, 2)
-            _count("attn.dispatch", kernel="qblock")
-            out = sa2.attention_bhsd(qt, kt, vt, causal=causal,
-                                     scale=scale)
-            return jnp.swapaxes(out, 1, 2)
-        if bfk.supported(bhsd, k.shape[1], q.dtype, causal):
-            # long-S tier: every monolithic gate above has rejected
-            # (S>=4096 at D128) — q×kv-blocked online-softmax kernel
-            # with static causal block-skipping
-            qt = jnp.swapaxes(q, 1, 2)
-            kt = jnp.swapaxes(k, 1, 2)
-            vt = jnp.swapaxes(v, 1, 2)
-            _count("attn.dispatch", kernel="blocked")
-            out = bfk.attention_bhsd(qt, kt, vt, causal=causal,
-                                     scale=scale)
-            return jnp.swapaxes(out, 1, 2)
-        _count("attn.dispatch", kernel="library_flash")
-        return flash_attention(q, k, v, causal=causal, scale=scale)
-    except Exception:
-        _count("attn.dispatch_fallback", reason="error")
+    if jax.default_backend() != "tpu":
         return None
+    if not _supported(q, k, v):
+        _count("attn.dispatch_fallback", reason=_gate_reason(q, k))
+        return None
+    from paddle_tpu.ops.pallas import autotune
+    from paddle_tpu.ops.pallas import blocked_flash as bfk
+    from paddle_tpu.ops.pallas import causal_attention as cak
+    from paddle_tpu.ops.pallas import simple_attention as sa
+    from paddle_tpu.ops.pallas import simple_attention2 as sa2
+    # measured winner (runtime autotune cache / first-call timing)
+    # takes precedence over the static chain below
+    tuned = autotune.decide(q, k, causal)
+    if tuned is not None:
+        _count("attn.dispatch", kernel=tuned)
+        if tuned == "xla":
+            return None
+        return autotune.run(tuned, q, k, v, causal, scale)
+    # Dispatch order (timings from an earlier chip record, deleted in
+    # PR 21; not measured on the current code): at S<=1024 the full-S^2
+    # monolithic kernel wins (VPU-bound; causal skipping does not
+    # pay: 49.1k vs 50.6k tok/s e2e). Where the whole [S,S] score
+    # matrix no longer fits (S=2048), the causal-skip strip kernel
+    # beats the q-block kernel ~1.8x (4.33 vs 7.85 ms/layer
+    # fwd+bwd) because attention MACs dominate at long S.
+    bhsd = (q.shape[0], q.shape[2], q.shape[1], q.shape[3])
+    if q.shape[1] == k.shape[1] and sa.supported(bhsd, q.dtype):
+        qt = jnp.swapaxes(q, 1, 2)
+        kt = jnp.swapaxes(k, 1, 2)
+        vt = jnp.swapaxes(v, 1, 2)
+        _count("attn.dispatch", kernel="simple")
+        out = sa.attention_bhsd(qt, kt, vt, causal=causal,
+                                scale=scale)
+        return jnp.swapaxes(out, 1, 2)
+    if causal and q.shape[1] == k.shape[1] \
+            and cak.supported(bhsd, q.dtype):
+        qt = jnp.swapaxes(q, 1, 2)
+        kt = jnp.swapaxes(k, 1, 2)
+        vt = jnp.swapaxes(v, 1, 2)
+        _count("attn.dispatch", kernel="causal_skip")
+        out = cak.attention_bhsd(qt, kt, vt, causal=True,
+                                 scale=scale)
+        return jnp.swapaxes(out, 1, 2)
+    if q.shape[1] == k.shape[1] and sa2.supported(bhsd, q.dtype):
+        # middle tier: q streams in blocks, k/v whole in VMEM
+        # (3.30 vs 3.64 ms/layer vs library flash at S=2048 —
+        # benchmarks/probes/_qblock_bench.py)
+        qt = jnp.swapaxes(q, 1, 2)
+        kt = jnp.swapaxes(k, 1, 2)
+        vt = jnp.swapaxes(v, 1, 2)
+        _count("attn.dispatch", kernel="qblock")
+        out = sa2.attention_bhsd(qt, kt, vt, causal=causal,
+                                 scale=scale)
+        return jnp.swapaxes(out, 1, 2)
+    if bfk.supported(bhsd, k.shape[1], q.dtype, causal):
+        # long-S tier: every monolithic gate above has rejected
+        # (S>=4096 at D128) — q×kv-blocked online-softmax kernel
+        # with static causal block-skipping
+        qt = jnp.swapaxes(q, 1, 2)
+        kt = jnp.swapaxes(k, 1, 2)
+        vt = jnp.swapaxes(v, 1, 2)
+        _count("attn.dispatch", kernel="blocked")
+        out = bfk.attention_bhsd(qt, kt, vt, causal=causal,
+                                 scale=scale)
+        return jnp.swapaxes(out, 1, 2)
+    _count("attn.dispatch", kernel="library_flash")
+    return flash_attention(q, k, v, causal=causal, scale=scale)

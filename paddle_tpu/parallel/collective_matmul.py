@@ -161,7 +161,7 @@ def _nested_manual_context() -> bool:
 
 
 def _smap(fn, mesh, in_specs, out_specs, axis_name):
-    from paddle_tpu.core.compat import shard_map
+    from jax import shard_map
     if _nested_manual_context():
         return shard_map(fn, axis_names={axis_name},
                          in_specs=in_specs, out_specs=out_specs)

@@ -24,7 +24,7 @@ Peak live activations per stage: 2(N-1-s)+1 <= 2N-1, independent of M
 (vs M for F-then-B/GPipe) — the same bound class as host 1F1B, achieved
 with compiled collectives instead of NCCL p2p + host scheduling.
 
-Trade-offs (documented, measured in benchmarks/probes/_pp_memory_probe.py):
+Trade-offs:
 ramp ticks execute masked compute (SPMD stages run one program), so
 wall-clock efficiency is M/(M+2(N-1)) per leg — the usual pipeline
 bubble; and the last-stage head/loss runs (masked) on every stage.

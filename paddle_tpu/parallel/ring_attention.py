@@ -113,7 +113,7 @@ def ring_attention_sharded(q, k, v, mesh, axis_name="sp", causal=False):
     """Convenience: run ring_attention via shard_map on [B, S, H, D] arrays
     sharded along S over `axis_name` (other dims replicated)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from paddle_tpu.core.compat import shard_map
+    from jax import shard_map
     spec = P(None, axis_name, None, None)
     fn = shard_map(
         functools.partial(ring_attention, axis_name=axis_name,

@@ -69,7 +69,7 @@ def ulysses_attention_sharded(q, k, v, mesh, axis_name="sp",
                               causal=False):
     """Convenience: shard_map wrapper for [B, S, H, D] arrays sharded
     along S over `axis_name` (mirrors ring_attention_sharded)."""
-    from paddle_tpu.core.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     spec = P(None, axis_name, None, None)
     fn = shard_map(

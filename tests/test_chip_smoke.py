@@ -1,0 +1,116 @@
+"""chip_smoke.py's control flow at tiny size on the CPU mesh, plus the
+start-up contracts it rests on: no CPU branch in the script, a compile
+cache placed from outside, imports that create no backend, peaks that
+refuse an unknown device. The full-width run needs a chip
+(``python chip_smoke.py`` through the chip tool)."""
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+import chip_smoke
+from paddle_tpu.models.gpt import GPTConfig
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+TINY = GPTConfig(vocab_size=256, hidden_size=64, num_layers=2, num_heads=2,
+                 max_seq_len=64)
+
+
+def _python(*args, env_extra=None, drop=()):
+    """A fresh CPU-backend python at the repo root, started, not waited."""
+    env = {k: v for k, v in os.environ.items() if k not in drop}
+    env["JAX_PLATFORMS"] = "cpu"
+    env.update(env_extra or {})
+    return subprocess.Popen([sys.executable, *args], cwd=ROOT, env=env,
+                            text=True, stdout=subprocess.PIPE,
+                            stderr=subprocess.PIPE)
+
+
+def test_train_phase_tiny():
+    # every earlier check (loss start/fall, no compile after warm-up) must
+    # hold for the phase to reach its last one: on the CPU no Pallas kernel
+    # is routed, so asking for one is what fails
+    with pytest.raises(AssertionError, match="expected the 'simple'"):
+        chip_smoke.train_phase(TINY, batch=2, seq=32, steps=2,
+                               scan_unroll=2, expect_kernel="simple")
+
+
+def test_multichip_phase_tiny():
+    rs = chip_smoke.multichip_phase(
+        TINY, batch=8, seq=32, steps=1, scan_unroll=1, n_devices=4,
+        layouts=({"dp": 4, "zero1": True}, {"dp": 2, "tp": 2, "sp": True}))
+    assert [r["param_devices"] for r in rs] == [4, 4]
+    assert all(r["losses"][-1] < r["losses"][0] and not r["dispatch"]
+               for r in rs)
+
+
+def test_serve_phase_tiny():
+    r = chip_smoke.serve_phase(TINY, max_slots=2, max_length=64,
+                               decode_block=4, n_requests=4,
+                               prompt_range=(4, 12), budget_range=(4, 8))
+    assert r["requests"] == 4 and r["first_diff"] != 0
+
+
+def test_kernel_phase_interpret():
+    cases = (("simple", (1, 2, 128, 64), True, None),
+             ("causal_skip", (1, 1, 256, 64), True, None),
+             ("qblock", (1, 1, 256, 64), False, None),
+             ("blocked", (1, 1, 256, 64), True, (128, 128)),
+             ("blocked", (1, 1, 256, 64), False, (128, 128)))
+    assert len(chip_smoke.kernel_phase(cases, interpret=True)) == len(cases)
+    with pytest.raises(AssertionError, match="off its float32 reference"):
+        chip_smoke.check_kernel(*cases[0], interpret=True, tol=0.0)
+
+
+def test_blocked_flash_passes_dimension_semantics():
+    from paddle_tpu.ops.pallas import blocked_flash
+    params = blocked_flash._compiler_params()
+    assert tuple(str(s).lower().rsplit(".", 1)[-1]
+                 for s in params.dimension_semantics) == (
+        "parallel", "parallel", "parallel", "arbitrary")
+
+
+def test_unknown_device_kind_raises():
+    from paddle_tpu import cost_model
+    assert cost_model.spec_for_device_kind("TPU v5 lite")["flops"] == 197e12
+    with pytest.raises(KeyError, match="no peak spec"):
+        cost_model.spec_for_device_kind("cpu")
+    with pytest.raises(KeyError):
+        cost_model.attached_chip_spec()        # the CPU test backend
+
+
+def test_start_up_contracts_in_subprocesses():
+    """Three fresh processes, run side by side: the smoke on a CPU backend;
+    every root script and the launcher imported, then the cache placed with
+    the variable unset; the cache placed with the variable set."""
+    report = ("import json, jax, jax._src.xla_bridge as xb; "
+              "from paddle_tpu import compile_cache; "
+              "d = compile_cache.enable(); "
+              "print(json.dumps({'dir': d, "
+              "'cfg': jax.config.jax_compilation_cache_dir, "
+              "'backends': sorted(xb._backends)}))")
+    smoke = _python("chip_smoke.py")
+    unset = _python("-c", "import paddle_tpu, bench, chip_smoke, "
+                    "__graft_entry__, paddle_tpu.distributed.launch.main; "
+                    + report, drop=("JAX_COMPILATION_CACHE_DIR",))
+    given = _python("-c", report,
+                    env_extra={"JAX_COMPILATION_CACHE_DIR": "/x/cache"})
+
+    out, err = smoke.communicate(timeout=120)
+    assert smoke.returncode not in (0, None), out
+    assert "default_backend=cpu" in out and '"ok"' not in out
+    assert "only runs on a chip" in err
+
+    out, err = unset.communicate(timeout=120)
+    assert unset.returncode == 0, err[-2000:]
+    got = json.loads(out.strip().splitlines()[-1])
+    assert got["dir"] == got["cfg"] == os.path.join(ROOT, ".jax_cache")
+    assert got["backends"] == [], "an import created a jax backend"
+
+    out, err = given.communicate(timeout=120)
+    assert given.returncode == 0, err[-2000:]
+    got = json.loads(out.strip().splitlines()[-1])
+    assert got["dir"] == got["cfg"] == "/x/cache"

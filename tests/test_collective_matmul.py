@@ -219,9 +219,7 @@ def test_cm_under_pp_upstream_wall():
     gpt_hybrid._use_cm's pp==1 gate and the planner's
     collective_matmul property, and turn this into a parity test.
     Minimal structure: jax.checkpoint(stage-with-tp-ring) under scan +
-    vjp inside a pp-manual region. A standalone upstreamable
-    reproducer of the same wall (with the shallower failure modes
-    peeled off) lives in benchmarks/probes/_cm_repro.py.
+    vjp inside a pp-manual region.
 
     Round-5 note: the CAPABILITY is delivered under pp>1 anyway by the
     manual-tp stage body (tp manual at the SAME level as pp, ring via

@@ -2,7 +2,6 @@
 (VERDICT r2 item 8; reference auto_parallel_gradient_merge.py and
 DataParallel.no_sync)."""
 import numpy as np
-import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu import nn
@@ -166,16 +165,6 @@ def test_no_sync_defers_stage2_relay():
     assert relaid, "stage-2 re-lay should have fired at window exit"
 
 
-from paddle_tpu.core.compat import HAS_MANUAL_AXES
-
-_needs_manual_pp = pytest.mark.skipif(
-    not HAS_MANUAL_AXES,
-    reason="compiled-pipeline paths need jax's varying-manual-axes "
-           "surface (lax.pcast / top-level shard_map); this jax "
-           "predates it")
-
-
-@_needs_manual_pp
 def test_split_accum_composes_with_pipeline():
     """Gradient merge under pp in the compiled engines (VERDICT r3
     item 10): the split accum engine at pp=2 accumulates stage grads
@@ -240,7 +229,6 @@ def test_split_accum_composes_with_pipeline():
                                    rtol=2e-4, atol=2e-4)
 
 
-@_needs_manual_pp
 def test_gradient_merge_composes_with_zero_bubble_schedules():
     """gradient_merge_steps=2 at pp=2 produces the SAME update under
     the 1f1b, zbh1 and zbvpp compiled schedules — merge composes with

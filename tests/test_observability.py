@@ -432,9 +432,8 @@ def test_mfu_gauge_from_configured_flops():
         mfu = obs.gauge("train.mfu").value
         assert mfu == pytest.approx((64 / 0.01) * 6e9 / 1e12)
     finally:
-        obs.training.configure(flops_per_token=0,
-                               peak_flops=obs.training.DEFAULT_PEAK_FLOPS)
         obs.training._flops_per_token = None
+        obs.training._peak_flops = None
 
 
 def test_pipeline_bubble_gauge_math():

@@ -19,23 +19,21 @@ Reference analog: the CI op-benchmark regression gate
 (/root/reference/tools/ci_op_benchmark.sh) — an automated tripwire, not
 a human remembering to re-measure.
 
-RATIO-BASED rungs (ISSUE 13): BENCH_r05 showed the absolute decode
-number sits inside a 129-480 tokens/s transport-weather band — an
-absolute pin would either gate nothing or cry wolf. The gate therefore
-pins WITHIN-WINDOW RATIOS (two quantities measured in the same
-capture: s4096/s1024 MFU, dataloader-fed/pinned, cb/per-step-decode)
-and telemetry-derived invariants read from the registry snapshot each
-BENCH json now embeds under its ``telemetry`` key. Absolute
-throughputs are reported informationally only — they are NOT asserted.
+RATIO-BASED rungs (ISSUE 13): the gate pins WITHIN-WINDOW RATIOS (two
+quantities measured in the same capture: s4096/s1024 MFU,
+dataloader-fed/pinned, cb/per-step-decode) and telemetry-derived
+invariants read from the registry snapshot a bench json embeds under its
+``telemetry`` key. Absolute throughputs are reported informationally
+only — they are NOT asserted. ``check_ratio_rungs`` is exercised on a
+synthetic capture; the chip records the bands were first drawn from
+were deleted in PR 21, and the ledger's per-cell bounds are to replace
+the bands (ROADMAP).
 """
-import glob
 import json
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from paddle_tpu.models.gpt import GPTConfig
 from paddle_tpu.models import gpt_hybrid as GH
@@ -163,21 +161,18 @@ def test_gradient_merge_accumulator_dtype():
 
 
 # ===================================================================
-# Ratio-based regression rungs (ISSUE 13). Bands are anchored on the
-# BENCH_r05 on-device capture and NOTES.md Round-6:
-#   * s4096/s1024 MFU ratio: 0.870 pre-blocked-kernel (0.5897/0.6779);
-#     the kernel only dispatches where it measures faster, so the
-#     floor is pre-kernel-minus-margin. (The 0.62-MFU roofline target
-#     corresponds to ratio ~0.915 — reported, not yet pinned: it is
-#     the thing the next capture must resolve.)
-#   * s2048/s1024: 0.929 recorded -> floor 0.87.
-#   * dataloader-fed vs pinned batch: 1.007 recorded -> floor 0.97
-#     (the loader must not throttle the step); ceiling 1.10 catches a
-#     formula bug (the loader cannot beat a pinned batch by 10%).
-#   * cb vs per-step decode, SAME window: 1.83 recorded; the per-step
-#     leg is RTT-dominated so good transport compresses the ratio —
-#     floor 0.8 only trips when continuous batching falls below the
-#     naive path it exists to beat.
+# Ratio-based regression rungs (ISSUE 13). The bands below were drawn
+# from an earlier chip record, deleted in PR 21 (s4096/s1024 0.870,
+# s2048/s1024 0.929, dataloader-fed/pinned 1.007, cb/per-step decode
+# 1.83 there); nothing on the current code has been measured against
+# them yet.
+#   * s4096/s1024 MFU ratio: the blocked kernel only dispatches where
+#     it measures faster, so the floor is pre-kernel-minus-margin.
+#   * dataloader-fed vs pinned batch: the loader must not throttle the
+#     step; the ceiling catches a formula bug (the loader cannot beat a
+#     pinned batch by 10%).
+#   * cb vs per-step decode, SAME window: the floor only trips when
+#     continuous batching falls below the naive path it exists to beat.
 RATIO_RUNGS = {
     "train_s4096.mfu_ratio_vs_s1024": (0.82, 1.05),
     "train_s2048.mfu_ratio_vs_s1024": (0.87, 1.10),
@@ -190,7 +185,7 @@ RATIO_RUNGS = {
 BUBBLE_CEILING = {"zbh1": 0.2, "zbvpp": 0.2}
 BUBBLE_CEILING_DEFAULT = 0.5
 
-S4096_MFU_TARGET = 0.62   # NOTES.md Round-6 roofline question
+S4096_MFU_TARGET = 0.62   # the s4096 roofline question (bench.py)
 
 
 def check_ratio_rungs(parsed):
@@ -243,22 +238,6 @@ def check_ratio_rungs(parsed):
                 f"{name}: s4096 measured but no attn.dispatch "
                 "counters in the embedded snapshot")
     return checked, failures, missing
-
-
-def _bench_docs_newest_first():
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    docs = []
-    for p in glob.glob(os.path.join(root, "BENCH_*.json")):
-        try:
-            with open(p) as f:
-                doc = json.load(f)
-        except (OSError, ValueError):
-            continue
-        parsed = doc.get("parsed")
-        if isinstance(parsed, dict):
-            docs.append((doc.get("n", 0), os.path.basename(p), parsed))
-    docs.sort(key=lambda t: -t[0])
-    return docs
 
 
 def test_ratio_gate_trips_and_passes(tmp_path):
@@ -323,38 +302,3 @@ def test_ratio_gate_trips_and_passes(tmp_path):
     assert not failures
     assert "train_s4096.mfu_ratio_vs_s1024" in missing
     assert "telemetry" in missing
-
-
-def test_recorded_bench_ratios_within_bands():
-    """Gate the real recorded BENCH artifacts: for each ratio rung,
-    the NEWEST capture that carries it must sit inside its band.
-    Rungs no capture carries yet are reported (the next on-device run
-    fills them); at least one must already be live so the gate is
-    provably wired to real artifacts. Absolute throughputs print
-    informationally and are NOT asserted."""
-    docs = _bench_docs_newest_first()
-    assert docs, "no BENCH_*.json artifacts found at repo root"
-    newest = docs[0][2]
-    print(f"[perf_gate] informational absolutes (newest capture): "
-          f"value={newest.get('value')} {newest.get('unit', '')} "
-          f"mfu={newest.get('mfu')}")
-    gated, all_failures, still_missing = {}, [], set(RATIO_RUNGS)
-    for _n, fname, parsed in docs:
-        checked, failures, _missing = check_ratio_rungs(parsed)
-        fresh = {k: v for k, v in checked.items() if k not in gated}
-        if not fresh:
-            continue
-        for k, v in fresh.items():
-            gated[k] = (v, fname)
-        still_missing -= set(fresh)
-        # only failures for rungs this doc is the newest carrier of
-        all_failures += [f for f in failures
-                         if any(k in f for k in fresh)]
-    assert not all_failures, all_failures
-    assert gated, "no ratio rung found in any recorded BENCH json"
-    if still_missing:
-        print(f"[perf_gate] rungs awaiting their first capture: "
-              f"{sorted(still_missing)}")
-    # the r05 capture already carries the dataloader ratio — the gate
-    # must be LIVE against today's artifacts, not only future ones
-    assert "train_dataloader_fed.vs_pinned_batch" in gated

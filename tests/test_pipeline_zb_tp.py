@@ -139,8 +139,8 @@ def test_collective_matmul_under_pp_via_manual_tp():
     """The round-4 'cm under pp>1' hole, closed for the LOCKSTEP 1F1B
     route: ring collective matmuls (sp_*_matmul_local) inside the
     manual-tp stage body — tp manual at the same level as pp, no
-    nested region, so the Shardy wall (benchmarks/probes/_cm_repro.py) does
-    not apply. The cond-gated zero-bubble schedules cannot host the
+    nested region, so the Shardy wall (gpt_hybrid._use_cm) does not
+    apply. The cond-gated zero-bubble schedules cannot host the
     ring (ppermute lowers to a whole-mesh op; idle stages never
     arrive — probe leg E) and must refuse it with a diagnosis."""
     _parity("1f1b", True, cm=True)
