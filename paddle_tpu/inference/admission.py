@@ -67,15 +67,24 @@ class ServingStepError(RuntimeError):
 
 
 class RequestResult:
-    """Terminal outcome of one request."""
+    """Terminal outcome of one request.
 
-    __slots__ = ("state", "ids", "error")
+    ``timings`` holds the session's own stamps of the request, each a
+    ``time.perf_counter()`` instant or None where the request never got
+    there (or metrics were off, which is also the stamps' off switch):
+    ``submit``, ``admit`` (its prefill program dispatched),
+    ``first_token`` (its first token on the host) and ``done`` (its
+    terminal transition)."""
+
+    __slots__ = ("state", "ids", "error", "timings")
 
     def __init__(self, state: RequestState, ids: np.ndarray,
-                 error: Optional[str] = None):
+                 error: Optional[str] = None,
+                 timings: Optional[dict] = None):
         self.state = state
         self.ids = ids
         self.error = error
+        self.timings = timings
 
     @property
     def ok(self) -> bool:

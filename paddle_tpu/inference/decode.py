@@ -43,6 +43,7 @@ from paddle_tpu.core.dispatch import run_op
 from paddle_tpu.observability import metrics as _met
 from paddle_tpu.observability import server as _obs_server
 from paddle_tpu import _chaos
+from paddle_tpu.profiler import RecordEvent
 from paddle_tpu.inference import admission as _adm
 from paddle_tpu.inference.admission import (AdmissionRejected,  # noqa: F401
                                             RequestResult, RequestState,
@@ -65,6 +66,7 @@ def init_static_cache(batch_size, capacity, num_kv_heads, head_dim,
     return StaticCache(k, v, length)
 
 
+@jax.named_scope("write_kv")
 def _write_kv(buf, new, lens):
     """Write new [B, s, H, D] into buf [B, C, H, D] at per-seq offsets."""
     return jax.vmap(
@@ -72,6 +74,7 @@ def _write_kv(buf, new, lens):
     )(buf, new, lens)
 
 
+@jax.named_scope("cache_attention")
 def _cache_attention(q, kn, vn, kbuf, vbuf, lens):
     """Write-then-attend against a fixed-capacity cache.
 
@@ -177,6 +180,7 @@ def masked_multihead_attention_impl(x, cache_kv, seq_lens, num_heads,
                   n_outputs=2, differentiable=False)
 
 
+@jax.named_scope("sample")
 def _sample(logits, key, temperature, top_p, top_k=None):
     """On-device sampling: greedy / temperature / top-k / nucleus."""
     if temperature == 0.0:
@@ -504,9 +508,6 @@ class DecodeSession(_SessionLifecycle):
         r.counter("serving.prefill_tokens").inc(batch * prompt_len)
         r.counter("serving.decode_tokens").inc(batch * n_new)
         r.histogram("serving.generate_latency_s").observe(dt)
-        if dt > 0:
-            r.gauge("serving.decode_tokens_per_s").set(
-                batch * n_new / dt)
 
     def _generate_blocks(self, state, token, key, finished, cache_arrays,
                          b, m_total):
@@ -544,10 +545,36 @@ class DecodeSession(_SessionLifecycle):
 
 
 
+class _Phase:
+    """One timed phase of the serving step: the span (a RecordEvent, so it
+    shows in a running jax.profiler trace) and its seconds into a
+    histogram, off the same two clock reads. While metrics are off it does
+    nothing and reads no clock; ``t0`` is then None and ``seconds`` 0."""
+
+    __slots__ = ("_span", "_hist", "t0", "seconds")
+
+    def __init__(self, span, hist):
+        self._span, self._hist = span, hist
+        self.t0, self.seconds = None, 0.0
+
+    def __enter__(self):
+        if _met._ENABLED:
+            self._span.begin()
+            self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        if self.t0 is not None:
+            self.seconds = time.perf_counter() - self.t0
+            self._span.end()
+            self._hist.observe(self.seconds)
+        return False
+
+
 class _Request:
     __slots__ = ("rid", "ids", "plen", "budget", "tokens", "slot",
-                 "t_submit", "state", "priority", "deadline",
-                 "ttft_deadline", "error")
+                 "t_submit", "t_admit", "t_first", "t_done", "state",
+                 "priority", "deadline", "ttft_deadline", "error")
 
     def __init__(self, rid, ids, plen, budget, priority=0,
                  deadline_s=None, ttft_deadline_s=None):
@@ -555,7 +582,11 @@ class _Request:
         self.budget = budget
         self.tokens: List[int] = []
         self.slot = None
+        # lifecycle stamps, all perf_counter instants: submitted, admit
+        # program dispatched, first token on the host, terminal
+        # transition; the last three only while metrics are on
         self.t_submit = time.perf_counter()
+        self.t_admit = self.t_first = self.t_done = None
         self.state = RequestState.QUEUED
         self.priority = int(priority)
         # deadlines are absolute perf_counter instants; None = no bound
@@ -572,6 +603,10 @@ class _Request:
             return True
         return (self.ttft_deadline is not None and not self.tokens
                 and now > self.ttft_deadline)
+
+    def timings(self):
+        return {"submit": self.t_submit, "admit": self.t_admit,
+                "first_token": self.t_first, "done": self.t_done}
 
 
 class ContinuousBatchingSession(_SessionLifecycle):
@@ -661,7 +696,13 @@ class ContinuousBatchingSession(_SessionLifecycle):
         # reference's block-scheduler makes with its step quantum.
         self._sync_every = max(1, int(sync_every))
         self._pending: List = []
-        self._t_last_drain = None
+        reg = _met.REGISTRY
+        self._h_phase = {
+            phase: reg.histogram("serving.step_phase_s", phase=phase)
+            for phase in ("admit", "dispatch", "fetch", "deliver")}
+        self._h_step = reg.histogram("serving.step_s")
+        self._h_step_host = reg.histogram("serving.step_host_s")
+        self._fetch_s = 0.0     # seconds this step() waited in its fetch
         # decode_block=k runs k decode steps per DISPATCH in one
         # lax.while_loop program (the DecodeSession block-decode idea
         # applied to the slot batch): one dispatch emits a [slots, k]
@@ -727,6 +768,7 @@ class ContinuousBatchingSession(_SessionLifecycle):
                 lax.dynamic_update_index_in_dim(fl, plen, slot, 0)))
         return jax.tree_util.tree_leaves(out)
 
+    @jax.named_scope("admit")
     def _admit_pure(self, *flat):
         n = len(self._state_t)
         state = flat[:n]
@@ -745,6 +787,7 @@ class ContinuousBatchingSession(_SessionLifecycle):
                                           slot, plen)
         return tokens, key, cache_arrays
 
+    @jax.named_scope("decode_step")
     def _masked_step(self, state, tok, key, active, cache_arrays):
         """ONE masked decode step — the single home of the per-slot
         semantics shared by the per-step and block programs: inactive
@@ -899,10 +942,14 @@ class ContinuousBatchingSession(_SessionLifecycle):
         self._done[req.rid] = req
         if _met._ENABLED:
             r = _met.REGISTRY
+            req.t_done = now = time.perf_counter()
             if state is RequestState.DONE:
                 r.counter("serving.requests_completed").inc()
                 r.histogram("serving.request_latency_s").observe(
-                    time.perf_counter() - req.t_submit)
+                    now - req.t_submit)
+                if req.t_first is not None and len(req.tokens) > 1:
+                    r.histogram("serving.tpot_s").observe(
+                        (now - req.t_first) / (len(req.tokens) - 1))
             elif state is RequestState.TIMED_OUT:
                 r.counter("serving.timed_out").inc()
             elif state is RequestState.CANCELLED:
@@ -973,8 +1020,14 @@ class ContinuousBatchingSession(_SessionLifecycle):
                 *state, self._tokens, self._key, jnp.asarray(active),
                 *self._cache_arrays)
 
-        out = self._device_call("serving.decode_step",
-                                {"slots": slots}, call, retries)
+        with _Phase(RecordEvent("serving.dispatch", slots=len(slots)),
+                    self._h_phase["dispatch"]):
+            out = self._device_call("serving.decode_step",
+                                    {"slots": slots}, call, retries)
+        if _met._ENABLED:
+            # every lane computes every step, whoever sits in it
+            _met.REGISTRY.counter("serving.decode_lane_steps").inc(
+                self._slots * (self._decode_block or 1))
         if self._decode_block:
             blk_out, self._tokens, self._key, self._cache_arrays = out
             self._pending.append(("block", slots, blk_out))
@@ -1038,57 +1091,61 @@ class ContinuousBatchingSession(_SessionLifecycle):
 
     def _admit_ready(self):
         state = [t._data for t in self._state_t]
-        t_admit = time.perf_counter()
         while self._free and self._queue:
             req = self._queue.popleft()
             slot = self._free.pop()
             req.state = RequestState.PREFILLING
             bucket = next((b for b in self._buckets
                            if b >= req.plen), self._max_length)
-            padded = jnp.asarray(
-                np.pad(req.ids, (0, bucket - req.plen))[None])
+            with _Phase(RecordEvent("serving.admit", rid=req.rid,
+                                    slot=slot, plen=req.plen,
+                                    bucket=bucket),
+                        self._h_phase["admit"]):
+                self._admit_one(state, req, slot, bucket)
 
-            def call():
-                return self._admit_jit(
-                    *state, padded, jnp.int32(req.plen),
-                    jnp.int32(slot), self._tokens, self._key,
-                    *self._cache_arrays)
+    def _admit_one(self, state, req, slot, bucket):
+        """Pad the prompt to its bucket and dispatch the admit program
+        (a b=1 prefill into ``slot``)."""
+        padded = jnp.asarray(
+            np.pad(req.ids, (0, bucket - req.plen))[None])
 
-            try:
-                self._tokens, self._key, self._cache_arrays = \
-                    self._device_call("serving.admit_step",
-                                      {"rid": req.rid, "slot": slot},
-                                      call)
-            except Exception as e:  # noqa: BLE001
-                # the failing request is identified directly here (the
-                # admit is b=1): quarantine it, keep admitting others
-                self._free.append(slot)
-                self._finish(req, RequestState.FAILED,
-                             error=f"{type(e).__name__}: {e}")
-                continue
-            req.slot = slot
-            req.state = RequestState.DECODING
-            self._running[slot] = req
-            if _met._ENABLED:
-                r = _met.REGISTRY
-                r.counter("serving.admits").inc()
-                r.counter("serving.prefill_tokens").inc(req.plen)
-                dt = time.perf_counter() - t_admit
-                if dt > 0:
-                    # dispatch-side rate: prefill programs are async,
-                    # so this tracks admission throughput, not device
-                    # occupancy
-                    r.gauge("serving.prefill_tokens_per_s").set(
-                        req.plen / dt)
-                t_admit = time.perf_counter()
-            # the admit's sampled token is the request's first output;
-            # it stays ON DEVICE and is fetched with the next pending
-            # drain (an immediate device_get would reintroduce one
-            # blocking RTT per admission — the cost sync_every exists
-            # to amortize). The tagged entry applies to THIS slot only:
-            # the other lanes of the vector hold already-consumed
-            # decode tokens.
-            self._pending.append(("admit", slot, self._tokens))
+        def call():
+            return self._admit_jit(
+                *state, padded, jnp.int32(req.plen),
+                jnp.int32(slot), self._tokens, self._key,
+                *self._cache_arrays)
+
+        try:
+            self._tokens, self._key, self._cache_arrays = \
+                self._device_call("serving.admit_step",
+                                  {"rid": req.rid, "slot": slot},
+                                  call)
+        except Exception as e:  # noqa: BLE001
+            # the failing request is identified directly here (the
+            # admit is b=1): quarantine it, keep admitting others
+            self._free.append(slot)
+            self._finish(req, RequestState.FAILED,
+                         error=f"{type(e).__name__}: {e}")
+            return
+        req.slot = slot
+        req.state = RequestState.DECODING
+        self._running[slot] = req
+        if _met._ENABLED:
+            r = _met.REGISTRY
+            r.counter("serving.admits").inc()
+            r.counter("serving.prefill_tokens").inc(req.plen)
+            r.counter("serving.prefill_padded_tokens").inc(bucket)
+            req.t_admit = time.perf_counter()
+            r.histogram("serving.queue_wait_s").observe(
+                req.t_admit - req.t_submit)
+        # the admit's sampled token is the request's first output;
+        # it stays ON DEVICE and is fetched with the next pending
+        # drain (an immediate device_get would reintroduce one
+        # blocking RTT per admission — the cost sync_every exists
+        # to amortize). The tagged entry applies to THIS slot only:
+        # the other lanes of the vector hold already-consumed
+        # decode tokens.
+        self._pending.append(("admit", slot, self._tokens))
 
     def _maybe_retire(self, req):
         if (len(req.tokens) >= req.budget
@@ -1103,8 +1160,19 @@ class ContinuousBatchingSession(_SessionLifecycle):
         entries = self._pending
         self._pending = []
         _chaos.hit("serving.drain", n=len(entries))
-        fetched = jax.device_get([t for (_k, _s, t) in entries])
-        delivered = 0
+        with _Phase(RecordEvent("serving.fetch", entries=len(entries)),
+                    self._h_phase["fetch"]) as fetch:
+            fetched = jax.device_get([t for (_k, _s, t) in entries])
+        self._fetch_s += fetch.seconds
+        with _Phase(RecordEvent("serving.deliver", entries=len(entries)),
+                    self._h_phase["deliver"]) as deliver:
+            self._deliver(entries, fetched, deliver.t0)
+
+    def _deliver(self, entries, fetched, now):
+        """Append the fetched tokens to their requests and retire those
+        at their budget or eos. ``now`` is when the tokens reached the
+        host (None while metrics are off)."""
+        delivered = first = 0
         for (kind, ainfo, _t), row in zip(entries, fetched):
             # ainfo: the admitted slot ("admit") or the tuple of slots
             # active AT DISPATCH ("step"/"block") — only those lanes
@@ -1117,6 +1185,9 @@ class ContinuousBatchingSession(_SessionLifecycle):
                 if req is not None:
                     req.tokens.append(int(row[ainfo]))
                     delivered += 1
+                    first += 1
+                    if now is not None:
+                        self._stamp_first_token(req, now)
                     self._maybe_retire(req)
                 continue
             if kind == "block":
@@ -1135,13 +1206,19 @@ class ContinuousBatchingSession(_SessionLifecycle):
                     delivered += 1
                     self._maybe_retire(req)
         if _met._ENABLED and delivered:
-            now = time.perf_counter()
             r = _met.REGISTRY
             r.counter("serving.decode_tokens").inc(delivered)
-            if self._t_last_drain is not None and now > self._t_last_drain:
-                r.gauge("serving.decode_tokens_per_s").set(
-                    delivered / (now - self._t_last_drain))
-            self._t_last_drain = now
+            if first:
+                r.counter("serving.first_tokens").inc(first)
+
+    @staticmethod
+    def _stamp_first_token(req, now):
+        req.t_first = now
+        r = _met.REGISTRY
+        r.histogram("serving.ttft_s").observe(now - req.t_submit)
+        if req.t_admit is not None:
+            r.histogram("serving.first_token_hold_s").observe(
+                now - req.t_admit)
 
     def step(self):
         """Expire deadlines, admit whatever fits (on sync boundaries),
@@ -1149,6 +1226,18 @@ class ContinuousBatchingSession(_SessionLifecycle):
         and — every `sync_every` steps — fetch the pending token block
         and retire finished requests. Returns the list of request ids
         that reached a terminal state during this step."""
+        self._fetch_s = 0.0
+        with _Phase(RecordEvent("serving.step",
+                                running=len(self._running),
+                                queued=len(self._queue)),
+                    self._h_step) as whole:
+            done = self._step()
+        if whole.t0 is not None:
+            # the host's own time: an upper bound on the idle it causes
+            self._h_step_host.observe(whole.seconds - self._fetch_s)
+        return done
+
+    def _step(self):
         before = set(self._done)
         self._expire_deadlines()
         if not self._pending:
@@ -1193,7 +1282,7 @@ class ContinuousBatchingSession(_SessionLifecycle):
                    req.state,
                    np.concatenate([req.ids,
                                    np.asarray(req.tokens, np.int32)]),
-                   req.error)
+                   req.error, req.timings())
                for rid, req in self._done.items()}
         self._done = {}
         # delivered ids leave the in-flight set: a serving loop calling

@@ -262,6 +262,7 @@ def _layer_norm(x, g, b, eps=1e-5):
     return ((xf - mu) * lax.rsqrt(var + eps)).astype(x.dtype) * g + b
 
 
+@jax.named_scope("attend")
 def _attend(q, k, v, nh, mesh=None):
     """Causal self-attention over [b, s, h] projections.
 
@@ -371,6 +372,7 @@ def _cm_row(x, w, b, mesh):
     return sp_row_matmul(x, w, mesh, "tp") + b
 
 
+@jax.named_scope("block")
 def _block(x, lp, cfg, pcfg, mesh):
     from jax.ad_checkpoint import checkpoint_name
     act_spec = P("dp", "tp", None) if pcfg.sp else P("dp", None, None)
@@ -396,18 +398,19 @@ def _block(x, lp, cfg, pcfg, mesh):
     x = _constrain(x, act_spec, mesh)
     hres = x
     hx = _layer_norm(x, lp["ln2_g"], lp["ln2_b"])
-    if pcfg.num_experts > 0:
-        ff = _moe_ffn(hx, lp, pcfg, mesh)
-    elif cm:
-        ff = checkpoint_name(
-            _cm_row(jax.nn.gelu(checkpoint_name(
-                _cm_column(hx, lp["fc1_w"], lp["fc1_b"], mesh),
-                "ffn1")), lp["fc2_w"], lp["fc2_b"], mesh), "ffn2")
-    else:
-        ff = checkpoint_name(
-            jax.nn.gelu(checkpoint_name(
-                hx @ lp["fc1_w"] + lp["fc1_b"], "ffn1")) @ lp["fc2_w"]
-            + lp["fc2_b"], "ffn2")
+    with jax.named_scope("mlp"):
+        if pcfg.num_experts > 0:
+            ff = _moe_ffn(hx, lp, pcfg, mesh)
+        elif cm:
+            ff = checkpoint_name(
+                _cm_row(jax.nn.gelu(checkpoint_name(
+                    _cm_column(hx, lp["fc1_w"], lp["fc1_b"], mesh),
+                    "ffn1")), lp["fc2_w"], lp["fc2_b"], mesh), "ffn2")
+        else:
+            ff = checkpoint_name(
+                jax.nn.gelu(checkpoint_name(
+                    hx @ lp["fc1_w"] + lp["fc1_b"], "ffn1"))
+                @ lp["fc2_w"] + lp["fc2_b"], "ffn2")
     x = hres + ff
     return _constrain(x, act_spec, mesh)
 
@@ -546,6 +549,7 @@ def forward(params, input_ids, cfg: GPTConfig, pcfg: ParallelConfig,
                       params["wte"].astype(pcfg.compute_dtype))
 
 
+@jax.named_scope("lm_head_ce")
 def _ce_from_hidden(h, wte, labels, pcfg):
     """Next-token CE from the final (post-LN) hidden states [b, s, hid]
     — the single home of the LM-head+loss math, shared by loss_fn and
@@ -644,6 +648,7 @@ def _state_out_shardings(mesh, pspecs, mspecs):
             scalar)
 
 
+@jax.named_scope("adamw_update")
 def adamw_update(params, grads, opt_state, lr=3e-4, b1=0.9, b2=0.95,
                  eps=1e-8, wd=0.1):
     step = opt_state["step"] + 1

@@ -54,6 +54,19 @@ CATALOG = {
     # ------------------------------------------------- jit / compiles
     "jit.xla_compiles": _m("counter",
                            "XLA executable builds process-wide"),
+    "jit.trace_s": _m(
+        "counter", "seconds jax spent tracing functions to jaxprs "
+        "(nested traces each count their own)"),
+    "jit.lower_s": _m(
+        "counter", "seconds jax spent lowering jaxprs to MLIR modules"),
+    "jit.backend_compile_s": _m(
+        "counter", "seconds in the backend's compile, or in the "
+        "retrieval on a persistent-cache hit"),
+    "jit.cache_hits": _m(
+        "counter", "executables found in the persistent compile cache"),
+    "jit.cache_misses": _m(
+        "counter", "executables compiled and written to the persistent "
+        "compile cache"),
     "jit.fn_calls": _m("counter", "StaticFunction calls", ("fn",)),
     "jit.fn_cache_hits": _m("counter",
                             "StaticFunction spec-cache hits", ("fn",)),
@@ -89,10 +102,32 @@ CATALOG = {
         "histogram", "end-to-end generate() latency"),
     "serving.request_latency_s": _m(
         "histogram", "submit-to-retire latency per request"),
-    "serving.decode_tokens_per_s": _m(
-        "gauge", "decode throughput of the last drain"),
-    "serving.prefill_tokens_per_s": _m(
-        "gauge", "prefill throughput of the last admit"),
+    "serving.prefill_padded_tokens": _m(
+        "counter", "prompt tokens prefilled, padding to the bucket "
+        "included"),
+    "serving.first_tokens": _m(
+        "counter", "first tokens delivered (the admit program's; part "
+        "of serving.decode_tokens)"),
+    "serving.decode_lane_steps": _m(
+        "counter", "decode lane-steps dispatched: max_slots x steps, "
+        "whether or not a lane held a request within its budget"),
+    "serving.step_s": _m("histogram", "wall time of one step()"),
+    "serving.step_host_s": _m(
+        "histogram", "step() less the seconds it waited in its fetch: "
+        "the host's own time"),
+    "serving.step_phase_s": _m(
+        "histogram", "wall time of one phase of step(): admit (one "
+        "request), dispatch, fetch, deliver", ("phase",)),
+    "serving.queue_wait_s": _m(
+        "histogram", "submit to admit program dispatched, per request"),
+    "serving.first_token_hold_s": _m(
+        "histogram", "admit program dispatched to first token on the "
+        "host, per request"),
+    "serving.ttft_s": _m(
+        "histogram", "submit to first token on the host, per request"),
+    "serving.tpot_s": _m(
+        "histogram", "(done - first token) / (tokens - 1), per DONE "
+        "request of more than one token"),
     "serving.requests_submitted": _m("counter", "requests submitted"),
     "serving.requests_completed": _m("counter", "requests retired"),
     "serving.admits": _m("counter", "slot admissions"),

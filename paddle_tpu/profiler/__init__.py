@@ -4,10 +4,10 @@ Reference: python/paddle/profiler/profiler.py:358 (scheduler windows,
 chrome-tracing export, statistics tables) over the C++ HostTracer/CUPTI
 CudaTracer (fluid/platform/profiler/).
 
-TPU-native: host spans are recorded by this module (RecordEvent); device
-timelines come from jax.profiler (XLA/TPU xprof trace) — start_trace/
-stop_trace wrap it. Chrome-tracing JSON export covers host spans; the
-xprof trace directory holds the device side.
+TPU-native: device timelines come from jax.profiler (XLA/TPU xprof trace);
+``RecordEvent`` spans are ``jax.profiler.TraceAnnotation``s, so they sit in
+that same trace, on its host plane and clock. While a ``Profiler`` records,
+the spans are also kept by this module for its chrome-tracing JSON export.
 """
 from __future__ import annotations
 
@@ -68,19 +68,31 @@ _TRACER = _HostTracer()
 
 
 class RecordEvent:
-    """Host-span marker (reference platform::RecordEvent).
+    """Span marker (reference platform::RecordEvent).
 
-    Spans go to the native C++ tracer ring (native/src/tracer.cc,
-    HostTracer analog) when the native runtime is built; Python-side
-    buffer otherwise.
+    Every span is a ``jax.profiler.TraceAnnotation``: while a
+    ``jax.profiler`` trace runs it lands on the ``/host:CPU`` plane, on
+    the calling thread's line and the profiler's clock, beside the device
+    planes; keyword attributes become the event's stats. Without a
+    running trace that costs a flag test.
+
+    While a :class:`Profiler` records, the span also goes to the native
+    C++ tracer ring (native/src/tracer.cc, HostTracer analog) when the
+    native runtime is built, the Python-side buffer otherwise, and from
+    there into the chrome-tracing export.
     """
 
-    def __init__(self, name: str, event_type=None):
+    def __init__(self, name: str, event_type=None, **attrs):
         self.name = name
+        self._attrs = attrs
+        self._annotation = None
         self._t0 = None
         self._native = False
 
     def begin(self):
+        self._annotation = jax.profiler.TraceAnnotation(self.name,
+                                                        **self._attrs)
+        self._annotation.__enter__()
         # Availability is only probed while the tracer is enabled, so the
         # common profiler-off hot path never triggers the native build.
         if _TRACER.enabled:
@@ -102,6 +114,9 @@ class RecordEvent:
                                   time.perf_counter_ns() / 1e3,
                                   threading.get_ident() % 100000))
         self._t0 = None
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
 
     def __enter__(self):
         self.begin()

@@ -328,6 +328,25 @@ def test_compile_counter_on_toy_jit_fn():
     assert c3() >= 1
 
 
+def test_compile_seconds_kept_by_phase():
+    """A fresh jit moves the seconds of all three compile phases (what
+    jax.monitoring's duration events carry); a second call moves none."""
+    import jax
+    import jax.numpy as jnp
+    names = ("jit.trace_s", "jit.lower_s", "jit.backend_compile_s")
+
+    def read():
+        return [obs.counter(n).value for n in names]
+
+    jf = jax.jit(lambda x: jnp.tanh(x) * 3 + 1)
+    before = read()
+    jf(jnp.ones((5,)))
+    first = read()
+    assert all(b > a for a, b in zip(before, first)), (before, first)
+    jf(jnp.ones((5,)))
+    assert read() == first
+
+
 def test_global_compile_counter_and_static_function_stats():
     before = obs.counter("jit.xla_compiles").value
 
@@ -480,6 +499,8 @@ def test_cb_session_metrics_and_rid_release():
     sess = ContinuousBatchingSession(m, max_slots=2, max_length=16)
     lat0 = obs.histogram("serving.request_latency_s").count
     tok0 = obs.counter("serving.decode_tokens").value
+    fetch0 = obs.histogram("serving.step_phase_s", phase="fetch").count
+    tpot0 = obs.histogram("serving.tpot_s").count
     rng = np.random.RandomState(2)
     rids = [sess.submit(rng.randint(0, 17, (n,)), 4)
             for n in (3, 5, 2)]
@@ -502,11 +523,66 @@ def test_cb_session_metrics_and_rid_release():
     # queue-depth / utilization gauges exist in the snapshot
     assert obs.histogram("serving.request_latency_s").count >= lat0 + 3
     assert obs.counter("serving.decode_tokens").value > tok0
+    # the session's own clock: every step's fetch, every request's pace
+    assert obs.histogram("serving.step_phase_s",
+                         phase="fetch").count > fetch0
+    assert obs.histogram("serving.tpot_s").count >= tpot0 + 3
     snap = {d["name"] for d in obs.dump()}
     for name in ("serving.queue_depth", "serving.slot_utilization",
-                 "serving.decode_tokens_per_s",
                  "serving.prefill_tokens"):
         assert name in snap, f"missing {name}"
+
+
+class _CountingClock:
+    """The ``time`` module with its clock reads counted."""
+
+    def __init__(self):
+        self.reads = 0
+
+    def __getattr__(self, name):
+        if name.startswith(("perf_counter", "monotonic", "time")):
+            self.reads += 1
+        return getattr(time, name)
+
+
+def test_disabled_session_step_reads_no_clock_for_metrics(monkeypatch):
+    """The disabled-cost micro-benchmark, extended to the session's
+    step(): with metrics off its spans, stamps and histograms read no
+    clock (the deadline scan's one read is all a step makes) and the
+    phase wrapper costs a branch each way."""
+    from paddle_tpu.inference import decode
+    import paddle_tpu.profiler as prof
+    paddle.seed(11)
+    sess = decode.ContinuousBatchingSession(_TinyLM(), max_slots=2,
+                                            max_length=16, decode_block=2)
+    sess.submit(np.arange(3), 4)
+    sess.run()                                  # compiled
+    clock = _CountingClock()
+    monkeypatch.setattr(decode, "time", clock)
+    monkeypatch.setattr(prof, "time", clock)
+    sess.submit(np.arange(4), 6)
+    sess.step()
+    assert clock.reads > 3, "the counting clock sees the enabled path"
+    step_s = obs.histogram("serving.step_s")
+    obs.disable()
+    try:
+        before = (step_s.count, obs.counter("serving.decode_tokens").value)
+        clock.reads = 0
+        sess.step()
+        assert clock.reads == 1, "only _expire_deadlines reads the clock"
+        assert before == (step_s.count,
+                          obs.counter("serving.decode_tokens").value)
+        n = 20000
+        t0 = time.perf_counter()
+        for _ in range(n):
+            with decode._Phase(prof.RecordEvent("t.phase", slot=1), step_s):
+                pass
+        per_phase = (time.perf_counter() - t0) / n
+        assert per_phase < 5e-6, f"a disabled phase costs {per_phase:.2e}s"
+        assert clock.reads == 1
+    finally:
+        obs.enable()
+    sess.close()
 
 
 def test_chrome_trace_carries_metric_counter_events(tmp_path):
