@@ -8,10 +8,12 @@ seeded weights) through the entry points a user calls:
 
 * kernels — every in-tree Pallas attention kernel, forward and backward,
   compiled for the chip at the shape of the regime it is routed for and
-  compared with a float32 ``jax.numpy`` attention;
+  compared with a float32 ``jax.numpy`` attention; the decode attention
+  kernel (forward only) against the float32 einsums of the XLA path;
 * train   — ``gpt_hybrid.setup`` + a few steps at B4xS1024 on a fixed batch;
 * serve   — ``ContinuousBatchingSession`` answering sixteen requests, with
-  request 0 checked against ``DecodeSession.generate``.
+  request 0 checked against ``DecodeSession.generate``, every one-token
+  step through the decode attention kernel and none fallen back.
 
 A phase that fails raises; nothing is caught and summarised. Without a TPU
 backend the script exits non-zero before building anything — there is no CPU
@@ -184,6 +186,48 @@ def check_kernel(name, shape, causal, blocks, interpret=False, tol=2e-2,
         raise AssertionError(f"kernel {tag} off its float32 reference "
                              f"beyond {tol}: {bad}")
     return errs
+
+
+#: the longctx cell's cache of one layer, [B,C,Hkv,D] float32
+DECODE_KERNEL_SHAPE = (12, 2048, 16, 128)
+
+
+def check_decode_kernel(shape=DECODE_KERNEL_SHAPE, interpret=False,
+                        tol=1e-5):
+    """The length-aware decode attention kernel (one query a slot, ragged
+    lengths from 0 to the capacity, the last two slots past it as a decode
+    block steps a lane past its budget) against ``_attend_einsum``, the
+    float32 einsums it stands in for, at HIGHEST precision. Max-abs error,
+    as tests/test_decode_attention_kernel.py holds it on the CPU."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.inference.decode import _attend_einsum
+    from paddle_tpu.ops.pallas.decode_attention import decode_attention
+
+    b, c, hkv, d = shape
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(0), 3)
+    q = jax.random.normal(kq, (b, 1, hkv, d), jnp.bfloat16)
+    kbuf = jax.random.normal(kk, shape, jnp.float32)
+    vbuf = jax.random.normal(kv, shape, jnp.float32)
+    lens = np.linspace(0, c - 1, b).astype(np.int32)
+    lens[-2:] = c, c + 40
+    lens = jnp.asarray(lens)
+    got = jax.jit(lambda *a: decode_attention(*a, interpret=interpret))(
+        q, kbuf, vbuf, lens)
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(_attend_einsum)(q, kbuf, vbuf, lens)
+    got = np.asarray(got, np.float32)
+    if not np.all(np.isfinite(got)):
+        raise AssertionError("decode_ragged: non-finite values")
+    err = float(np.max(np.abs(got - np.asarray(want))))
+    print(f"[smoke] kernel decode_ragged {list(shape)} float32, lengths 0.."
+          f"{c + 40} of {c}: out={err:.2e} (max-abs)", flush=True)
+    if not err <= tol:
+        raise AssertionError(f"kernel decode_ragged {list(shape)} off the "
+                             f"float32 einsums beyond {tol}: {err}")
+    return err
 
 
 def kernel_phase(cases=KERNEL_CASES, interpret=False, tol=2e-2, dtype=None):
@@ -378,7 +422,7 @@ def multichip_phase(cfg, batch, seq, steps, *, scan_unroll, layouts,
 # -------------------------------------------------------------------- serve
 
 def serve_phase(cfg, *, max_slots, max_length, decode_block, n_requests,
-                prompt_range, budget_range, seed=0):
+                prompt_range, budget_range, seed=0, expect_kernel=None):
     """A ContinuousBatchingSession over a bf16 GPTForCausalLM(cfg) answers
     ``n_requests`` seeded requests (the first ``max_slots`` up front, the
     rest after the first step — overlapping lifetimes).
@@ -386,7 +430,9 @@ def serve_phase(cfg, *, max_slots, max_length, decode_block, n_requests,
     Checks: every request ends DONE with exactly its budget;
     serving.step_retries and serving.quarantined stay 0; request 0's greedy
     continuation agrees with DecodeSession.generate on the same prompt at
-    token 0 (the first differing index is printed).
+    token 0 (the first differing index is printed); no attention dispatch
+    fell back and, where ``expect_kernel`` is given, that kernel was
+    dispatched.
     """
     import jax
     import numpy as np
@@ -428,6 +474,7 @@ def serve_phase(cfg, *, max_slots, max_length, decode_block, n_requests,
                 f"of {budget} tokens (error={res.error})")
     retries = w.value("serving.step_retries", default=0) or 0
     quarantined = w.value("serving.quarantined", default=0) or 0
+    dispatch = _attn_counters(w.delta)
     got = results[rids[0]].ids
     n0 = len(prompt0)
     diff = next((i for i in range(budget0)
@@ -439,6 +486,7 @@ def serve_phase(cfg, *, max_slots, max_length, decode_block, n_requests,
           "benchmark)")
     print(f"[smoke] serve: step_retries={int(retries)} "
           f"quarantined={int(quarantined)}")
+    print(f"[smoke] serve: attention dispatch {dispatch or '{}'}")
     print("[smoke] serve: request 0 vs DecodeSession.generate: " +
           (f"identical over {budget0} tokens" if diff is None
            else f"first difference at generated token {diff} of {budget0}"),
@@ -449,11 +497,20 @@ def serve_phase(cfg, *, max_slots, max_length, decode_block, n_requests,
     if diff == 0:
         raise AssertionError("serve: request 0 differs from "
                              "DecodeSession.generate at token 0")
+    fallbacks = {k: n for k, n in dispatch.items()
+                 if k.startswith("attn.dispatch_fallback")}
+    if fallbacks:
+        raise AssertionError(f"serve: attention fell back: {fallbacks}")
+    if expect_kernel is not None and not dispatch.get(
+            f"attn.dispatch{{kernel={expect_kernel}}}"):
+        raise AssertionError(
+            f"serve: expected the {expect_kernel!r} Pallas kernel to be "
+            f"dispatched, saw {dispatch}")
     del model
     gc.collect()
     jax.clear_caches()
     return {"requests": n_requests, "tokens": total, "first_diff": diff,
-            "serve_s": serve_s}
+            "dispatch": dispatch, "serve_s": serve_s}
 
 
 # --------------------------------------------------------------------- main
@@ -496,11 +553,13 @@ def main(argv=None):
                                  {"dp": 2, "tp": 2, "sp": True}))
     else:
         kernel_phase()
+        check_decode_kernel()
         train_phase(cfg, batch=4, seq=1024, steps=4,
                     scan_unroll=SCAN_UNROLL, expect_kernel="simple")
         serve_phase(GPTConfig.gpt3_1p3b(), max_slots=8, max_length=512,
                     decode_block=16, n_requests=16,
-                    prompt_range=(32, 128), budget_range=(64, 128))
+                    prompt_range=(32, 128), budget_range=(64, 128),
+                    expect_kernel="decode_ragged")
 
     print(f"[smoke] compile cache {cache_dir}: "
           f"{compile_cache.entry_count(cache_dir)} entries at end "

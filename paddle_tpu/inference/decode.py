@@ -28,6 +28,7 @@ pure jax functions (weights passed as inputs, cache donated), and exposes
 from __future__ import annotations
 
 import collections
+import functools
 import math
 import time
 import weakref
@@ -74,20 +75,13 @@ def _write_kv(buf, new, lens):
     )(buf, new, lens)
 
 
-@jax.named_scope("cache_attention")
-def _cache_attention(q, kn, vn, kbuf, vbuf, lens):
-    """Write-then-attend against a fixed-capacity cache.
-
-    q: [B, s, H, D] new queries; kn/vn: [B, s, Hkv, D] new keys/values;
-    kbuf/vbuf: [B, C, Hkv, D]; lens: [B] valid lengths BEFORE this call.
-    Returns (out [B, s, H, D], kbuf', vbuf', lens + s). GQA is handled by
-    grouping the query heads — the cache is never materialized at H heads.
-    """
+def _attend_einsum(q, kbuf, vbuf, lens):
+    """q [B, s, H, D] against all C columns of the cache, masked to each
+    row's valid prefix afterwards: every prefill, and every backend but
+    the TPU."""
     b, s, h, d = q.shape
     c = kbuf.shape[1]
     hkv = kbuf.shape[2]
-    kbuf = _write_kv(kbuf, kn.astype(kbuf.dtype), lens)
-    vbuf = _write_kv(vbuf, vn.astype(vbuf.dtype), lens)
     g = h // hkv
     qg = q.reshape(b, s, hkv, g, d).astype(jnp.float32)
     scale = 1.0 / math.sqrt(d)
@@ -100,8 +94,60 @@ def _cache_attention(q, kn, vn, kbuf, vbuf, lens):
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bkgsc,bckd->bskgd", probs,
                      vbuf.astype(jnp.float32))
-    return (out.reshape(b, s, h, d).astype(q.dtype), kbuf, vbuf,
-            lens + jnp.int32(s))
+    return out.reshape(b, s, h, d)
+
+
+def _kernel_backend():
+    """Where the one-token step attends through the Pallas kernel."""
+    return jax.default_backend() == "tpu"
+
+
+@functools.partial(jax.jit, static_argnames="interpret")
+@jax.named_scope("cache_attention")     # a profile names the kernel after it
+def _decode_kernel(q, kbuf, vbuf, lens, interpret):
+    """The kernel as a program of its own: the layers of a model call it
+    at one shape, so it is traced once a shape and not once a layer (in
+    line it costs 60 ms a layer, in every process; XLA inlines the
+    calls)."""
+    from paddle_tpu.ops.pallas.decode_attention import decode_attention
+    return decode_attention(q, kbuf, vbuf, lens, interpret=interpret)
+
+
+def _attend_decode_kernel(q, kbuf, vbuf, lens):
+    """The one-token step on a TPU: the length-aware kernel, which reads
+    each slot's valid blocks and nothing else. None where the call is not
+    its to take (s > 1, another backend) or its shape gate refuses; a
+    refusal is counted, as flash_attention_maybe counts its own."""
+    if q.shape[1] != 1 or not _kernel_backend():
+        return None
+    from paddle_tpu.ops.pallas.decode_attention import gate_reason
+    from paddle_tpu.ops.pallas.flash_attention import _count
+    reason = gate_reason(q.shape, kbuf.shape, kbuf.dtype)
+    if reason is not None:
+        _count("attn.dispatch_fallback", reason=reason)
+        return None
+    _count("attn.dispatch", kernel="decode_ragged")
+    return _decode_kernel(q, kbuf, vbuf, lens,
+                          interpret=jax.default_backend() != "tpu")
+
+
+@jax.named_scope("cache_attention")
+def _cache_attention(q, kn, vn, kbuf, vbuf, lens):
+    """Write-then-attend against a fixed-capacity cache.
+
+    q: [B, s, H, D] new queries; kn/vn: [B, s, Hkv, D] new keys/values;
+    kbuf/vbuf: [B, C, Hkv, D]; lens: [B] valid lengths BEFORE this call.
+    Returns (out [B, s, H, D], kbuf', vbuf', lens + s). GQA is handled by
+    grouping the query heads — the cache is never materialized at H heads.
+    One algorithm, two regimes: float32 products and sums over each row's
+    valid prefix, through the kernel where s == 1 on a TPU.
+    """
+    kbuf = _write_kv(kbuf, kn.astype(kbuf.dtype), lens)
+    vbuf = _write_kv(vbuf, vn.astype(vbuf.dtype), lens)
+    out = _attend_decode_kernel(q, kbuf, vbuf, lens)
+    if out is None:
+        out = _attend_einsum(q, kbuf, vbuf, lens)
+    return out.astype(q.dtype), kbuf, vbuf, lens + jnp.int32(q.shape[1])
 
 
 def _check_capacity(length, s_new, capacity):
@@ -545,6 +591,10 @@ class DecodeSession(_SessionLifecycle):
 
 
 
+# What a slot of the batched decode step is doing (_masked_step)
+_LANE_EMPTY, _LANE_STEPPING, _LANE_PAUSED = 0, 1, 2
+
+
 class _Phase:
     """One timed phase of the serving step: the span (a RecordEvent, so it
     shows in a running jax.profiler trace) and its seconds into a
@@ -573,7 +623,7 @@ class _Phase:
 
 class _Request:
     __slots__ = ("rid", "ids", "plen", "budget", "tokens", "slot",
-                 "t_submit", "t_admit", "t_first", "t_done", "state",
+                 "cached", "t_submit", "t_admit", "t_first", "t_done", "state",
                  "priority", "deadline", "ttft_deadline", "error")
 
     def __init__(self, rid, ids, plen, budget, priority=0,
@@ -582,6 +632,7 @@ class _Request:
         self.budget = budget
         self.tokens: List[int] = []
         self.slot = None
+        self.cached = 0     # positions of the slot's cache that hold tokens
         # lifecycle stamps, all perf_counter instants: submitted, admit
         # program dispatched, first token on the host, terminal
         # transition; the last three only while metrics are on
@@ -625,9 +676,10 @@ class ContinuousBatchingSession(_SessionLifecycle):
         cache rows out of the batch, run a b=1 prefill on the padded
         prompt, write the rows back at a TRACED slot index and deposit
         the first sampled token into the batched token vector;
-      * decode — ONE executable, always the full slot batch; retired /
-        empty slots are masked (their length is pinned so the cache
-        valid region never moves, and their token is passed through);
+      * decode — ONE executable, always the full slot batch; lanes that
+        do not step are masked (their token is passed through and their
+        length stays as it was; a lane with no request is shown to the
+        model at length 0, so attention reads one block of it);
       * retire — host-side: eos or budget exhaustion frees the slot,
         the next queued request is admitted into it on the next step.
 
@@ -674,7 +726,7 @@ class ContinuousBatchingSession(_SessionLifecycle):
         self._admit_jit = jax.jit(
             self._admit_pure,
             donate_argnums=tuple(range(n + 5, n + 5 + nc)))
-        # decode args: (*state, tokens, key, active, *caches)
+        # decode args: (*state, tokens, key, lane, *caches)
         self._decode_jit = jax.jit(
             self._decode_pure,
             donate_argnums=tuple(range(n + 3, n + 3 + nc)))
@@ -788,22 +840,29 @@ class ContinuousBatchingSession(_SessionLifecycle):
         return tokens, key, cache_arrays
 
     @jax.named_scope("decode_step")
-    def _masked_step(self, state, tok, key, active, cache_arrays):
+    def _masked_step(self, state, tok, key, lane, cache_arrays):
         """ONE masked decode step — the single home of the per-slot
-        semantics shared by the per-step and block programs: inactive
-        slots pass their token through and keep their cache length
-        pinned (their valid region must not move while they wait for
-        the next admission; the k/v rows the masked step wrote there
-        are dead — the next admit's prefill overwrites the slot from
-        position 0)."""
+        semantics shared by the per-step and block programs. ``lane``
+        says what each slot is doing (_LANE_*): only a stepping lane
+        takes its new token and length. Every other lane passes its
+        token through and keeps its length pinned. A paused lane (a
+        live request left out of a recovery probe) is shown to the
+        model as it is: the k/v row the step writes at its length is
+        dead, its own next step overwrites it. An empty lane is shown
+        at length 0, so attention reads one block of it and not the
+        retired request's whole text; the write lands at position 0 of
+        a slot that the next admit's prefill overwrites from 0."""
+        active = lane == _LANE_STEPPING
+        old = jax.tree_util.tree_unflatten(self._cache_treedef,
+                                           list(cache_arrays))
+        shown = [(k, v, jnp.where(lane == _LANE_EMPTY, 0, lo))
+                 for (k, v, lo) in old]
         logits, cache_out = _bind_and_run(
             self._model, self._state_t, state, tok[:, None],
-            self._cache_treedef, list(cache_arrays))
+            self._cache_treedef, jax.tree_util.tree_leaves(shown))
         nxt, key = _sample(logits[:, -1], key, self._temperature,
                            self._top_p, self._top_k)
         nxt = jnp.where(active, nxt, tok)
-        old = jax.tree_util.tree_unflatten(self._cache_treedef,
-                                           list(cache_arrays))
         new = jax.tree_util.tree_unflatten(self._cache_treedef,
                                            cache_out)
         fixed = [(k, v, jnp.where(active, ln, lo))
@@ -816,7 +875,7 @@ class ContinuousBatchingSession(_SessionLifecycle):
         caches)."""
         n = len(self._state_t)
         state = flat[:n]
-        tokens, key, active = flat[n:n + 3]
+        tokens, key, lane = flat[n:n + 3]
         cache_arrays = tuple(flat[n + 3:])
         blk = self._decode_block
         out0 = jnp.zeros((self._slots, blk), jnp.int32)
@@ -824,7 +883,7 @@ class ContinuousBatchingSession(_SessionLifecycle):
         def body(carry):
             i, tok, key, out, caches = carry
             nxt, key, fixed = self._masked_step(state, tok, key,
-                                                active, caches)
+                                                lane, caches)
             out = out.at[:, i].set(nxt)
             return (i + 1, nxt, key, out, tuple(fixed))
 
@@ -836,9 +895,9 @@ class ContinuousBatchingSession(_SessionLifecycle):
     def _decode_pure(self, *flat):
         n = len(self._state_t)
         state = flat[:n]
-        tokens, key, active = flat[n:n + 3]
+        tokens, key, lane = flat[n:n + 3]
         cache_arrays = flat[n + 3:]
-        return self._masked_step(state, tokens, key, active,
+        return self._masked_step(state, tokens, key, lane,
                                  cache_arrays)
 
     # ---------------- host-side slot management ----------------------
@@ -1008,26 +1067,36 @@ class ContinuousBatchingSession(_SessionLifecycle):
         """One decode dispatch for the given active-slot subset; on
         success the sampled tokens are committed to pending tagged
         with exactly that subset (drains credit only those slots)."""
-        active = np.zeros((self._slots,), bool)
-        active[list(slots)] = True
+        lane = np.full((self._slots,), _LANE_EMPTY, np.int8)
+        lane[list(self._running)] = _LANE_PAUSED
+        lane[list(slots)] = _LANE_STEPPING
+        steps = self._decode_block or 1
 
         def call():
             if self._decode_block:
                 return self._decode_blk_jit(
                     *state, self._tokens, self._key,
-                    jnp.asarray(active), *self._cache_arrays)
+                    jnp.asarray(lane), *self._cache_arrays)
             return self._decode_jit(
-                *state, self._tokens, self._key, jnp.asarray(active),
+                *state, self._tokens, self._key, jnp.asarray(lane),
                 *self._cache_arrays)
 
         with _Phase(RecordEvent("serving.dispatch", slots=len(slots)),
                     self._h_phase["dispatch"]):
             out = self._device_call("serving.decode_step",
                                     {"slots": slots}, call, retries)
+        stepped = [self._running[slot] for slot in slots]
         if _met._ENABLED:
+            r = _met.REGISTRY
             # every lane computes every step, whoever sits in it
-            _met.REGISTRY.counter("serving.decode_lane_steps").inc(
-                self._slots * (self._decode_block or 1))
+            r.counter("serving.decode_lane_steps").inc(self._slots * steps)
+            # the share of the cache a step has any reason to read
+            r.counter("serving.decode_cache_positions").inc(
+                steps * sum(req.cached for req in stepped))
+            r.counter("serving.decode_cache_capacity").inc(
+                self._slots * self._max_length * steps)
+        for req in stepped:
+            req.cached += steps
         if self._decode_block:
             blk_out, self._tokens, self._key, self._cache_arrays = out
             self._pending.append(("block", slots, blk_out))
@@ -1128,6 +1197,7 @@ class ContinuousBatchingSession(_SessionLifecycle):
                          error=f"{type(e).__name__}: {e}")
             return
         req.slot = slot
+        req.cached = req.plen
         req.state = RequestState.DECODING
         self._running[slot] = req
         if _met._ENABLED:

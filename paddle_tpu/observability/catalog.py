@@ -111,6 +111,12 @@ CATALOG = {
     "serving.decode_lane_steps": _m(
         "counter", "decode lane-steps dispatched: max_slots x steps, "
         "whether or not a lane held a request within its budget"),
+    "serving.decode_cache_positions": _m(
+        "counter", "cached positions of the stepping lanes at each decode "
+        "dispatch x its steps: what decode attention has a reason to read"),
+    "serving.decode_cache_capacity": _m(
+        "counter", "max_slots x capacity x steps at each decode dispatch: "
+        "the whole cache buffer, the denominator of the valid share"),
     "serving.step_s": _m("histogram", "wall time of one step()"),
     "serving.step_host_s": _m(
         "histogram", "step() less the seconds it waited in its fetch: "

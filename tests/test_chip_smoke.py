@@ -52,6 +52,25 @@ def test_serve_phase_tiny():
                                decode_block=4, n_requests=4,
                                prompt_range=(4, 12), budget_range=(4, 8))
     assert r["requests"] == 4 and r["first_diff"] != 0
+    assert r["dispatch"] == {}      # no kernel, and no fallback, on the CPU
+
+
+def test_serve_phase_tiny_asks_for_its_kernel():
+    # as in the train phase: every other check holds, and on the CPU the
+    # one-token step runs the einsums, so asking for the kernel fails
+    with pytest.raises(AssertionError, match="expected the 'decode_ragged'"):
+        chip_smoke.serve_phase(TINY, max_slots=2, max_length=64,
+                               decode_block=4, n_requests=2,
+                               prompt_range=(4, 12), budget_range=(4, 8),
+                               expect_kernel="decode_ragged")
+
+
+def test_decode_kernel_check_interpret():
+    assert chip_smoke.check_decode_kernel((4, 64, 2, 128),
+                                          interpret=True) <= 1e-5
+    with pytest.raises(AssertionError, match="off the float32 einsums"):
+        chip_smoke.check_decode_kernel((4, 64, 2, 128), interpret=True,
+                                       tol=0.0)
 
 
 def test_kernel_phase_interpret():

@@ -180,3 +180,79 @@ def test_decode_block_mode_same_outputs(tiny_model):
         np.testing.assert_array_equal(out[rid], _isolated(m, p, b),
                                       err_msg=f"request {rid}")
     assert sess.executable_counts()[1] == 1
+
+
+def _both_paths(monkeypatch, reqs, **session):
+    """Greedy tokens of ``reqs`` (prompt length, budget) through a two-slot
+    session of a tiny GQA model whose head_dim the kernel's shape gate
+    takes, first on the einsum path, then with the dispatcher's backend
+    predicate turned on (the CPU runs the kernel in interpret mode). Each
+    side: (tokens by request, the counters' window)."""
+    import paddle_tpu.observability as obs
+    from paddle_tpu.inference import decode
+
+    obs.enable()
+    paddle.seed(5)
+    m = LlamaForCausalLM(LlamaConfig(
+        vocab_size=256, hidden_size=256, intermediate_size=128,
+        num_layers=2, num_heads=2, num_kv_heads=1, max_seq_len=64))
+    rng = np.random.RandomState(17)
+    reqs = [(rng.randint(0, 256, (n,)).astype(np.int32), b) for n, b in reqs]
+
+    def run():
+        with obs.window() as w, ContinuousBatchingSession(
+                m, max_slots=2, max_length=64, **session) as sess:
+            rids = [sess.submit(p, b) for p, b in reqs]
+            out = sess.run()
+        return [out[r] for r in rids], w
+
+    ref = run()
+    monkeypatch.setattr(decode, "_kernel_backend", lambda: True)
+    return ref, run()
+
+
+def _kernel_took_every_step(w_ref, w):
+    from chip_smoke import _attn_counters
+    moved = _attn_counters(w.delta)
+    return (_attn_counters(w_ref.delta) == {}
+            and moved.get("attn.dispatch{kernel=decode_ragged}", 0) > 0
+            and not any(k.startswith("attn.dispatch_fallback")
+                        for k in moved))
+
+
+def test_decode_kernel_in_the_session_matches_the_einsum_path(monkeypatch):
+    """The one-token step through the length-aware Pallas kernel: greedy
+    tokens equal the einsum path's with an admission and two retirements
+    mid-run, the dispatch is counted and nothing falls back, and the two
+    cache counters give the share of the buffer that held tokens."""
+    (ref, w_ref), (got, w) = _both_paths(
+        monkeypatch, ((5, 9), (3, 5), (9, 5)), decode_block=4)
+    for a, b in zip(got, ref):
+        np.testing.assert_array_equal(a, b)
+    assert _kernel_took_every_step(w_ref, w)
+    # two blocks of 4 steps over 2 slots x 64: the first with prompts of
+    # 5 and 3 cached; the 3 retires at its budget of 5, the 9 is admitted
+    # into its slot, and the second block starts from 5 + 4 and 9
+    for win in (w_ref, w):
+        assert win.value("serving.decode_cache_positions") == \
+            4 * (5 + 3) + 4 * (9 + 9)
+        assert win.value("serving.decode_cache_capacity") == 2 * (2 * 64 * 4)
+
+
+@pytest.mark.parametrize("session", [
+    dict(decode_block=4), dict(decode_block=4, sync_every=2),
+    dict(sync_every=3)], ids=["block4", "block4_sync2", "sync3"])
+def test_decode_kernel_when_a_lane_steps_past_the_capacity(monkeypatch,
+                                                           session):
+    """A request that fills its slot to the last position (prompt + budget
+    - 1 == max_length) and whose budget ends inside a decode block: the
+    lane steps on, at lengths past the capacity, until the host retires
+    it, one or more dispatches later. The kernel then reads the whole slot
+    and no further, and every request's tokens equal the einsum path's;
+    the other slot admits and retires meanwhile."""
+    (ref, w_ref), (got, w) = _both_paths(
+        monkeypatch, ((50, 15), (3, 5), (9, 6)), **session)
+    assert [len(x) for x in ref] == [50 + 15, 3 + 5, 9 + 6]
+    for a, b in zip(got, ref):
+        np.testing.assert_array_equal(a, b)
+    assert _kernel_took_every_step(w_ref, w)

@@ -1,0 +1,176 @@
+"""The length-aware decode attention kernel (ops/pallas/decode_attention.py)
+against ``_cache_attention``'s einsum path, in float32 through the Pallas
+interpreter: ragged lengths around the block edges, lanes shown at length 0,
+poisoned positions past the valid length, the dispatch and its shape gate,
+and the kernel compiled for the v5e at the serving cells' cache shapes."""
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu.observability as obs
+from chip_smoke import _attn_counters
+from paddle_tpu.inference import decode
+from paddle_tpu.ops.pallas import decode_attention as da
+
+C, HKV, D, BLOCK = 64, 2, 128, 16
+#: one batch: empty, full, and both sides of a block edge
+RAGGED = (0, C - 1, BLOCK - 1, BLOCK, BLOCK + 1, 40)
+#: lengths at and past the capacity, the last slot's among them: what a lane
+#: reads when a decode block steps it past its budget (the write clamps to
+#: the last position, the mask takes all C)
+PAST_CAPACITY = (C, 5, C + BLOCK, C - 1, 2 * C + 3)
+
+
+def _inputs(lens, g, c=C, hkv=HKV, d=D, seed=0):
+    rng = np.random.RandomState(seed)
+    b = len(lens)
+
+    def draw(*shape):
+        return jnp.asarray(rng.randn(*shape), jnp.float32)
+    return (draw(b, 1, hkv * g, d), draw(b, 1, hkv, d), draw(b, 1, hkv, d),
+            draw(b, c, hkv, d), draw(b, c, hkv, d),
+            jnp.asarray(lens, jnp.int32))
+
+
+def _kernel(q, kbuf, vbuf, lens):
+    return da.decode_attention(q, kbuf, vbuf, lens, block=BLOCK, chunk=8,
+                               interpret=True)
+
+
+@pytest.mark.parametrize("lens", [RAGGED, (0,) * 4, (0, 33, 0, 0, 63),
+                                  PAST_CAPACITY],
+                         ids=["ragged", "all_at_0", "some_at_0",
+                              "past_capacity"])
+@pytest.mark.parametrize("g", [1, 4], ids=["mha", "gqa4"])
+def test_kernel_matches_the_einsum_path(g, lens):
+    q, kn, vn, kbuf, vbuf, lens = _inputs(lens, g)
+    ref, kbuf, vbuf, _ = decode._cache_attention(q, kn, vn, kbuf, vbuf, lens)
+    out = _kernel(q, kbuf, vbuf, lens)
+    assert out.dtype == jnp.float32 and out.shape == q.shape
+    assert float(jnp.max(jnp.abs(out - ref))) <= 1e-5
+
+
+@pytest.mark.parametrize("g", [1, 4], ids=["mha", "gqa4"])
+def test_nothing_past_the_valid_length_reaches_the_result(g):
+    q, kn, vn, kbuf, vbuf, lens = _inputs(RAGGED, g, seed=1)
+    ref, kbuf, vbuf, _ = decode._cache_attention(q, kn, vn, kbuf, vbuf, lens)
+    past = jnp.arange(C)[None, :, None, None] > lens[:, None, None, None]
+    out = _kernel(q, jnp.where(past, jnp.nan, kbuf),
+                  jnp.where(past, jnp.nan, vbuf), lens)
+    assert bool(jnp.all(jnp.isfinite(out)))
+    assert float(jnp.max(jnp.abs(out - ref))) <= 1e-5
+
+
+def test_sizes_come_from_the_cache_shape_in_code():
+    # the serving cells: 256 KB of K a block, 32 registers of scores a chunk
+    assert da.sizes(2048, 16, 128, 4) == (32, 16)
+    assert da.sizes(1024, 16, 128, 4) == (32, 16)
+    assert da.sizes(2048, 8, 128, 4, g=4) == (64, 8)    # GQA: g scores each
+    assert da.sizes(64, 2, 128, 4) == (64, 32)          # all of a tiny one
+    with pytest.raises(ValueError, match="must divide"):
+        da.decode_attention(*(_inputs((0,), 1)[i] for i in (0, 3, 4, 5)),
+                            block=48, interpret=True)
+
+
+@pytest.mark.parametrize("cache_shape, dtype, reason", [
+    ((12, 2048, 16, 128), jnp.float32, None),
+    ((24, 1024, 16, 128), jnp.float32, None),
+    ((8, 2048, 8, 128), jnp.float32, None),
+    ((2, 64, 2, 16), jnp.float32, "head_dim"),
+    ((2, 64, 2, 128), jnp.float16, "cache_dtype"),
+    ((8, 2048, 8, 128), jnp.bfloat16, "cache_dtype"),
+    ((2, 7, 1024, 128), jnp.float32, "vmem"),
+], ids=["longctx", "chat", "gqa", "head_dim", "float16", "bfloat16", "vmem"])
+def test_shape_gate(cache_shape, dtype, reason):
+    b, _c, hkv, d = cache_shape
+    assert da.gate_reason((b, 1, hkv, d), cache_shape, dtype) == reason
+
+
+def _attn_moves(window):
+    return _attn_counters(window.delta)
+
+
+@pytest.mark.parametrize("lens", [RAGGED, PAST_CAPACITY],
+                         ids=["ragged", "past_capacity"])
+def test_dispatch_takes_the_one_token_step_only(monkeypatch, lens):
+    """Off the TPU, and for s > 1 anywhere, ``_cache_attention`` runs the
+    einsums it ran before; with the backend predicate turned on, the
+    one-token call goes through the kernel, is counted, and returns the
+    same cache and lengths, bit for bit."""
+    obs.enable()
+    args = _inputs(lens, 2)
+    with obs.window() as w:
+        ref = decode._cache_attention(*args)
+    assert _attn_moves(w) == {}
+    monkeypatch.setattr(decode, "_kernel_backend", lambda: True)
+    with obs.window() as w:
+        got = decode._cache_attention(*args)
+    assert _attn_moves(w) == {"attn.dispatch{kernel=decode_ragged}": 1}
+    assert float(jnp.max(jnp.abs(got[0] - ref[0]))) <= 1e-5
+    for a, b in zip(got[1:], ref[1:]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # a prefill (s = 3) is not the kernel's
+    q, kn, vn, kbuf, vbuf, lens = args
+    pre = [jnp.tile(x, (1, 3, 1, 1)) for x in (q, kn, vn)]
+    with obs.window() as w:
+        decode._cache_attention(*pre, kbuf, vbuf, jnp.minimum(lens, C - 3))
+    assert _attn_moves(w) == {}
+
+
+def test_a_refused_shape_falls_back_and_is_counted(monkeypatch):
+    obs.enable()
+    args = _inputs((0, 5, 63), 2, d=16)
+    ref = decode._cache_attention(*args)
+    monkeypatch.setattr(decode, "_kernel_backend", lambda: True)
+    with obs.window() as w:
+        got = decode._cache_attention(*args)
+    assert _attn_moves(w) == {"attn.dispatch_fallback{reason=head_dim}": 1}
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(ref[0]))
+
+
+# ------------------------------------------- compiled for the chip, not run
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    # a compile for a described chip cannot be read back from the cache
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    cc.reset_cache()
+
+
+@pytest.mark.parametrize("b, c, hkv, g, dtype", [
+    (12, 2048, 16, 1, jnp.float32),
+    (24, 1024, 16, 1, jnp.float32),
+    (2, 512, 16, 1, jnp.float32),
+    (8, 2048, 8, 4, jnp.float32),
+    (8, 1024, 2, 2, jnp.float32),
+], ids=["longctx", "chat", "reference_check", "gqa4", "hkv2"])
+def test_kernel_compiles_for_the_v5e(one_chip, no_compile_cache, b, c, hkv,
+                                     g, dtype):
+    def spec(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    cache = spec((b, c, hkv, 128), dtype)
+    assert da.gate_reason((b, 1, hkv * g, 128), cache.shape, dtype) is None
+    compiled = jax.jit(functools.partial(da.decode_attention)).lower(
+        spec((b, 1, hkv * g, 128), jnp.bfloat16), cache, cache,
+        spec((b,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
