@@ -74,17 +74,22 @@ class RequestResult:
     there (or metrics were off, which is also the stamps' off switch):
     ``submit``, ``admit`` (its prefill program dispatched),
     ``first_token`` (its first token on the host) and ``done`` (its
-    terminal transition)."""
+    terminal transition). ``commit_steps``: under generation by diffusion
+    over blocks, for each generated token the denoising pass of its block
+    that fixed it (so tokens a pass is ``len / passes run``); None in the
+    autoregressive mode."""
 
-    __slots__ = ("state", "ids", "error", "timings")
+    __slots__ = ("state", "ids", "error", "timings", "commit_steps")
 
     def __init__(self, state: RequestState, ids: np.ndarray,
                  error: Optional[str] = None,
-                 timings: Optional[dict] = None):
+                 timings: Optional[dict] = None,
+                 commit_steps: Optional[np.ndarray] = None):
         self.state = state
         self.ids = ids
         self.error = error
         self.timings = timings
+        self.commit_steps = commit_steps
 
     @property
     def ok(self) -> bool:

@@ -75,10 +75,12 @@ def _write_kv(buf, new, lens):
     )(buf, new, lens)
 
 
-def _attend_einsum(q, kbuf, vbuf, lens):
+def _attend_einsum(q, kbuf, vbuf, lens, block=None):
     """q [B, s, H, D] against all C columns of the cache, masked to each
     row's valid prefix afterwards: every prefill, and every backend but
-    the TPU."""
+    the TPU. With ``block`` = B the prefix is the row's whole block of B
+    positions (blocks aligned at absolute multiples of B), so the rows of
+    one block see one another in both directions."""
     b, s, h, d = q.shape
     c = kbuf.shape[1]
     hkv = kbuf.shape[2]
@@ -89,7 +91,8 @@ def _attend_einsum(q, kbuf, vbuf, lens):
                         kbuf.astype(jnp.float32)) * scale
     col = jnp.arange(c)[None, None, None, None, :]
     row = jnp.arange(s)[None, None, None, :, None]
-    valid = col < (lens[:, None, None, None, None] + row + 1)
+    at = lens[:, None, None, None, None] + row
+    valid = col < (at + 1 if block is None else (at // block + 1) * block)
     logits = jnp.where(valid, logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bkgsc,bckd->bskgd", probs,
@@ -132,7 +135,7 @@ def _attend_decode_kernel(q, kbuf, vbuf, lens):
 
 
 @jax.named_scope("cache_attention")
-def _cache_attention(q, kn, vn, kbuf, vbuf, lens):
+def _cache_attention(q, kn, vn, kbuf, vbuf, lens, block=None):
     """Write-then-attend against a fixed-capacity cache.
 
     q: [B, s, H, D] new queries; kn/vn: [B, s, Hkv, D] new keys/values;
@@ -141,16 +144,36 @@ def _cache_attention(q, kn, vn, kbuf, vbuf, lens):
     grouping the query heads — the cache is never materialized at H heads.
     One algorithm, two regimes: float32 products and sums over each row's
     valid prefix, through the kernel where s == 1 on a TPU.
+
+    ``block`` = B is the block form (generation by diffusion over blocks):
+    row i sees column j iff j // B <= (lens + i) // B. One rule serves the
+    prefill (lens 0) and a block pass (lens a multiple of B, s = B: every
+    row sees all B new columns). A caller whose pass is not a commit keeps
+    the buffers and drops the advanced length: the rows written past it
+    are dead until the commit pass overwrites them.
     """
     kbuf = _write_kv(kbuf, kn.astype(kbuf.dtype), lens)
     vbuf = _write_kv(vbuf, vn.astype(vbuf.dtype), lens)
-    out = _attend_decode_kernel(q, kbuf, vbuf, lens)
+    out = None if block is not None else \
+        _attend_decode_kernel(q, kbuf, vbuf, lens)
     if out is None:
-        out = _attend_einsum(q, kbuf, vbuf, lens)
+        out = _attend_einsum(q, kbuf, vbuf, lens, block)
     return out.astype(q.dtype), kbuf, vbuf, lens + jnp.int32(q.shape[1])
 
 
-def _check_capacity(length, s_new, capacity):
+def block_attention(q, kn, vn, block, cache=None):
+    """The block form on raw arrays, for a model's layer to call inside
+    its own op: q [B, s, H, D], kn/vn [B, s, Hkv, D] under the block mask
+    of ``block`` positions. ``cache``: None (the s positions are the whole
+    sequence, from 0) or (kbuf, vbuf, lens). Returns (out, cache')."""
+    if cache is None:
+        lens = jnp.zeros((q.shape[0],), jnp.int32)
+        return _attend_einsum(q, kn, vn, lens, block).astype(q.dtype), None
+    out, *cache = _cache_attention(q, kn, vn, *cache, block=block)
+    return out, tuple(cache)
+
+
+def check_capacity(length, s_new, capacity):
     """Eager misuse guard: writing past capacity would silently clamp
     (dynamic_update_slice semantics) and corrupt the newest cache slot.
     Lengths are concrete in eager mode — check them; under a trace
@@ -172,13 +195,15 @@ def _check_capacity(length, s_new, capacity):
             f"{top - s_new} exceeds capacity {capacity}")
 
 
-def cache_attention(q, k_new, v_new, cache: StaticCache):
+def cache_attention(q, k_new, v_new, cache: StaticCache, block=None):
     """Eager-op wrapper: attend q against (cache ++ new kv), updating the
     cache in place. Returns (out, new_cache). Not differentiable (serving
-    path)."""
-    _check_capacity(cache.length, q.shape[1], cache.k.shape[1])
+    path). ``block``: the block form of ``_cache_attention``."""
+    check_capacity(cache.length, q.shape[1], cache.k.shape[1])
+    fn = _cache_attention if block is None else \
+        functools.partial(_cache_attention, block=int(block))
     out, k2, v2, l2 = run_op(
-        "masked_cache_attention", _cache_attention, q, k_new, v_new,
+        "masked_cache_attention", fn, q, k_new, v_new,
         cache.k, cache.v, cache.length, n_outputs=4, differentiable=False)
     return out, StaticCache(k2, v2, l2)
 
@@ -191,7 +216,7 @@ def masked_multihead_attention_impl(x, cache_kv, seq_lens, num_heads,
     (the reference's cache layout); seq_lens: [B] int32 lengths before this
     step. Returns (out [B, H*D], new cache_kv).
     """
-    _check_capacity(seq_lens, 1, (cache_kv.shape[3] if hasattr(
+    check_capacity(seq_lens, 1, (cache_kv.shape[3] if hasattr(
         cache_kv, "shape") else cache_kv._data.shape[3]))
 
     def f(xa, ck, lens):
@@ -268,9 +293,12 @@ def _collect_model_state(model):
 
 
 def _bind_and_run(model, state_tensors, state_arrays, ids_arr,
-                  cache_treedef, cache_arrays):
+                  cache_treedef, cache_arrays, with_expert_load=None):
     """Rebind traced state into the live model and run its cached
-    forward (the jit.StaticFunction discipline, serving-only)."""
+    forward (the jit.StaticFunction discipline, serving-only).
+    ``with_expert_load``: a lane mask; the model's
+    ``forward_with_expert_load`` is run instead and its load vector
+    returned third."""
     import paddle_tpu as paddle
     saved = [t._data for t in state_tensors]
     try:
@@ -280,13 +308,19 @@ def _bind_and_run(model, state_tensors, state_arrays, ids_arr,
             cache_treedef,
             [Tensor._wrap(a, True) for a in cache_arrays])
         caches = [StaticCache(*c) for c in caches]
+        ids = Tensor._wrap(ids_arr, True)
         with paddle.no_grad():
-            logits, caches = model.forward_with_cache(
-                Tensor._wrap(ids_arr, True), caches)
+            if with_expert_load is None:
+                logits, caches = model.forward_with_cache(ids, caches)
+            else:
+                logits, caches, load = model.forward_with_expert_load(
+                    ids, caches, with_expert_load)
         cache_out = [a._data for a in jax.tree_util.tree_leaves(
             [tuple(c) for c in caches],
             is_leaf=lambda x: isinstance(x, Tensor))]
-        return logits._data, cache_out
+        if with_expert_load is None:
+            return logits._data, cache_out
+        return logits._data, cache_out, load
     finally:
         for t, s in zip(state_tensors, saved):
             t._data = s
@@ -624,13 +658,16 @@ class _Phase:
 class _Request:
     __slots__ = ("rid", "ids", "plen", "budget", "tokens", "slot",
                  "cached", "t_submit", "t_admit", "t_first", "t_done", "state",
-                 "priority", "deadline", "ttft_deadline", "error")
+                 "priority", "deadline", "ttft_deadline", "error",
+                 "commit_steps")
 
     def __init__(self, rid, ids, plen, budget, priority=0,
                  deadline_s=None, ttft_deadline_s=None):
         self.rid, self.ids, self.plen = rid, ids, plen
         self.budget = budget
         self.tokens: List[int] = []
+        # block diffusion: for each token the denoising pass that fixed it
+        self.commit_steps: List[int] = []
         self.slot = None
         self.cached = 0     # positions of the slot's cache that hold tokens
         # lifecycle stamps, all perf_counter instants: submitted, admit
@@ -688,6 +725,38 @@ class ContinuousBatchingSession(_SessionLifecycle):
     stream; with temperature=0 (default) outputs are bit-identical to
     isolated DecodeSession runs (asserted in
     tests/test_continuous_batching.py).
+
+    ``generation="block_diffusion"`` (a model that generates by diffusion
+    over blocks, ``models/sdar_moe.py``; SDAR's
+    ``block_diffusion_generate``): the text grows a block of the model's
+    ``block_length`` = B positions at a time, blocks aligned at absolute
+    multiples of B, under the model's block mask. The model also gives
+    ``mask_token_id`` and, if it counts its experts' load,
+    ``forward_with_expert_load``/``count_expert_load``.
+
+      * admit  — prefills the prompt's whole blocks, ``(plen // B) * B``
+        tokens, and samples nothing; the ``plen % B`` tokens left open
+        the first block, already fixed;
+      * decode — ONE executable, one dispatch = one block for every
+        stepping lane: ``denoising_steps`` = T passes over ``[slots, B]``
+        ids whose open positions hold the mask token, each fixing the most
+        confident open positions (``remasking``: ``low_confidence_static``
+        fixes ``B // T`` a pass, SDAR's ``get_num_transfer_tokens``;
+        ``low_confidence_dynamic`` every open position whose confidence is
+        over ``confidence_threshold`` where those are at least that many),
+        then one commit pass that writes the block's K/V and advances the
+        lengths by B. A denoising pass keeps no K/V: its rows lie past the
+        length and the commit pass overwrites them. A lane with nothing
+        open passes through unchanged, so a request's tokens are those of
+        the published b=1 loop;
+      * a dispatch yields up to B tokens a lane, delivered in position
+        order; what passes the budget is dropped. ``RequestResult.
+        commit_steps`` gives for each token the pass that fixed it.
+
+    Departures from the published loop: the mask token's logit is held at
+    -inf when sampling; open-ness is a boolean kept beside the ids; only
+    open positions are ever fixed; confidences that tie go to the lower
+    position.
     """
 
     def __init__(self, model, max_slots, max_length,
@@ -697,7 +766,10 @@ class ContinuousBatchingSession(_SessionLifecycle):
                  max_queue=None, shed_policy="reject_newest",
                  default_deadline_s=None, default_ttft_s=None,
                  step_retries=2, step_backoff_s=0.02,
-                 degraded_queue_frac=0.8):
+                 degraded_queue_frac=0.8,
+                 generation="autoregressive", denoising_steps=None,
+                 remasking="low_confidence_static",
+                 confidence_threshold=0.9):
         model.eval()
         self._model = model
         self._slots = int(max_slots)
@@ -768,6 +840,12 @@ class ContinuousBatchingSession(_SessionLifecycle):
             self._decode_blk_jit = jax.jit(
                 self._decode_block_pure,
                 donate_argnums=tuple(range(n + 3, n + 3 + nc)))
+        self._block_length = None
+        if generation == "block_diffusion":
+            self._init_block_diffusion(denoising_steps, remasking,
+                                       confidence_threshold)
+        elif generation != "autoregressive":
+            raise ValueError(f"unknown generation mode {generation!r}")
         # robustness knobs (ISSUE 14): bounded-queue admission control
         # with a pluggable shedding policy, per-request deadline
         # defaults, and the device-step retry envelope
@@ -900,6 +978,208 @@ class ContinuousBatchingSession(_SessionLifecycle):
         return self._masked_step(state, tokens, key, lane,
                                  cache_arrays)
 
+    # ---------------- generation by diffusion over blocks -------------
+    def _init_block_diffusion(self, denoising_steps, remasking,
+                              confidence_threshold):
+        model = self._model
+        blk = int(model.block_length)       # the model's block mask's
+        if self._decode_block:
+            raise ValueError("decode_block is the autoregressive mode's; "
+                             "a block-diffusion dispatch is one block")
+        if remasking not in ("low_confidence_static",
+                             "low_confidence_dynamic"):
+            raise ValueError(f"unknown remasking {remasking!r}")
+        steps = int(denoising_steps or blk)
+        if not 1 <= steps <= blk:
+            raise ValueError("denoising_steps must lie in 1..block_length")
+        self._block_length, self._denoising_steps = blk, steps
+        self._remasking = remasking
+        self._confidence_threshold = float(confidence_threshold)
+        self._mask_token_id = int(model.mask_token_id)
+        # SDAR's get_num_transfer_tokens: positions fixed by pass t
+        self._transfer_counts = [blk // steps + (t < blk % steps)
+                                 for t in range(steps)]
+        # a mixture of experts counts its own load: on the device beside
+        # the pass (forward_with_expert_load gives EXPERT_LOAD_LEN int32
+        # sums, added up over a dispatch's passes), on the host at
+        # delivery (count_expert_load ticks them)
+        self._expert_load_len = getattr(model, "EXPERT_LOAD_LEN", 0)
+        n, nc = len(self._state_t), len(self._cache_arrays)
+        # admit args: (*state, ids, plen, slot, *caches)
+        self._admit_blk_jit = jax.jit(
+            self._admit_block_pure,
+            donate_argnums=tuple(range(n + 3, n + 3 + nc)))
+        # block args: (*state, ids, open, key, lane, *caches)
+        self._block_jit = jax.jit(
+            self._block_pure,
+            donate_argnums=tuple(range(n + 4, n + 4 + nc)))
+
+    @jax.named_scope("admit")
+    def _admit_block_pure(self, *flat):
+        """Block mode's admit: a b=1 prefill of the prompt's whole blocks
+        under the model's block mask into ``slot``; nothing is sampled
+        (the unused head is not computed)."""
+        n = len(self._state_t)
+        state = flat[:n]
+        ids, plen, slot = flat[n:n + 3]
+        cache_arrays = flat[n + 3:]
+        slot_leaves = self._slot_slice(cache_arrays, slot)
+        _logits, slot_out = _bind_and_run(
+            self._model, self._state_t, state, ids,
+            self._cache_treedef, slot_leaves)
+        return self._slot_unslice(cache_arrays, slot_out, slot, plen)
+
+    def _block_pure(self, *flat):
+        """One block for every stepping lane in ONE program: the
+        denoising passes (a fori_loop) and the commit pass. Returns the
+        block's ids, for each position the pass that fixed it
+        (``denoising_steps`` where it was not open) and the expert load,
+        packed into one int32 vector so that one transfer fetches them."""
+        n = len(self._state_t)
+        state = flat[:n]
+        ids, is_open, key, lane = flat[n:n + 4]
+        cache_arrays = tuple(flat[n + 4:])
+        blk, steps = self._block_length, self._denoising_steps
+        active = lane == _LANE_STEPPING
+        old = jax.tree_util.tree_unflatten(self._cache_treedef,
+                                           list(cache_arrays))
+        # an empty lane is shown at length 0, as in _masked_step
+        shown = [jnp.where(lane == _LANE_EMPTY, 0, lo)
+                 for (_k, _v, lo) in old]
+        counts = jnp.asarray(self._transfer_counts, jnp.int32)
+
+        def run_pass(ids, caches):
+            """The model over the block at the lengths shown, whatever
+            length an earlier pass left in ``caches``."""
+            layers = jax.tree_util.tree_unflatten(self._cache_treedef,
+                                                  list(caches))
+            layers = [(k, v, ln) for (k, v, _l), ln in zip(layers, shown)]
+            logits, cache_out, *load = _bind_and_run(
+                self._model, self._state_t, state, ids, self._cache_treedef,
+                jax.tree_util.tree_leaves(layers),
+                with_expert_load=active if self._expert_load_len else None)
+            return logits, tuple(cache_out), \
+                load[0] if load else jnp.zeros((0,), jnp.int32)
+
+        @jax.named_scope("block_denoise")
+        def denoise(t, carry):
+            ids, is_open, fixed_at, key, load, caches = carry
+            logits, caches, load_t = run_pass(ids, caches)
+            vocab = jnp.arange(logits.shape[-1])
+            logits = jnp.where(vocab == self._mask_token_id, -jnp.inf,
+                               logits.astype(jnp.float32))
+            x0, key = _sample(logits, key, self._temperature, self._top_p,
+                              self._top_k)
+            conf = jnp.exp(
+                jnp.take_along_axis(logits, x0[..., None], -1)[..., 0]
+                - jax.nn.logsumexp(logits, axis=-1))
+            conf = jnp.where(is_open, conf, -jnp.inf)
+            rank = jnp.argsort(jnp.argsort(-conf, axis=-1, stable=True),
+                               axis=-1)
+            move = is_open & (rank < counts[t])
+            if self._remasking == "low_confidence_dynamic":
+                high = is_open & (conf > self._confidence_threshold)
+                enough = jnp.sum(high, -1, keepdims=True) >= counts[t]
+                move = jnp.where(enough, high, move)
+            move = move & active[:, None]
+            return (jnp.where(move, x0, ids), is_open & ~move,
+                    jnp.where(move, t, fixed_at), key, load + load_t, caches)
+
+        carry = (ids, is_open, jnp.full(ids.shape, steps, jnp.int32), key,
+                 jnp.zeros((self._expert_load_len,), jnp.int32),
+                 cache_arrays)
+        ids, _open, fixed_at, key, load, caches = lax.fori_loop(
+            0, steps, denoise, carry)
+        with jax.named_scope("block_commit"):
+            _logits, caches, load_c = run_pass(ids, caches)
+        new = jax.tree_util.tree_unflatten(self._cache_treedef,
+                                           list(caches))
+        fixed = [(k, v, jnp.where(active, lo + blk, lo))
+                 for (k, v, _l), (_k, _v, lo) in zip(new, old)]
+        out = jnp.concatenate([ids.reshape(-1), fixed_at.reshape(-1),
+                               load + load_c])
+        return out, key, jax.tree_util.tree_leaves(fixed)
+
+    def _dispatch_block(self, state, slots, lane, retries):
+        """Block mode's ``_dispatch_once``: the host builds each stepping
+        lane's block (the prompt's remainder opens a request's first
+        block, every later block is all open) and dispatches it."""
+        blk = self._block_length
+        passes = self._denoising_steps + 1
+        ids = np.full((self._slots, blk), self._mask_token_id, np.int32)
+        is_open = np.zeros((self._slots, blk), bool)
+        starts = []
+        for slot in slots:
+            req = self._running[slot]
+            held = max(0, min(blk, req.plen - req.cached))
+            ids[slot, :held] = req.ids[req.cached:req.cached + held]
+            is_open[slot, held:] = True
+            starts.append((slot, held))
+
+        def call():
+            return self._block_jit(
+                *state, jnp.asarray(ids), jnp.asarray(is_open), self._key,
+                jnp.asarray(lane), *self._cache_arrays)
+
+        with _Phase(RecordEvent("serving.dispatch", slots=len(slots),
+                                passes=passes),
+                    self._h_phase["dispatch"]):
+            out, self._key, self._cache_arrays = self._device_call(
+                "serving.decode_step", {"slots": slots}, call, retries)
+        if _met._ENABLED:
+            r = _met.REGISTRY
+            r.counter("serving.block_dispatches").inc()
+            # every lane computes every pass, whoever sits in it
+            r.counter("serving.block_lane_passes").inc(self._slots * passes)
+            r.counter("serving.block_open_positions").inc(
+                int(is_open.sum()))
+        for slot in slots:
+            self._running[slot].cached += blk
+        self._pending.append(("blocks", tuple(starts), out))
+
+    def _deliver_blocks(self, starts, row, now):
+        """One fetched block dispatch: each lane's tokens from its first
+        open position on, in position order; what its request no longer
+        takes (past the budget, after eos) is dropped. Returns (tokens
+        delivered, requests whose first token this was)."""
+        lanes = self._slots * self._block_length
+        shape = (self._slots, self._block_length)
+        tokens = row[:lanes].reshape(shape)
+        fixed_at = row[lanes:2 * lanes].reshape(shape)
+        delivered = first = dropped = 0
+        for slot, held in starts:
+            for col in range(held, self._block_length):
+                req = self._running.get(slot)
+                if req is None:
+                    dropped += 1
+                    continue
+                if not req.tokens:
+                    first += 1
+                    if now is not None:
+                        self._stamp_first_token(req, now)
+                req.tokens.append(int(tokens[slot, col]))
+                req.commit_steps.append(int(fixed_at[slot, col]))
+                delivered += 1
+                self._maybe_retire(req)
+        if _met._ENABLED:
+            r = _met.REGISTRY
+            if dropped:
+                r.counter("serving.block_discarded_tokens").inc(dropped)
+        if self._expert_load_len:
+            self._model.count_expert_load(row[2 * lanes:])
+        return delivered, first
+
+    def generated(self, request_id):
+        """Tokens of the request that have reached the host so far; None
+        for unknown (or already-delivered) ids."""
+        req = self._done.get(request_id)
+        if req is not None:
+            return len(req.tokens)
+        for req in (*self._running.values(), *self._queue):
+            if req.rid == request_id:
+                return len(req.tokens)
+        return None
+
     # ---------------- host-side slot management ----------------------
     def submit(self, input_ids, max_new_tokens, request_id=None,
                priority=0, deadline_s=None, ttft_deadline_s=None):
@@ -916,7 +1196,11 @@ class ContinuousBatchingSession(_SessionLifecycle):
         ids = np.asarray(
             input_ids._data if isinstance(input_ids, Tensor)
             else input_ids).reshape(-1).astype(np.int32)
-        if ids.size + max_new_tokens - 1 > self._max_length:
+        need = ids.size + max_new_tokens - 1
+        if self._block_length:
+            # the last block is committed whole
+            need = -(-(need + 1) // self._block_length) * self._block_length
+        if need > self._max_length:
             raise ValueError(
                 f"prompt ({ids.size}) + {max_new_tokens} new tokens "
                 f"exceeds the cache capacity {self._max_length}")
@@ -1070,6 +1354,8 @@ class ContinuousBatchingSession(_SessionLifecycle):
         lane = np.full((self._slots,), _LANE_EMPTY, np.int8)
         lane[list(self._running)] = _LANE_PAUSED
         lane[list(slots)] = _LANE_STEPPING
+        if self._block_length:
+            return self._dispatch_block(state, slots, lane, retries)
         steps = self._decode_block or 1
 
         def call():
@@ -1165,20 +1451,32 @@ class ContinuousBatchingSession(_SessionLifecycle):
             slot = self._free.pop()
             req.state = RequestState.PREFILLING
             bucket = next((b for b in self._buckets
-                           if b >= req.plen), self._max_length)
+                           if b >= self._prefill_len(req)),
+                          self._max_length)
             with _Phase(RecordEvent("serving.admit", rid=req.rid,
                                     slot=slot, plen=req.plen,
                                     bucket=bucket),
                         self._h_phase["admit"]):
                 self._admit_one(state, req, slot, bucket)
 
+    def _prefill_len(self, req):
+        """Prompt tokens the admit program prefills: all of them, or in
+        block mode the prompt's whole blocks."""
+        blk = self._block_length
+        return req.plen // blk * blk if blk else req.plen
+
     def _admit_one(self, state, req, slot, bucket):
         """Pad the prompt to its bucket and dispatch the admit program
         (a b=1 prefill into ``slot``)."""
+        plen = self._prefill_len(req)
         padded = jnp.asarray(
-            np.pad(req.ids, (0, bucket - req.plen))[None])
+            np.pad(req.ids[:plen], (0, bucket - plen))[None])
 
         def call():
+            if self._block_length:
+                return self._tokens, self._key, self._admit_blk_jit(
+                    *state, padded, jnp.int32(plen), jnp.int32(slot),
+                    *self._cache_arrays)
             return self._admit_jit(
                 *state, padded, jnp.int32(req.plen),
                 jnp.int32(slot), self._tokens, self._key,
@@ -1197,13 +1495,13 @@ class ContinuousBatchingSession(_SessionLifecycle):
                          error=f"{type(e).__name__}: {e}")
             return
         req.slot = slot
-        req.cached = req.plen
+        req.cached = plen
         req.state = RequestState.DECODING
         self._running[slot] = req
         if _met._ENABLED:
             r = _met.REGISTRY
             r.counter("serving.admits").inc()
-            r.counter("serving.prefill_tokens").inc(req.plen)
+            r.counter("serving.prefill_tokens").inc(plen)
             r.counter("serving.prefill_padded_tokens").inc(bucket)
             req.t_admit = time.perf_counter()
             r.histogram("serving.queue_wait_s").observe(
@@ -1214,8 +1512,10 @@ class ContinuousBatchingSession(_SessionLifecycle):
         # blocking RTT per admission — the cost sync_every exists
         # to amortize). The tagged entry applies to THIS slot only:
         # the other lanes of the vector hold already-consumed
-        # decode tokens.
-        self._pending.append(("admit", slot, self._tokens))
+        # decode tokens. A block-mode admit samples nothing: the first
+        # tokens come with the request's first block.
+        if not self._block_length:
+            self._pending.append(("admit", slot, self._tokens))
 
     def _maybe_retire(self, req):
         if (len(req.tokens) >= req.budget
@@ -1250,6 +1550,11 @@ class ContinuousBatchingSession(_SessionLifecycle):
             # quarantine) between dispatch and drain are skipped, and
             # recovery probes over subsets credit exactly their subset
             row = np.asarray(row)
+            if kind == "blocks":
+                n_tok, n_first = self._deliver_blocks(ainfo, row, now)
+                delivered += n_tok
+                first += n_first
+                continue
             if kind == "admit":
                 req = self._running.get(ainfo)
                 if req is not None:
@@ -1352,7 +1657,9 @@ class ContinuousBatchingSession(_SessionLifecycle):
                    req.state,
                    np.concatenate([req.ids,
                                    np.asarray(req.tokens, np.int32)]),
-                   req.error, req.timings())
+                   req.error, req.timings(),
+                   np.asarray(req.commit_steps, np.int32)
+                   if self._block_length else None)
                for rid, req in self._done.items()}
         self._done = {}
         # delivered ids leave the in-flight set: a serving loop calling
@@ -1404,6 +1711,9 @@ class ContinuousBatchingSession(_SessionLifecycle):
         bounded by the bucket count, decode must stay 1 however many
         requests flow through (in block mode the block program is THE
         decode executable)."""
+        if self._block_length:
+            return (self._admit_blk_jit._cache_size(),
+                    self._block_jit._cache_size())
         n_dec = self._decode_jit._cache_size()
         if self._decode_block:
             n_dec += self._decode_blk_jit._cache_size()

@@ -43,26 +43,42 @@ class LlamaConfig:
                            num_kv_heads=2, max_seq_len=64)
 
 
-def apply_rotary_pos_emb(x, position_offset=0, theta=10000.0):
+def apply_rotary_pos_emb(x, position_offset=0, theta=10000.0,
+                         half_split=False):
     """RoPE on [B, S, H, D] (reference:
     incubate/nn/functional/fused_rotary_position_embedding.py).
     position_offset may be a python int or a [B] int32 tensor (the decode
-    path's per-sequence cache lengths)."""
-    def f(a, off):
-        b, s, h, d = a.shape
-        pos = (off.reshape(-1, 1).astype(jnp.float32)
-               + jnp.arange(s, dtype=jnp.float32)[None, :])   # [B|1, S]
-        inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-        freqs = pos[..., None] * inv                   # [B|1, S, D/2]
-        cos = jnp.cos(freqs)[:, :, None, :]
-        sin = jnp.sin(freqs)[:, :, None, :]
+    path's per-sequence cache lengths). By default the rotated pairs are
+    the interleaved columns (2i, 2i+1); ``half_split`` pairs column i with
+    column i + D/2 (``x * cos + rotate_half(x) * sin``, the Hugging Face
+    layout), which is the interleaved form up to a fixed permutation of
+    the columns."""
+    return run_op("rope", lambda a, off: rope(a, off, theta, half_split),
+                  x, position_offset)
+
+
+def rope(a, off, theta, half_split=False):
+    """The rotation on raw arrays (``apply_rotary_pos_emb``'s body)."""
+    b, s, h, d = a.shape
+    pos = (off.reshape(-1, 1).astype(jnp.float32)
+           + jnp.arange(s, dtype=jnp.float32)[None, :])   # [B|1, S]
+    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    freqs = pos[..., None] * inv                   # [B|1, S, D/2]
+    cos = jnp.cos(freqs)[:, :, None, :]
+    sin = jnp.sin(freqs)[:, :, None, :]
+    if half_split:
+        x1 = a[..., :d // 2].astype(jnp.float32)
+        x2 = a[..., d // 2:].astype(jnp.float32)
+    else:
         x1 = a[..., 0::2].astype(jnp.float32)
         x2 = a[..., 1::2].astype(jnp.float32)
-        o1 = x1 * cos - x2 * sin
-        o2 = x2 * cos + x1 * sin
+    o1 = x1 * cos - x2 * sin
+    o2 = x2 * cos + x1 * sin
+    if half_split:
+        out = jnp.concatenate([o1, o2], axis=-1)
+    else:
         out = jnp.stack([o1, o2], axis=-1).reshape(a.shape)
-        return out.astype(a.dtype)
-    return run_op("rope", f, x, position_offset)
+    return out.astype(a.dtype)
 
 
 class LlamaAttention(nn.Layer):

@@ -8,6 +8,12 @@ TPU-native: GShard dense-dispatch einsums with the expert dim sharded over
 the 'dp' (expert-parallel) mesh axis; XLA partitions the dispatch/combine
 einsums into all-to-alls over ICI. Top-1 (switch) and top-2 (gshard)
 gating with capacity + load-balancing aux loss.
+
+These are the TRAINING layers: a capacity drops tokens and every expert
+multiplies every token (``einsum("te,th->eth")``). The dropless layer
+(softmax over all experts, top-k renormalised, no capacity, sorted pairs
+through a grouped product so that only routed pairs are multiplied) is
+``models/sdar_moe.expert_ffn``; serving uses that one.
 """
 from __future__ import annotations
 

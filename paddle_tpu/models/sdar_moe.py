@@ -1,0 +1,369 @@
+"""SDAR-MoE decoder LM (``model_type: sdar_moe``; SDAR-30B-A3B-Chat,
+https://huggingface.co/JetLM/SDAR-30B-A3B-Chat): a Qwen3-MoE-shaped decoder
+that generates by diffusion over blocks.
+
+Every layer: RMSNorm, GQA attention with RMSNorm over each head of q and k
+(QK-norm) and half-split RoPE, under the BLOCK mask (position i sees j iff
+``j // B <= i // B``: a block of B positions sees itself in both directions
+and everything before it), then RMSNorm and a mixture of ``num_experts``
+SwiGLU experts without biases: softmax over all experts in float32, the
+``num_experts_per_tok`` largest, renormalised, DROPLESS (no capacity, no
+auxiliary loss: this is the serving layer).
+
+The expert layer holds its experts stacked and is told which it holds
+(``expert_offset``, ``num_local_experts``): it routes over all of them and
+computes its own experts' part of the result, which is what expert
+parallelism asks of it; on one chip that holds all of them it is the whole
+layer. Only routed (token, expert) pairs are multiplied: the pairs are
+sorted by expert and go through a grouped product (``_grouped``), never
+the ``[E, T, H]`` dispatch of ``models/moe.py``.
+
+Serving contract (``ContinuousBatchingSession(generation=
+"block_diffusion")``): ``init_cache``, ``forward_with_cache``,
+``block_length``, ``mask_token_id``, and for the expert load
+``EXPERT_LOAD_LEN``, ``forward_with_expert_load``, ``count_expert_load``.
+"""
+from __future__ import annotations
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+import paddle_tpu as paddle
+from paddle_tpu import nn
+from paddle_tpu.core.dispatch import run_op
+from paddle_tpu.models.llama import rope
+from paddle_tpu.nn import initializer as I
+from paddle_tpu.observability import metrics as _met
+
+
+@dataclasses.dataclass
+class SDARMoeConfig:
+    """The keys of the model's public ``config.json``, and what a holder of
+    a share of it needs besides. Keys the layer equations do not read are
+    kept so that the file can be passed whole; ``__post_init__`` refuses
+    the values this implementation does not compute."""
+    vocab_size: int = 151936
+    hidden_size: int = 2048
+    intermediate_size: int = 6144        # dense width; no layer is dense
+    moe_intermediate_size: int = 768
+    num_hidden_layers: int = 48
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 4
+    head_dim: int = 128
+    num_experts: int = 128
+    num_experts_per_tok: int = 8
+    norm_topk_prob: bool = True
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 1000000.0
+    max_position_embeddings: int = 32768
+    attention_bias: bool = False
+    decoder_sparse_step: int = 1
+    mlp_only_layers: tuple = ()
+    hidden_act: str = "silu"
+    tie_word_embeddings: bool = False
+    rope_scaling: object = None
+    sliding_window: object = None
+    use_sliding_window: bool = False
+    max_window_layers: int = 48
+    model_type: str = "sdar_moe"
+    # not in config.json: the release's generation defaults
+    block_length: int = 4
+    mask_token_id: int = 151669
+    # the experts held here, of num_experts (None: all of them)
+    num_local_experts: int = None
+    expert_offset: int = 0
+    param_dtype: str = "float32"
+    initializer_range: float = 0.02
+
+    def __post_init__(self):
+        if self.num_local_experts is None:
+            self.num_local_experts = self.num_experts - self.expert_offset
+        unsupported = {
+            "attention_bias": self.attention_bias,
+            "mlp_only_layers": bool(self.mlp_only_layers),
+            "rope_scaling": self.rope_scaling is not None,
+            "use_sliding_window": self.use_sliding_window,
+            "tie_word_embeddings": self.tie_word_embeddings,
+            "decoder_sparse_step": self.decoder_sparse_step != 1,
+            "hidden_act": self.hidden_act != "silu"}
+        bad = [k for k, v in unsupported.items() if v]
+        if bad:
+            raise ValueError(f"SDARMoeConfig: not supported here: {bad}")
+        if not 0 <= self.expert_offset <= self.expert_offset \
+                + self.num_local_experts <= self.num_experts:
+            raise ValueError("experts held must lie within num_experts")
+
+    @staticmethod
+    def tiny(**kw):
+        base = dict(vocab_size=160, hidden_size=32, moe_intermediate_size=16,
+                    num_hidden_layers=2, num_attention_heads=4,
+                    num_key_value_heads=2, head_dim=8, num_experts=8,
+                    num_experts_per_tok=2, max_position_embeddings=128,
+                    mask_token_id=159)
+        base.update(kw)
+        return SDARMoeConfig(**base)
+
+
+# ---------------------------------------------------------------- arrays
+
+def _rms_norm(x, w, eps):
+    x32 = x.astype(jnp.float32)
+    y = x32 * lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
+    return (y * w.astype(jnp.float32)).astype(x.dtype)
+
+
+@jax.named_scope("moe_router")
+def route(h, w_router, top_k, norm_topk_prob):
+    """h [T, H] -> (weights [T, k] float32, expert index [T, k] int32):
+    softmax over all experts in float32, the k largest, renormalised."""
+    logits = jnp.dot(h, w_router, preferred_element_type=jnp.float32)
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    weights, index = lax.top_k(probs, top_k)
+    if norm_topk_prob:
+        weights = weights / jnp.sum(weights, -1, keepdims=True)
+    return weights, index.astype(jnp.int32)
+
+
+def _kernel_backend():
+    """Where the grouped product goes through the Pallas kernel."""
+    return jax.default_backend() == "tpu"
+
+
+def _gmm_tiling(k, n):
+    """(rows, whole contraction, columns): each grid step streams one
+    expert's [k, tn] weight tile, tn the widest divisor of n that is a
+    multiple of 128 and keeps the tile within 2 MB (measured on the v5e,
+    PERF.md, PR 28: 640-680 GB/s of expert weights at 1024 rows, against
+    65-90 GB/s at the library's default of 128 cubed)."""
+    tn = max((c for c in range(128, n + 1, 128)
+              if n % c == 0 and k * c * 2 <= 2 ** 21), default=n)
+    return (128, k, tn)
+
+
+def _grouped(lhs, rhs, group_sizes, out_dtype, interpret=False):
+    """Rows of ``lhs`` [m, k] in consecutive groups, group g of
+    ``group_sizes[g]`` rows times ``rhs[g]`` [k, n]; ``rhs`` holds the
+    first groups only, and the rows of the others come out as zeros.
+
+    On a TPU the megablox grouped matmul (a Pallas kernel that visits
+    only the (row tile, group) pairs that exist, so each touched expert's
+    weights are streamed about once); elsewhere ``jax.lax.ragged_dot``,
+    which on the v5e read the same weights at less than half the rate
+    (PERF.md, PR 28)."""
+    held = rhs.shape[0]
+    if not (_kernel_backend() or interpret):
+        out = lax.ragged_dot(lhs, rhs, group_sizes[:held],
+                             preferred_element_type=jnp.float32)
+    else:
+        from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+        m, k = lhs.shape
+        pad = -m % 128                      # the kernel's row tile
+        out = gmm(jnp.pad(lhs, ((0, pad), (0, 0))), rhs, group_sizes,
+                  preferred_element_type=jnp.float32,
+                  tiling=_gmm_tiling(k, rhs.shape[2]),
+                  interpret=interpret)[:m]
+    if held < group_sizes.shape[0]:
+        rows = jnp.arange(lhs.shape[0])[:, None]
+        out = jnp.where(rows < jnp.sum(group_sizes[:held]), out, 0.0)
+    return out.astype(out_dtype)
+
+
+@jax.named_scope("moe_experts")
+def expert_ffn(h, weights, index, w_gate_up, w_down, expert_offset,
+               num_experts):
+    """The held experts' part of ``sum_e p_e * (silu(h Wg_e) * (h Wu_e))
+    Wd_e``. h [T, H]; weights/index [T, k] over ALL experts; w_gate_up
+    [E_local, H, 2I] (gate columns first), w_down [E_local, I, H], the
+    experts ``expert_offset .. expert_offset + E_local``. Dropless: every
+    pair routed to a held expert is computed, whatever the load."""
+    t, k = index.shape
+    inter = w_down.shape[1]
+    # held experts first, so that their rows lead the sorted order
+    label = (index.reshape(-1) - expert_offset) % num_experts
+    order = jnp.argsort(label, stable=True)
+    group_sizes = jnp.zeros((num_experts,), jnp.int32).at[label].add(1)
+    rows = h[order // k]                                    # [T*k, H]
+    gate_up = _grouped(rows, w_gate_up, group_sizes, h.dtype)
+    act = jax.nn.silu(gate_up[:, :inter]) * gate_up[:, inter:]
+    out = _grouped(act, w_down, group_sizes, jnp.float32)
+    out = out * weights.reshape(-1)[order][:, None]
+    back = jnp.argsort(order)                               # pair -> row
+    return jnp.sum(out[back].reshape(t, k, -1), axis=1).astype(h.dtype)
+
+
+def decoder_layer(cfg, x, p, cache=None):
+    """One layer on raw arrays. x [B, S, H]; p: the layer's parameters by
+    name; cache: None (the S positions are the whole sequence, from 0) or
+    (kbuf, vbuf, lens). Returns (x, cache', expert index [B, S, k])."""
+    from paddle_tpu.inference.decode import block_attention
+    b, s, hid = x.shape
+    nh, nkv, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                  cfg.head_dim)
+    eps, blk = cfg.rms_norm_eps, cfg.block_length
+    h = _rms_norm(x, p["input_layernorm"], eps)
+    q = jnp.dot(h, p["q_proj"]).reshape(b, s, nh, d)
+    k = jnp.dot(h, p["k_proj"]).reshape(b, s, nkv, d)
+    v = jnp.dot(h, p["v_proj"]).reshape(b, s, nkv, d)
+    q = _rms_norm(q, p["q_norm"], eps)
+    k = _rms_norm(k, p["k_norm"], eps)
+    lens = jnp.zeros((b,), jnp.int32) if cache is None else cache[2]
+    q = rope(q, lens, cfg.rope_theta, half_split=True)
+    k = rope(k, lens, cfg.rope_theta, half_split=True)
+    attn, cache = block_attention(q, k, v, blk, cache)
+    x = x + jnp.dot(attn.reshape(b, s, nh * d), p["o_proj"])
+    h = _rms_norm(x, p["post_attention_layernorm"], eps).reshape(b * s, hid)
+    weights, index = route(h, p["router"], cfg.num_experts_per_tok,
+                           cfg.norm_topk_prob)
+    y = expert_ffn(h, weights, index, p["gate_up_proj"], p["down_proj"],
+                   cfg.expert_offset, cfg.num_experts)
+    return x + y.reshape(b, s, hid), cache, index.reshape(b, s, -1)
+
+
+# ---------------------------------------------------------------- layers
+
+_LAYER_PARAMS = ("input_layernorm", "q_proj", "k_proj", "v_proj", "q_norm",
+                 "k_norm", "o_proj", "post_attention_layernorm", "router",
+                 "gate_up_proj", "down_proj")
+
+
+class SDARMoeDecoderLayer(nn.Layer):
+    def __init__(self, cfg: SDARMoeConfig):
+        super().__init__()
+        self.cfg = cfg
+        h, d = cfg.hidden_size, cfg.head_dim
+        nh, nkv = cfg.num_attention_heads, cfg.num_key_value_heads
+        e, inter = cfg.num_local_experts, cfg.moe_intermediate_size
+        # drawn leaf by leaf in param_dtype: the whole model in float32
+        # would not fit the chip before a cast
+        shapes = {"q_proj": (h, nh * d), "k_proj": (h, nkv * d),
+                  "v_proj": (h, nkv * d), "o_proj": (nh * d, h),
+                  "router": (h, cfg.num_experts),
+                  "gate_up_proj": (e, h, 2 * inter),
+                  "down_proj": (e, inter, h)}
+        norms = {"input_layernorm": h, "post_attention_layernorm": h,
+                 "q_norm": d, "k_norm": d}
+        for name in _LAYER_PARAMS:
+            if name in norms:
+                param = self.create_parameter(
+                    (norms[name],), dtype=cfg.param_dtype,
+                    default_initializer=I.Constant(1.0))
+            else:
+                param = self.create_parameter(
+                    shapes[name], dtype=cfg.param_dtype,
+                    default_initializer=I.Normal(0.0, cfg.initializer_range))
+            setattr(self, name, param)
+
+    def forward(self, x, cache=None):
+        """cache: None or a StaticCache. Returns (x, cache', index)."""
+        from paddle_tpu.inference.decode import StaticCache, check_capacity
+        if cache is not None:
+            check_capacity(cache.length, x.shape[1], cache.k.shape[1])
+        held = tuple(cache or ())
+
+        def f(x, *rest):
+            out, new, index = decoder_layer(
+                self.cfg, x, dict(zip(_LAYER_PARAMS, rest[len(held):])),
+                rest[:len(held)] or None)
+            return (out, *(new or ()), index)
+        out, *new, index = run_op(
+            "sdar_moe_layer", f, x, *held,
+            *(getattr(self, n) for n in _LAYER_PARAMS),
+            n_outputs=2 + len(held), differentiable=False)
+        return out, StaticCache(*new) if new else None, index
+
+
+class SDARMoeForCausalLM(nn.Layer):
+    """Inference only: the block-diffusion training objective needs a
+    noise schedule that the public config does not give."""
+
+    def __init__(self, cfg: SDARMoeConfig):
+        super().__init__()
+        self.cfg = cfg
+        std = I.Normal(0.0, cfg.initializer_range)
+        self.embed_tokens = self.create_parameter(
+            (cfg.vocab_size, cfg.hidden_size), dtype=cfg.param_dtype,
+            default_initializer=std)
+        self.layers = nn.LayerList([SDARMoeDecoderLayer(cfg)
+                                    for _ in range(cfg.num_hidden_layers)])
+        self.norm = self.create_parameter(
+            (cfg.hidden_size,), dtype=cfg.param_dtype,
+            default_initializer=I.Constant(1.0))
+        self.lm_head = self.create_parameter(
+            (cfg.hidden_size, cfg.vocab_size), dtype=cfg.param_dtype,
+            default_initializer=std)
+
+    block_length = property(lambda self: self.cfg.block_length)
+    mask_token_id = property(lambda self: self.cfg.mask_token_id)
+
+    def _run(self, input_ids, caches):
+        """-> (logits, caches', the routing: one [B, S, k] int32 array a
+        layer, indices over all ``num_experts``)."""
+        x = run_op("sdar_embed", lambda w, i: w[i], self.embed_tokens,
+                   input_ids, differentiable=False)
+        new_caches, routing = [], []
+        for i, layer in enumerate(self.layers):
+            x, cache, index = layer(x, None if caches is None else caches[i])
+            new_caches.append(cache)
+            routing.append(index._data)
+        eps = self.cfg.rms_norm_eps
+        logits = run_op(
+            "sdar_head", lambda x, g, w: jnp.dot(_rms_norm(x, g, eps), w),
+            x, self.norm, self.lm_head, differentiable=False)
+        return logits, new_caches, routing
+
+    @paddle.no_grad()
+    def forward(self, input_ids):
+        """[B, S] ids -> [B, S, V] logits of the whole sequence from
+        position 0, under the block mask."""
+        return self._run(input_ids, None)[0]
+
+    def init_cache(self, batch_size, max_length):
+        from paddle_tpu.inference.decode import init_static_cache
+        return [init_static_cache(batch_size, max_length,
+                                  self.cfg.num_key_value_heads,
+                                  self.cfg.head_dim)
+                for _ in range(self.cfg.num_hidden_layers)]
+
+    def forward_with_cache(self, input_ids, caches):
+        """The sessions' contract: (ids [B, s], caches) -> (logits,
+        caches), the s positions following each sequence's cached length,
+        under the block mask."""
+        return self._run(input_ids, caches)[:2]
+
+    #: what ``forward_with_expert_load`` sums: (token, expert) pairs routed
+    #: in the active lanes; the busiest expert's pairs; expert layers run;
+    #: experts any lane reached (what the pass had to read)
+    EXPERT_LOAD_LEN = 4
+
+    def forward_with_expert_load(self, input_ids, caches, active):
+        """``forward_with_cache`` and, third, the pass's expert load as
+        ``EXPERT_LOAD_LEN`` int32 sums over the layers, reduced where the
+        routing is (``active`` [B]: the lanes whose pairs count). A session
+        adds the vectors of a dispatch's passes and hands the sum to
+        ``count_expert_load`` when it has fetched it."""
+        logits, caches, routing = self._run(input_ids, caches)
+        experts = jnp.arange(self.cfg.num_experts)
+        total = busiest = touched = jnp.int32(0)
+        for index in routing:
+            hit = index[..., None] == experts
+            touched += jnp.sum(jnp.any(hit, axis=(0, 1, 2)),
+                               dtype=jnp.int32)
+            per_expert = jnp.sum(hit & active[:, None, None, None],
+                                 axis=(0, 1, 2), dtype=jnp.int32)
+            total += jnp.sum(per_expert)
+            busiest += jnp.max(per_expert)
+        return logits, caches, jnp.stack(
+            [total, busiest, jnp.int32(len(routing)), touched])
+
+    @staticmethod
+    def count_expert_load(load):
+        """Ticks the ``moe.*`` counters by a fetched sum of load vectors."""
+        if _met._ENABLED:
+            r = _met.REGISTRY
+            r.counter("moe.assignments").inc(int(load[0]))
+            r.counter("moe.busiest_expert_assignments").inc(int(load[1]))
+            r.counter("moe.layer_passes").inc(int(load[2]))
+            r.counter("moe.experts_touched").inc(int(load[3]))

@@ -117,6 +117,18 @@ CATALOG = {
     "serving.decode_cache_capacity": _m(
         "counter", "max_slots x capacity x steps at each decode dispatch: "
         "the whole cache buffer, the denominator of the valid share"),
+    "serving.block_dispatches": _m(
+        "counter", "block-diffusion dispatches: one block for every "
+        "stepping lane, its denoising passes and its commit pass"),
+    "serving.block_lane_passes": _m(
+        "counter", "block-diffusion lane-passes dispatched: max_slots x "
+        "(denoising_steps + 1) a dispatch, whoever sat in the lane"),
+    "serving.block_open_positions": _m(
+        "counter", "positions open at a block dispatch over its stepping "
+        "lanes: the tokens there were to generate"),
+    "serving.block_discarded_tokens": _m(
+        "counter", "tokens of a fetched block that its request no longer "
+        "took (past the budget, after eos, evicted)"),
     "serving.step_s": _m("histogram", "wall time of one step()"),
     "serving.step_host_s": _m(
         "histogram", "step() less the seconds it waited in its fetch: "
@@ -159,6 +171,20 @@ CATALOG = {
     "serving.degraded": _m(
         "gauge", "1 while readiness reports degraded "
         "(queue/slot pressure past thresholds)"),
+    # ---------------------------------------------- mixture of experts
+    "moe.assignments": _m(
+        "counter", "(token, expert) pairs routed in the stepping lanes of "
+        "the block-diffusion passes, all layers"),
+    "moe.busiest_expert_assignments": _m(
+        "counter", "pairs routed to the busiest expert of a layer in a "
+        "pass, summed over layers and passes: x num_experts / "
+        "moe.assignments is 1.0 where the load is even"),
+    "moe.layer_passes": _m(
+        "counter", "expert layers run: layers x passes of the "
+        "block-diffusion dispatches"),
+    "moe.experts_touched": _m(
+        "counter", "experts that a token of any lane reached, summed over "
+        "moe.layer_passes: the expert weights the passes had to read"),
     # ----------------------------------------------------- dataloader
     "dataloader.fetch_wait_s": _m(
         "histogram", "time the consumer waited on the loader"),

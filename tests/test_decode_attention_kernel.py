@@ -174,3 +174,24 @@ def test_kernel_compiles_for_the_v5e(one_chip, no_compile_cache, b, c, hkv,
         spec((b, 1, hkv * g, 128), jnp.bfloat16), cache, cache,
         spec((b,), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("tokens", [128, 1024], ids=["block_pass", "prefill"])
+def test_expert_layer_compiles_for_the_v5e(one_chip, no_compile_cache,
+                                           monkeypatch, tokens):
+    """The dropless expert layer at SDAR-30B-A3B's widths (128 experts of
+    2048 x 768, top-8) through the megablox grouped product, as a block
+    pass (32 lanes x 4 positions) and a b=1 prefill of 1024 see it. Kept
+    in this file: one process describes the chip."""
+    from paddle_tpu.models import sdar_moe
+    monkeypatch.setattr(sdar_moe, "_kernel_backend", lambda: True)
+
+    def spec(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    layer = functools.partial(sdar_moe.expert_ffn, expert_offset=0,
+                              num_experts=128)
+    compiled = jax.jit(layer).lower(
+        spec((tokens, 2048), jnp.bfloat16), spec((tokens, 8), jnp.float32),
+        spec((tokens, 8), jnp.int32), spec((128, 2048, 1536), jnp.bfloat16),
+        spec((128, 768, 2048), jnp.bfloat16)).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 2
