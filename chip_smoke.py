@@ -9,11 +9,13 @@ seeded weights) through the entry points a user calls:
 * kernels — every in-tree Pallas attention kernel, forward and backward,
   compiled for the chip at the shape of the regime it is routed for and
   compared with a float32 ``jax.numpy`` attention; the decode attention
-  kernel (forward only) against the float32 einsums of the XLA path;
+  kernel (forward only) against the float32 einsums of the XLA path; the
+  cache write's program against ``dynamic_update_slice``, bit for bit;
 * train   — ``gpt_hybrid.setup`` + a few steps at B4xS1024 on a fixed batch;
 * serve   — ``ContinuousBatchingSession`` answering sixteen requests, with
   request 0 checked against ``DecodeSession.generate``, every one-token
-  step through the decode attention kernel and none fallen back.
+  step through the decode attention kernel and none fallen back, every
+  step's cache write through its one program.
 
 A phase that fails raises; nothing is caught and summarised. Without a TPU
 backend the script exits non-zero before building anything — there is no CPU
@@ -63,11 +65,12 @@ def device_report():
     return device
 
 
-def _attn_counters(delta):
-    """attn.* counter movement of one obs.window(), as {label: count}."""
+def _moved_counters(delta, prefix="attn."):
+    """Counter movement of one obs.window() under ``prefix``, as
+    {name{labels}: count}."""
     out = {}
     for c in delta.changed():
-        if c["name"].startswith("attn."):
+        if c["name"].startswith(prefix):
             labels = ",".join(f"{k}={v}" for k, v in
                               sorted(c["labels"].items()))
             out[f"{c['name']}{{{labels}}}"] = int(c["value"])
@@ -230,6 +233,50 @@ def check_decode_kernel(shape=DECODE_KERNEL_SHAPE, interpret=False,
     return err
 
 
+#: the chat cell's cache of one layer, [B,C,Hkv,D] float32
+CACHE_WRITE_SHAPE = (24, 1024, 16, 128)
+
+
+def check_cache_write(shape=CACHE_WRITE_SHAPE, s=1, interpret=False):
+    """The cache write's Pallas program (``s`` new positions a slot, lengths
+    from 0 to the capacity, the last two slots past it) against the vmapped
+    ``dynamic_update_slice`` it stands in for: the same K and V buffers,
+    bit for bit. Returns the number of elements that differ, 0."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax import lax
+
+    from paddle_tpu.ops.pallas.cache_write import write_rows
+
+    b, c, hkv, d = shape
+    keys = jax.random.split(jax.random.PRNGKey(1), 4)
+    kbuf, vbuf = (jax.random.normal(k, shape, jnp.float32) for k in keys[:2])
+    kn, vn = (jax.random.normal(k, (b, s, hkv, d), jnp.float32)
+              for k in keys[2:])
+    lens = np.linspace(0, c - 1, b).astype(np.int32)
+    lens[-2:] = c, c + 40
+    lens = jnp.asarray(lens)
+
+    @jax.jit
+    def update_slice(buf, new):
+        return jax.vmap(
+            lambda x, n, l: lax.dynamic_update_slice(x, n, (l, 0, 0))
+        )(buf, new, lens)
+    got = jax.jit(lambda *a: write_rows(*a, interpret=interpret))(
+        kbuf, vbuf, kn, vn, lens)
+    want = update_slice(kbuf, kn), update_slice(vbuf, vn)
+    differ = sum(int(np.sum(np.asarray(g) != np.asarray(w)))
+                 for g, w in zip(got, want))
+    print(f"[smoke] kernel cache_write {list(shape)} float32, {s} new a "
+          f"slot, lengths 0..{c + 40} of {c}: {differ} elements of K and V "
+          "differ from dynamic_update_slice", flush=True)
+    if differ:
+        raise AssertionError(f"kernel cache_write {list(shape)}: {differ} "
+                             "elements differ from dynamic_update_slice")
+    return differ
+
+
 def kernel_phase(cases=KERNEL_CASES, interpret=False, tol=2e-2, dtype=None):
     """Every case of ``cases`` through check_kernel; the first failure
     raises."""
@@ -305,7 +352,7 @@ def train_phase(cfg, batch, seq, steps, *, scan_unroll, warmup=2, dp=1,
                 jax.block_until_ready(loss)
                 steady_s = time.perf_counter() - t0
     losses = [float(x) for x in losses]
-    dispatch = _attn_counters(w.delta)
+    dispatch = _moved_counters(w.delta)
     peak = _peak_bytes(devices[0])
 
     print(f"[smoke] {tag}: attention dispatch {dispatch or '{}'}")
@@ -422,7 +469,8 @@ def multichip_phase(cfg, batch, seq, steps, *, scan_unroll, layouts,
 # -------------------------------------------------------------------- serve
 
 def serve_phase(cfg, *, max_slots, max_length, decode_block, n_requests,
-                prompt_range, budget_range, seed=0, expect_kernel=None):
+                prompt_range, budget_range, seed=0, expect_kernel=None,
+                expect_write=None):
     """A ContinuousBatchingSession over a bf16 GPTForCausalLM(cfg) answers
     ``n_requests`` seeded requests (the first ``max_slots`` up front, the
     rest after the first step — overlapping lifetimes).
@@ -432,7 +480,8 @@ def serve_phase(cfg, *, max_slots, max_length, decode_block, n_requests,
     continuation agrees with DecodeSession.generate on the same prompt at
     token 0 (the first differing index is printed); no attention dispatch
     fell back and, where ``expect_kernel`` is given, that kernel was
-    dispatched.
+    dispatched; where ``expect_write`` is given, the cache was written
+    through that form (``cache.write_dispatch{kernel}``).
     """
     import jax
     import numpy as np
@@ -474,7 +523,8 @@ def serve_phase(cfg, *, max_slots, max_length, decode_block, n_requests,
                 f"of {budget} tokens (error={res.error})")
     retries = w.value("serving.step_retries", default=0) or 0
     quarantined = w.value("serving.quarantined", default=0) or 0
-    dispatch = _attn_counters(w.delta)
+    dispatch = _moved_counters(w.delta)
+    writes = _moved_counters(w.delta, prefix="cache.write_dispatch")
     got = results[rids[0]].ids
     n0 = len(prompt0)
     diff = next((i for i in range(budget0)
@@ -487,6 +537,7 @@ def serve_phase(cfg, *, max_slots, max_length, decode_block, n_requests,
     print(f"[smoke] serve: step_retries={int(retries)} "
           f"quarantined={int(quarantined)}")
     print(f"[smoke] serve: attention dispatch {dispatch or '{}'}")
+    print(f"[smoke] serve: cache write {writes or '{}'}")
     print("[smoke] serve: request 0 vs DecodeSession.generate: " +
           (f"identical over {budget0} tokens" if diff is None
            else f"first difference at generated token {diff} of {budget0}"),
@@ -506,11 +557,16 @@ def serve_phase(cfg, *, max_slots, max_length, decode_block, n_requests,
         raise AssertionError(
             f"serve: expected the {expect_kernel!r} Pallas kernel to be "
             f"dispatched, saw {dispatch}")
+    if expect_write is not None and not writes.get(
+            f"cache.write_dispatch{{kernel={expect_write}}}"):
+        raise AssertionError(
+            f"serve: expected the cache written through {expect_write!r}, "
+            f"saw {writes}")
     del model
     gc.collect()
     jax.clear_caches()
     return {"requests": n_requests, "tokens": total, "first_diff": diff,
-            "dispatch": dispatch, "serve_s": serve_s}
+            "dispatch": dispatch, "writes": writes, "serve_s": serve_s}
 
 
 # --------------------------------------------------------------------- main
@@ -554,12 +610,13 @@ def main(argv=None):
     else:
         kernel_phase()
         check_decode_kernel()
+        check_cache_write()
         train_phase(cfg, batch=4, seq=1024, steps=4,
                     scan_unroll=SCAN_UNROLL, expect_kernel="simple")
         serve_phase(GPTConfig.gpt3_1p3b(), max_slots=8, max_length=512,
                     decode_block=16, n_requests=16,
                     prompt_range=(32, 128), budget_range=(64, 128),
-                    expect_kernel="decode_ragged")
+                    expect_kernel="decode_ragged", expect_write="row_dma")
 
     print(f"[smoke] compile cache {cache_dir}: "
           f"{compile_cache.entry_count(cache_dir)} entries at end "

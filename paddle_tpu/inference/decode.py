@@ -13,8 +13,10 @@ chase block tables; on TPU every program is compiled with static shapes, so
 the idiomatic equivalent is a FIXED-CAPACITY dense cache ``[B, C, Hkv, D]``
 plus a per-sequence length counter:
 
-  * the cache is updated in place with ``lax.dynamic_update_slice`` — XLA
-    aliases the donated buffer, so this is a true in-place write in HBM;
+  * the cache is updated in place: ``lax.dynamic_update_slice`` (XLA
+    aliases the donated buffer) or, for a batch of slots on a TPU, one
+    Pallas program of copies a layer (``_write_kv``) — either way a true
+    in-place write in HBM;
   * attention masks columns ``>= length``, so capacity padding never leaks;
   * ONE jitted decode step (embed -> attention against the cache prefix ->
     sample) is reused for every generated token — zero recompiles after
@@ -67,12 +69,36 @@ def init_static_cache(batch_size, capacity, num_kv_heads, head_dim,
     return StaticCache(k, v, length)
 
 
+@functools.partial(jax.jit, static_argnames="interpret")
+@jax.named_scope("write_kv")            # a profile names the program after it
+def _row_dma(kbuf, vbuf, kn, vn, lens, interpret):
+    """The write as a program of its own, traced once a shape and not once
+    a layer, as ``_decode_kernel`` is."""
+    from paddle_tpu.ops.pallas.cache_write import write_rows
+    return write_rows(kbuf, vbuf, kn, vn, lens, interpret=interpret)
+
+
 @jax.named_scope("write_kv")
-def _write_kv(buf, new, lens):
-    """Write new [B, s, H, D] into buf [B, C, H, D] at per-seq offsets."""
-    return jax.vmap(
-        lambda b, n, l: lax.dynamic_update_slice(b, n, (l, 0, 0))
-    )(buf, new, lens)
+def _write_kv(kbuf, vbuf, kn, vn, lens):
+    """Write kn/vn [B, s, Hkv, D] into kbuf/vbuf [B, C, Hkv, D] at per-seq
+    offsets ``clip(lens, 0, C - s)``. One algorithm, and the shape says
+    which form is the cheap one: XLA expands a ``dynamic_update_slice``
+    batched over its start into a loop over the slots, so on a TPU a batch
+    of slots goes through one Pallas program of 2 * B copies in place; one
+    slot (every prefill into a session's slot) is one in-place operation
+    already, and every other backend keeps the update."""
+    from paddle_tpu.ops.pallas.flash_attention import _count
+    if kbuf.shape[0] > 1 and _kernel_backend():
+        _count("cache.write_dispatch", kernel="row_dma")
+        return _row_dma(kbuf, vbuf, kn, vn, lens,
+                        interpret=jax.default_backend() != "tpu")
+    _count("cache.write_dispatch", kernel="update_slice")
+
+    def put(buf, new):
+        return jax.vmap(
+            lambda b, n, l: lax.dynamic_update_slice(b, n, (l, 0, 0))
+        )(buf, new, lens)
+    return put(kbuf, kn), put(vbuf, vn)
 
 
 def _attend_einsum(q, kbuf, vbuf, lens, block=None):
@@ -101,7 +127,7 @@ def _attend_einsum(q, kbuf, vbuf, lens, block=None):
 
 
 def _kernel_backend():
-    """Where the one-token step attends through the Pallas kernel."""
+    """Where the cache is written and read through the Pallas programs."""
     return jax.default_backend() == "tpu"
 
 
@@ -152,8 +178,8 @@ def _cache_attention(q, kn, vn, kbuf, vbuf, lens, block=None):
     the buffers and drops the advanced length: the rows written past it
     are dead until the commit pass overwrites them.
     """
-    kbuf = _write_kv(kbuf, kn.astype(kbuf.dtype), lens)
-    vbuf = _write_kv(vbuf, vn.astype(vbuf.dtype), lens)
+    kbuf, vbuf = _write_kv(kbuf, vbuf, kn.astype(kbuf.dtype),
+                           vn.astype(vbuf.dtype), lens)
     out = None if block is not None else \
         _attend_decode_kernel(q, kbuf, vbuf, lens)
     if out is None:

@@ -203,6 +203,10 @@ CATALOG = {
     "attn.dispatch_fallback": _m(
         "counter", "shape-gate rejections falling back to XLA",
         ("reason",)),
+    "cache.write_dispatch": _m(
+        "counter", "KV cache writes at trace time, by the form the shape "
+        "and backend chose (row_dma: one Pallas program of copies; "
+        "update_slice: XLA's dynamic_update_slice)", ("kernel",)),
     "attn.autotune_candidate_errors": _m(
         "counter", "autotune candidates the compiler or runtime "
         "refused (text kept in the table entry)", ("kernel",)),

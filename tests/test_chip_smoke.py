@@ -53,6 +53,7 @@ def test_serve_phase_tiny():
                                prompt_range=(4, 12), budget_range=(4, 8))
     assert r["requests"] == 4 and r["first_diff"] != 0
     assert r["dispatch"] == {}      # no kernel, and no fallback, on the CPU
+    assert set(r["writes"]) == {"cache.write_dispatch{kernel=update_slice}"}
 
 
 def test_serve_phase_tiny_asks_for_its_kernel():
@@ -63,6 +64,32 @@ def test_serve_phase_tiny_asks_for_its_kernel():
                                decode_block=4, n_requests=2,
                                prompt_range=(4, 12), budget_range=(4, 8),
                                expect_kernel="decode_ragged")
+
+
+def test_serve_phase_tiny_asks_for_its_cache_write():
+    # likewise: on the CPU the cache is written by dynamic_update_slice
+    with pytest.raises(AssertionError, match="written through 'row_dma'"):
+        chip_smoke.serve_phase(TINY, max_slots=2, max_length=64,
+                               decode_block=4, n_requests=2,
+                               prompt_range=(4, 12), budget_range=(4, 8),
+                               expect_write="row_dma")
+
+
+@pytest.mark.parametrize("s", [1, 4])
+def test_cache_write_check_interpret(s):
+    assert chip_smoke.check_cache_write((4, 64, 2, 128), s=s,
+                                        interpret=True) == 0
+
+
+def test_cache_write_check_catches_a_wrong_row(monkeypatch):
+    from paddle_tpu.ops.pallas import cache_write
+    write_rows = cache_write.write_rows
+
+    def off_by_one(kbuf, vbuf, kn, vn, lens, interpret):
+        return write_rows(kbuf, vbuf, kn, vn, lens + 1, interpret=interpret)
+    monkeypatch.setattr(cache_write, "write_rows", off_by_one)
+    with pytest.raises(AssertionError, match="differ from dynamic_update"):
+        chip_smoke.check_cache_write((4, 64, 2, 128), interpret=True)
 
 
 def test_decode_kernel_check_interpret():

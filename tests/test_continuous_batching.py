@@ -212,9 +212,9 @@ def _both_paths(monkeypatch, reqs, **session):
 
 
 def _kernel_took_every_step(w_ref, w):
-    from chip_smoke import _attn_counters
-    moved = _attn_counters(w.delta)
-    return (_attn_counters(w_ref.delta) == {}
+    from chip_smoke import _moved_counters
+    moved = _moved_counters(w.delta)
+    return (_moved_counters(w_ref.delta) == {}
             and moved.get("attn.dispatch{kernel=decode_ragged}", 0) > 0
             and not any(k.startswith("attn.dispatch_fallback")
                         for k in moved))
@@ -237,6 +237,62 @@ def test_decode_kernel_in_the_session_matches_the_einsum_path(monkeypatch):
         assert win.value("serving.decode_cache_positions") == \
             4 * (5 + 3) + 4 * (9 + 9)
         assert win.value("serving.decode_cache_capacity") == 2 * (2 * 64 * 4)
+
+
+def _write_forms(w):
+    return {form for form in ("row_dma", "update_slice")
+            if w.value("cache.write_dispatch", default=0, kernel=form)}
+
+
+def test_cache_write_program_in_the_session_writes_the_same(monkeypatch):
+    """The cache write through its Pallas program (the backend predicate
+    on: two slots, so every decode step's write; an admit's is one slot's
+    and stays a ``dynamic_update_slice``) against the ``update_slice``
+    form: the same greedy tokens with a lane that fills its slot to the
+    last position and ends mid-block, stepping on at lengths at and past
+    the capacity, where the write clamps as the update does."""
+    (ref, w_ref), (got, w) = _both_paths(
+        monkeypatch, ((50, 15), (3, 5), (9, 6)), decode_block=4)
+    assert [len(x) for x in ref] == [50 + 15, 3 + 5, 9 + 6]
+    for a, b in zip(got, ref):
+        np.testing.assert_array_equal(a, b)
+    assert _write_forms(w_ref) == {"update_slice"}
+    assert _write_forms(w) == {"row_dma", "update_slice"}
+    # ticked as the decode block is traced, once, for each of two layers
+    assert w.value("cache.write_dispatch", kernel="row_dma") == 2
+
+
+def test_cache_write_program_under_block_diffusion(monkeypatch):
+    """Generation by diffusion over blocks (s = 4, GQA, the block mask's
+    einsums) with the cache written through the program: the same tokens,
+    fixed by the same passes, as the ``update_slice`` form."""
+    import paddle_tpu.observability as obs
+    from paddle_tpu.inference import decode
+    from paddle_tpu.models.sdar_moe import SDARMoeConfig, SDARMoeForCausalLM
+
+    obs.enable()
+    paddle.seed(0)
+    model = SDARMoeForCausalLM(SDARMoeConfig.tiny())
+    model.eval()
+    rng = np.random.RandomState(3)
+    reqs = [(rng.randint(0, 159, (n,)).astype(np.int32), b)
+            for n, b in ((5, 7), (8, 9), (11, 4), (14, 6))]
+
+    def run():
+        with obs.window() as w, ContinuousBatchingSession(
+                model, max_slots=2, max_length=32,
+                generation="block_diffusion", denoising_steps=2) as sess:
+            rids = [sess.submit(p, b) for p, b in reqs]
+            res = sess.results()
+        return [(list(res[r].ids), list(res[r].commit_steps))
+                for r in rids], w
+
+    ref, w_ref = run()
+    monkeypatch.setattr(decode, "_kernel_backend", lambda: True)
+    got, w = run()
+    assert got == ref
+    assert _write_forms(w_ref) == {"update_slice"}
+    assert _write_forms(w) == {"row_dma", "update_slice"}
 
 
 @pytest.mark.parametrize("session", [

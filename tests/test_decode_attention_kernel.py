@@ -2,7 +2,9 @@
 against ``_cache_attention``'s einsum path, in float32 through the Pallas
 interpreter: ragged lengths around the block edges, lanes shown at length 0,
 poisoned positions past the valid length, the dispatch and its shape gate,
-and the kernel compiled for the v5e at the serving cells' cache shapes."""
+and the kernel compiled for the v5e at the serving cells' cache shapes (the
+cache write's program and the expert layer beside it: one process describes
+the chip)."""
 import functools
 
 import jax
@@ -11,7 +13,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu.observability as obs
-from chip_smoke import _attn_counters
+from chip_smoke import _moved_counters
 from paddle_tpu.inference import decode
 from paddle_tpu.ops.pallas import decode_attention as da
 
@@ -90,7 +92,7 @@ def test_shape_gate(cache_shape, dtype, reason):
 
 
 def _attn_moves(window):
-    return _attn_counters(window.delta)
+    return _moved_counters(window.delta)
 
 
 @pytest.mark.parametrize("lens", [RAGGED, PAST_CAPACITY],
@@ -174,6 +176,34 @@ def test_kernel_compiles_for_the_v5e(one_chip, no_compile_cache, b, c, hkv,
         spec((b, 1, hkv * g, 128), jnp.bfloat16), cache, cache,
         spec((b,), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("b, c, hkv, s, dtype", [
+    (24, 1024, 16, 1, jnp.float32),
+    (12, 2048, 16, 1, jnp.float32),
+    (32, 1280, 4, 4, jnp.float32),
+    (12, 2048, 16, 256, jnp.float32),
+    (8, 1024, 4, 4, jnp.bfloat16),
+], ids=["chat", "longctx", "blockdiff", "batch_prefill", "bfloat16_gqa4"])
+def test_cache_write_compiles_for_the_v5e(one_chip, no_compile_cache, b, c,
+                                          hkv, s, dtype):
+    """The cache write's program (ops/pallas/cache_write.py; its results
+    are tests/test_cache_write_kernel.py's) at the three serving cells'
+    shapes, a DecodeSession's batched prefill and a bfloat16 cache: one
+    kernel, the donated buffers written in place with no copy of either
+    and no loop over the slots. Kept in this file: one process describes
+    the chip."""
+    from paddle_tpu.ops.pallas.cache_write import write_rows
+
+    def spec(shape, dt=dtype):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    cache, new = spec((b, c, hkv, 128)), spec((b, s, hkv, 128))
+    compiled = jax.jit(write_rows, donate_argnums=(0, 1)).lower(
+        cache, cache, new, new, spec((b,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert " while(" not in text and " copy(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
 @pytest.mark.parametrize("tokens", [128, 1024], ids=["block_pass", "prefill"])
