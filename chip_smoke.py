@@ -38,8 +38,8 @@ from importlib import metadata
 
 
 #: lax.scan unroll of the 24-layer stack in the full-width train phases
-#: (and bench.py's flagship rung): the full unroll compiles in ~70 s and
-#: fits the chip — CHANGES.md, PR 21
+#: (the train cells' configs state the same): the full unroll compiles in
+#: ~70 s and fits the chip — CHANGES.md, PR 21
 SCAN_UNROLL = 24
 
 
@@ -599,7 +599,7 @@ def main(argv=None):
           ("PRESENT (overrides the static chain)" if os.path.exists(table)
            else "absent (the static chain decides)"), flush=True)
 
-    # the flagship width; bench.py's train configuration
+    # the flagship width: GPT-3 1.3B, as configs/gpt3-1.3b.json has it
     cfg = GPTConfig(vocab_size=50304, hidden_size=2048, num_layers=24,
                     num_heads=16, max_seq_len=1024)
     if args.chips == 4:

@@ -6,9 +6,10 @@ Unset: the cache lives at ``<checkout>/.jax_cache``, a path derived
 from this package's location (the directory is part of the cache key's
 stability: a path made from a pid, a tempdir or the time never hits).
 
-Entry points that compile the big programs (chip_smoke.py, bench.py)
-call :func:`enable` once before their first jit; nothing else in the
-tree touches ``jax_compilation_cache_dir``.
+chip_smoke.py, which compiles the big programs, calls :func:`enable`
+once before its first jit; the benchmark's ``benchmarks/ledger/run.py``
+places its cache by the same rule itself, and nothing else in the tree
+touches ``jax_compilation_cache_dir``.
 """
 from __future__ import annotations
 

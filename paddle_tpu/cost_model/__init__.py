@@ -10,8 +10,7 @@ the attached device — the measured path the reference gets from its
 benchmark table."""
 from .cost_model import (  # noqa: F401
     CostModel, DEVICE_KIND_TO_CHIP, TPU_SPECS, OpCost,
-    attached_chip_spec, gpt_flops_per_token, mfu, spec_for_device_kind)
+    attached_chip_spec, mfu, spec_for_device_kind)
 
 __all__ = ["CostModel", "DEVICE_KIND_TO_CHIP", "TPU_SPECS", "OpCost",
-           "attached_chip_spec", "gpt_flops_per_token", "mfu",
-           "spec_for_device_kind"]
+           "attached_chip_spec", "mfu", "spec_for_device_kind"]

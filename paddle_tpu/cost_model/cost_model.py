@@ -48,19 +48,6 @@ def attached_chip_spec() -> Dict[str, float]:
     return spec_for_device_kind(jax.devices()[0].device_kind)
 
 
-def gpt_flops_per_token(cfg, seq_len: int) -> float:
-    """Training FLOPs per token of a GPT-family config: 6*N for the
-    parameter matmuls (fwd + bwd) + the 12*L*H*S attention term — the
-    single home of the formula bench.py and the observability MFU gauge
-    share. `cfg` needs vocab_size/hidden_size/max_seq_len/num_layers."""
-    n = (cfg.vocab_size * cfg.hidden_size
-         + cfg.max_seq_len * cfg.hidden_size
-         + cfg.num_layers * (12 * cfg.hidden_size * cfg.hidden_size
-                             + 13 * cfg.hidden_size)
-         + 2 * cfg.hidden_size)
-    return float(6 * n + 12 * cfg.num_layers * cfg.hidden_size * seq_len)
-
-
 def mfu(tokens_per_s: float, flops_per_token: float,
         peak_flops: float) -> float:
     """Achieved model-flops utilization against a bf16 peak FLOP/s

@@ -87,12 +87,14 @@ def _write_kv(kbuf, vbuf, kn, vn, lens):
     of slots goes through one Pallas program of 2 * B copies in place; one
     slot (every prefill into a session's slot) is one in-place operation
     already, and every other backend keeps the update."""
-    from paddle_tpu.ops.pallas.flash_attention import _count
-    if kbuf.shape[0] > 1 and _kernel_backend():
-        _count("cache.write_dispatch", kernel="row_dma")
+    row_dma = kbuf.shape[0] > 1 and _kernel_backend()
+    if _met._ENABLED:
+        _met.REGISTRY.counter(
+            "cache.write_dispatch",
+            kernel="row_dma" if row_dma else "update_slice").inc()
+    if row_dma:
         return _row_dma(kbuf, vbuf, kn, vn, lens,
                         interpret=jax.default_backend() != "tpu")
-    _count("cache.write_dispatch", kernel="update_slice")
 
     def put(buf, new):
         return jax.vmap(
@@ -150,12 +152,15 @@ def _attend_decode_kernel(q, kbuf, vbuf, lens):
     if q.shape[1] != 1 or not _kernel_backend():
         return None
     from paddle_tpu.ops.pallas.decode_attention import gate_reason
-    from paddle_tpu.ops.pallas.flash_attention import _count
     reason = gate_reason(q.shape, kbuf.shape, kbuf.dtype)
     if reason is not None:
-        _count("attn.dispatch_fallback", reason=reason)
+        if _met._ENABLED:
+            _met.REGISTRY.counter("attn.dispatch_fallback",
+                                  reason=reason).inc()
         return None
-    _count("attn.dispatch", kernel="decode_ragged")
+    if _met._ENABLED:
+        _met.REGISTRY.counter("attn.dispatch",
+                              kernel="decode_ragged").inc()
     return _decode_kernel(q, kbuf, vbuf, lens,
                           interpret=jax.default_backend() != "tpu")
 
@@ -739,10 +744,11 @@ class ContinuousBatchingSession(_SessionLifecycle):
         cache rows out of the batch, run a b=1 prefill on the padded
         prompt, write the rows back at a TRACED slot index and deposit
         the first sampled token into the batched token vector;
-      * decode — ONE executable, always the full slot batch; lanes that
-        do not step are masked (their token is passed through and their
-        length stays as it was; a lane with no request is shown to the
-        model at length 0, so attention reads one block of it);
+      * decode — ONE executable, always the full slot batch,
+        ``decode_block`` steps a dispatch; lanes that do not step are
+        masked (their token is passed through and their length stays as
+        it was; a lane with no request is shown to the model at length
+        0, so attention reads one block of it);
       * retire — host-side: eos or budget exhaustion frees the slot,
         the next queued request is admitted into it on the next step.
 
@@ -788,7 +794,7 @@ class ContinuousBatchingSession(_SessionLifecycle):
     def __init__(self, model, max_slots, max_length,
                  prefill_buckets=None, temperature=0.0, top_p=None,
                  top_k=None, eos_token_id=None, seed=0,
-                 sync_every=1, decode_block=None,
+                 decode_block=None,
                  max_queue=None, shed_policy="reject_newest",
                  default_deadline_s=None, default_ttft_s=None,
                  step_retries=2, step_backoff_s=0.02,
@@ -824,9 +830,15 @@ class ContinuousBatchingSession(_SessionLifecycle):
         self._admit_jit = jax.jit(
             self._admit_pure,
             donate_argnums=tuple(range(n + 5, n + 5 + nc)))
+        # decode_block=k (None: 1) is the one size of a decode dispatch:
+        # k masked steps in one lax.while_loop program that emits a
+        # [slots, k] token block, so the host round trip is paid once a
+        # block. Retirement lags up to k-1 steps (the freed slot's extra
+        # decodes are discarded; its cache is reset by the next admission).
         # decode args: (*state, tokens, key, lane, *caches)
-        self._decode_jit = jax.jit(
-            self._decode_pure,
+        self._decode_block = int(decode_block) if decode_block else 1
+        self._decode_blk_jit = jax.jit(
+            self._decode_block_pure,
             donate_argnums=tuple(range(n + 3, n + 3 + nc)))
 
         self._free = list(range(self._slots))
@@ -835,16 +847,9 @@ class ContinuousBatchingSession(_SessionLifecycle):
         self._done: dict = {}             # rid -> _Request (undelivered)
         self._next_rid = 0
         self._used_rids: set = set()
-        # sync_every=k batches the host-side retirement check: token
-        # vectors stay ON DEVICE for k decode steps and are fetched in
-        # one device_get, so the per-token host sync is paid once per
-        # k steps (its cost on the current chip: not measured).
-        # Retirement then lags up to k-1
-        # steps (the freed slot's extra decodes are discarded; its
-        # cache is reset by the next admission), trading a little
-        # wasted compute for dispatch pipelining — the same trade the
-        # reference's block-scheduler makes with its step quantum.
-        self._sync_every = max(1, int(sync_every))
+        # this step()'s ("admit" | "block" | "blocks", info, array)
+        # entries: filled by _admit_one, _dispatch_once and recovery's
+        # probes, emptied by _drain_pending in the same step()
         self._pending: List = []
         reg = _met.REGISTRY
         self._h_phase = {
@@ -853,21 +858,12 @@ class ContinuousBatchingSession(_SessionLifecycle):
         self._h_step = reg.histogram("serving.step_s")
         self._h_step_host = reg.histogram("serving.step_host_s")
         self._fetch_s = 0.0     # seconds this step() waited in its fetch
-        # decode_block=k runs k decode steps per DISPATCH in one
-        # lax.while_loop program (the DecodeSession block-decode idea
-        # applied to the slot batch): one dispatch emits a [slots, k]
-        # token block, amortizing the per-step dispatch cost.
-        # sync_every counts DISPATCHES in either mode, so block mode
-        # drains every sync_every blocks (retirement lag up to
-        # k*sync_every - 1 steps, same discard semantics); the usual
-        # block config is sync_every=1 + decode_block=k.
-        self._decode_block = int(decode_block) if decode_block else None
-        if self._decode_block:
-            self._decode_blk_jit = jax.jit(
-                self._decode_block_pure,
-                donate_argnums=tuple(range(n + 3, n + 3 + nc)))
         self._block_length = None
         if generation == "block_diffusion":
+            if decode_block:
+                raise ValueError(
+                    "decode_block is the autoregressive mode's; "
+                    "a block-diffusion dispatch is one block")
             self._init_block_diffusion(denoising_steps, remasking,
                                        confidence_threshold)
         elif generation != "autoregressive":
@@ -946,10 +942,9 @@ class ContinuousBatchingSession(_SessionLifecycle):
     @jax.named_scope("decode_step")
     def _masked_step(self, state, tok, key, lane, cache_arrays):
         """ONE masked decode step — the single home of the per-slot
-        semantics shared by the per-step and block programs. ``lane``
-        says what each slot is doing (_LANE_*): only a stepping lane
-        takes its new token and length. Every other lane passes its
-        token through and keeps its length pinned. A paused lane (a
+        semantics. ``lane`` says what each slot is doing (_LANE_*): only
+        a stepping lane takes its new token and length. Every other lane
+        passes its token through and keeps its length pinned. A paused lane (a
         live request left out of a recovery probe) is shown to the
         model as it is: the k/v row the step writes at its length is
         dead, its own next step overwrites it. An empty lane is shown
@@ -996,22 +991,11 @@ class ContinuousBatchingSession(_SessionLifecycle):
             lambda c: c[0] < blk, body, carry)
         return out, tokens, key, list(cache_arrays)
 
-    def _decode_pure(self, *flat):
-        n = len(self._state_t)
-        state = flat[:n]
-        tokens, key, lane = flat[n:n + 3]
-        cache_arrays = flat[n + 3:]
-        return self._masked_step(state, tokens, key, lane,
-                                 cache_arrays)
-
     # ---------------- generation by diffusion over blocks -------------
     def _init_block_diffusion(self, denoising_steps, remasking,
                               confidence_threshold):
         model = self._model
         blk = int(model.block_length)       # the model's block mask's
-        if self._decode_block:
-            raise ValueError("decode_block is the autoregressive mode's; "
-                             "a block-diffusion dispatch is one block")
         if remasking not in ("low_confidence_static",
                              "low_confidence_dynamic"):
             raise ValueError(f"unknown remasking {remasking!r}")
@@ -1382,14 +1366,10 @@ class ContinuousBatchingSession(_SessionLifecycle):
         lane[list(slots)] = _LANE_STEPPING
         if self._block_length:
             return self._dispatch_block(state, slots, lane, retries)
-        steps = self._decode_block or 1
+        steps = self._decode_block
 
         def call():
-            if self._decode_block:
-                return self._decode_blk_jit(
-                    *state, self._tokens, self._key,
-                    jnp.asarray(lane), *self._cache_arrays)
-            return self._decode_jit(
+            return self._decode_blk_jit(
                 *state, self._tokens, self._key, jnp.asarray(lane),
                 *self._cache_arrays)
 
@@ -1409,12 +1389,8 @@ class ContinuousBatchingSession(_SessionLifecycle):
                 self._slots * self._max_length * steps)
         for req in stepped:
             req.cached += steps
-        if self._decode_block:
-            blk_out, self._tokens, self._key, self._cache_arrays = out
-            self._pending.append(("block", slots, blk_out))
-        else:
-            self._tokens, self._key, self._cache_arrays = out
-            self._pending.append(("step", slots, self._tokens))
+        blk_out, self._tokens, self._key, self._cache_arrays = out
+        self._pending.append(("block", slots, blk_out))
 
     def _probe_slots(self, state, subset):
         """Single-attempt step over a slot subset. A SUCCESSFUL probe
@@ -1533,10 +1509,9 @@ class ContinuousBatchingSession(_SessionLifecycle):
             r.histogram("serving.queue_wait_s").observe(
                 req.t_admit - req.t_submit)
         # the admit's sampled token is the request's first output;
-        # it stays ON DEVICE and is fetched with the next pending
-        # drain (an immediate device_get would reintroduce one
-        # blocking RTT per admission — the cost sync_every exists
-        # to amortize). The tagged entry applies to THIS slot only:
+        # it stays ON DEVICE and is fetched with this step's drain
+        # (an immediate device_get would add one blocking round trip
+        # per admission). The tagged entry applies to THIS slot only:
         # the other lanes of the vector hold already-consumed
         # decode tokens. A block-mode admit samples nothing: the first
         # tokens come with the request's first block.
@@ -1571,7 +1546,7 @@ class ContinuousBatchingSession(_SessionLifecycle):
         delivered = first = 0
         for (kind, ainfo, _t), row in zip(entries, fetched):
             # ainfo: the admitted slot ("admit") or the tuple of slots
-            # active AT DISPATCH ("step"/"block") — only those lanes
+            # active AT DISPATCH ("block") — only those lanes
             # carry live tokens; slots evicted (cancel/timeout/
             # quarantine) between dispatch and drain are skipped, and
             # recovery probes over subsets credit exactly their subset
@@ -1591,21 +1566,13 @@ class ContinuousBatchingSession(_SessionLifecycle):
                         self._stamp_first_token(req, now)
                     self._maybe_retire(req)
                 continue
-            if kind == "block":
-                for col in range(row.shape[1]):
-                    for slot in ainfo:
-                        req = self._running.get(slot)
-                        if req is not None:
-                            req.tokens.append(int(row[slot, col]))
-                            delivered += 1
-                            self._maybe_retire(req)
-                continue
-            for slot in ainfo:
-                req = self._running.get(slot)
-                if req is not None:
-                    req.tokens.append(int(row[slot]))
-                    delivered += 1
-                    self._maybe_retire(req)
+            for col in range(row.shape[1]):
+                for slot in ainfo:
+                    req = self._running.get(slot)
+                    if req is not None:
+                        req.tokens.append(int(row[slot, col]))
+                        delivered += 1
+                        self._maybe_retire(req)
         if _met._ENABLED and delivered:
             r = _met.REGISTRY
             r.counter("serving.decode_tokens").inc(delivered)
@@ -1622,11 +1589,11 @@ class ContinuousBatchingSession(_SessionLifecycle):
                 now - req.t_admit)
 
     def step(self):
-        """Expire deadlines, admit whatever fits (on sync boundaries),
-        run ONE batched decode step under the retry/recovery envelope,
-        and — every `sync_every` steps — fetch the pending token block
-        and retire finished requests. Returns the list of request ids
-        that reached a terminal state during this step."""
+        """Expire deadlines, admit whatever fits, dispatch ONE decode
+        block under the retry/recovery envelope, fetch what the step
+        left pending and deliver it, retiring finished requests.
+        Returns the list of request ids that reached a terminal state
+        during this step."""
         self._fetch_s = 0.0
         with _Phase(RecordEvent("serving.step",
                                 running=len(self._running),
@@ -1641,8 +1608,7 @@ class ContinuousBatchingSession(_SessionLifecycle):
     def _step(self):
         before = set(self._done)
         self._expire_deadlines()
-        if not self._pending:
-            self._admit_ready()
+        self._admit_ready()
         if _met._ENABLED:
             r = _met.REGISTRY
             r.counter("serving.steps").inc()
@@ -1652,22 +1618,20 @@ class ContinuousBatchingSession(_SessionLifecycle):
                 len(self._running) / self._slots)
             r.gauge("serving.degraded").set(
                 1.0 if self._health_report() else 0.0)
-        if self._running:
-            state = [t._data for t in self._state_t]
-            slots = tuple(sorted(self._running))
-            try:
-                self._dispatch_once(state, slots)
-            except ServingStepError:
-                raise
-            except Exception as e:  # noqa: BLE001
-                self._recover_decode(state, slots, e)
-        if len(self._pending) >= self._sync_every or (
-                self._pending and not self._running):
-            # the second arm flushes a PARTIAL sync window when no slot
-            # is decoding anymore (every running request was cancelled/
-            # timed out/quarantined mid-window): admission is gated on
-            # an empty pending list, so waiting out the quantum would
-            # deadlock step()/results() with work still queued
+        try:
+            if self._running:
+                state = [t._data for t in self._state_t]
+                slots = tuple(sorted(self._running))
+                try:
+                    self._dispatch_once(state, slots)
+                except ServingStepError:
+                    raise
+                except Exception as e:  # noqa: BLE001
+                    self._recover_decode(state, slots, e)
+        finally:
+            # also under a step-wide failure: the tokens of the step's
+            # admits and successful probes are delivered before it
+            # propagates, so every step starts with nothing pending
             self._drain_pending()
         return [r for r in self._done if r not in before]
 
@@ -1677,7 +1641,7 @@ class ContinuousBatchingSession(_SessionLifecycle):
         state, prompt + generated ids (partial for TIMED_OUT /
         CANCELLED / FAILED), and the error string for FAILED.
         Delivered results are released exactly like :meth:`run`."""
-        while self._queue or self._running or self._pending:
+        while self._queue or self._running:
             self.step()
         out = {rid: RequestResult(
                    req.state,
@@ -1740,10 +1704,8 @@ class ContinuousBatchingSession(_SessionLifecycle):
         if self._block_length:
             return (self._admit_blk_jit._cache_size(),
                     self._block_jit._cache_size())
-        n_dec = self._decode_jit._cache_size()
-        if self._decode_block:
-            n_dec += self._decode_blk_jit._cache_size()
-        return (self._admit_jit._cache_size(), n_dec)
+        return (self._admit_jit._cache_size(),
+                self._decode_blk_jit._cache_size())
 
 
 

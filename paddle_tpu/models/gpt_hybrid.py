@@ -20,7 +20,8 @@ ONE jitted SPMD program over a (dp, pp, tp) mesh:
 - remat: jax.checkpoint per block (replaces RecomputeFunction)
 
 Everything below is pure-functional jax (no eager Tensor) — this is the
-engine the paddle-style wrappers lower to, and what bench.py measures.
+engine the paddle-style wrappers lower to, and what the train cells of
+BENCHMARK.json measure.
 """
 from __future__ import annotations
 
@@ -98,8 +99,8 @@ class ParallelConfig:
     # under (bf16 moments for the bf16-param flagship). Explicit f32
     # doubles moment HBM (+5.2 GB at 1.3B — does NOT fit v5e alongside
     # the step's working set); parity of bf16 vs f32 moments measured
-    # at 1.45e-6 max rel deviation over 30 steps
-    # (benchmarks/probes/_r3_moment_parity.py, asserted < 5e-3)
+    # at 1.45e-6 max rel deviation over 30 steps (a probe on an earlier
+    # chip, asserted < 5e-3; the script is in git history before PR 30)
     moment_dtype: Any = None
     fused_ce: bool = True     # chunked LM-head+CE (ops/fused_ce.py);
                               # never materializes [T, V] logits
@@ -428,8 +429,9 @@ def _stack_apply(blocks, x, cfg, pcfg, mesh):
                 # surgical: keep the expensive tensors (attention
                 # output, qkv, ffn up-projection), recompute the cheap
                 # rest — the flash kernel never re-runs in backward.
-                # Measured best on v5e (benchmarks/probes/_e2e_h8*.py); saving
-                # proj/ffn2 as well LOWERS throughput (memory pressure)
+                # Measured best on an earlier v5e (probes in git history
+                # before PR 30); saving proj/ffn2 as well LOWERS
+                # throughput (memory pressure)
                 fn = jax.checkpoint(
                     fn, policy=jax.checkpoint_policies
                     .save_only_these_names(*pcfg.remat_save_names))
@@ -902,11 +904,10 @@ def build_train_step(cfg: GPTConfig, pcfg: ParallelConfig, mesh: Mesh,
 
 
 def _make_grad_acc(cfg, pcfg, mesh):
-    """One home for the accumulate-into-tree gradient step shared by
-    the accumulation engines (parity by construction). Under pp>1 the
-    per-chunk gradient comes from the compiled 1F1B ring — the same
-    grads_of the fused train step uses, so gradient merge composes
-    with pipeline identically in both engines (reference:
+    """The accumulate-into-tree gradient step of build_accum_steps.
+    Under pp>1 the per-chunk gradient comes from the compiled 1F1B ring
+    — the same grads_of the fused train step uses, so gradient merge
+    composes with pipeline identically in both engines (reference:
     auto_parallel_gradient_merge composing with the pipeline passes)."""
     _validate_pp_schedule(pcfg)
     if pcfg.pp > 1 and pcfg.pp_schedule in ("1f1b", "zbh1", "zbvpp"):
@@ -970,8 +971,7 @@ def init_grad_accum(params):
 def _adamw_leaf(p, m, v, g, step, lr, b1=0.9, b2=0.95, eps=1e-8,
                 wd=0.1):
     """The single home of the per-leaf AdamW update math (f32 compute,
-    storage dtypes preserved) — shared by adamw_update and the
-    accumulation bench engines so their parity is by construction."""
+    storage dtypes preserved)."""
     gf = g.astype(jnp.float32)
     pf = p.astype(jnp.float32)
     m_new = b1 * m.astype(jnp.float32) + (1 - b1) * gf
@@ -983,174 +983,6 @@ def _adamw_leaf(p, m, v, g, step, lr, b1=0.9, b2=0.95, eps=1e-8,
     upd = (m_new / c1) / (jnp.sqrt(v_new / c2) + eps) + wd * pf
     return ((pf - lr * upd).astype(p.dtype),
             m_new.astype(m.dtype), v_new.astype(v.dtype))
-
-
-def build_leaf_accum_bench(cfg: GPTConfig, pcfg: ParallelConfig,
-                           mesh: Mesh, lr=3e-4):
-    """Donation-free k-chunk training engine with PER-LEAF applies.
-
-    Every compiled program keeps in+out+temps well under HBM even when
-    a compiler drops buffer donation:
-      grad_acc(params, acc_tree, batch) -> (acc', loss)   (~13 GB peak)
-      apply_leaf(p, m, v, g, step, k) per stacked leaf    (<= ~6 GB)
-    The per-k apply also amortizes the bandwidth-bound AdamW update —
-    a larger-global-batch pretrain config (update math identical to
-    adamw_update; k=1 reproduces the classic step exactly, see
-    benchmarks/probes/_r3_flat_parity.py).
-    """
-    grad_acc = _make_grad_acc(cfg, pcfg, mesh)
-
-    def apply_leaf(p, m, v, g, step, k):
-        return _adamw_leaf(p, m, v, g / k, step, lr)
-
-    grad_acc_j = jax.jit(grad_acc, donate_argnums=(1,))
-
-    def grad_only(params, batch):
-        return jax.value_and_grad(
-            lambda p: loss_fn(p, batch, cfg, pcfg, mesh))(params)
-
-    grad_only_j = jax.jit(grad_only)
-    apply_j = jax.jit(apply_leaf, donate_argnums=(0, 1, 2),
-                      static_argnums=(5,))
-
-    def init_state(seed=0):
-        params = init_params(cfg, pcfg, jax.random.PRNGKey(seed))
-        params = jax.tree_util.tree_map(
-            lambda x: x.astype(pcfg.param_dtype), params)
-        m = jax.tree_util.tree_map(
-            lambda x: jnp.zeros(x.shape, pcfg.moment_dtype or x.dtype),
-            params)
-        v = jax.tree_util.tree_map(
-            lambda x: jnp.zeros(x.shape, pcfg.moment_dtype or x.dtype),
-            params)
-        acc = jax.tree_util.tree_map(jnp.zeros_like, params)
-        return params, m, v, acc
-
-    def init_state_noacc(seed=0):
-        p_, m_, v_, _ = init_state(seed)
-        return p_, m_, v_, None
-
-    init_state.noacc = init_state_noacc
-
-    def train_window(params, m, v, acc, batches, step_no, k):
-        if k != len(batches):
-            raise ValueError(f"k={k} but {len(batches)} batches")
-        if acc is None and k > 1:
-            raise ValueError("k>1 needs the accumulator: use "
-                             "init_state(), not init_state.noacc()")
-        if k == 1 and acc is None:
-            # no-accumulator fast path: saves the 2.6 GB acc buffer —
-            # the minimum-footprint configuration
-            loss, gacc = grad_only_j(params, batches[0])
-        else:
-            for chunk in batches:
-                acc, loss = grad_acc_j(params, acc, chunk)
-            gacc = acc
-        stepa = jnp.asarray(step_no, jnp.float32)
-        pl, tdef = jax.tree_util.tree_flatten(params)
-        ml = jax.tree_util.tree_leaves(m)
-        vl = jax.tree_util.tree_leaves(v)
-        gl = jax.tree_util.tree_leaves(gacc)
-        had_acc = acc is not None
-        # release source trees so each leaf's old buffers free as its
-        # replacement lands (no donation needed to stay in budget)
-        del params, m, v, acc, gacc
-        for i in range(len(pl)):
-            po, mo, vo = apply_j(pl[i], ml[i], vl[i], gl[i], stepa, k)
-            pl[i], ml[i], vl[i] = po, mo, vo
-            # re-zero only when an accumulator persists; the noacc
-            # fast path must not materialize 2.6 GB of dead zeros
-            gl[i] = jnp.zeros_like(gl[i]) if had_acc else None
-        params = jax.tree_util.tree_unflatten(tdef, pl)
-        m = jax.tree_util.tree_unflatten(tdef, ml)
-        v = jax.tree_util.tree_unflatten(tdef, vl)
-        acc = jax.tree_util.tree_unflatten(tdef, gl) if had_acc \
-            else None
-        return params, m, v, acc, loss
-
-    return init_state, train_window
-
-
-def build_flat_accum_bench(cfg: GPTConfig, pcfg: ParallelConfig,
-                           mesh: Mesh, lr=3e-4):
-    """Donation-free benchmark engine: FLAT state vectors + k-chunk
-    gradient accumulation.
-
-    Motivation: when a compile path drops buffer donation, any program
-    whose inputs+outputs carry the full optimizer state (19-24 GB
-    un-aliased) stops fitting in 15.75 GB HBM. This engine keeps every
-    program's in+out+temps under ~12 GB WITHOUT donation:
-
-      grad_acc(params_flat, acc_flat, batch) -> (acc', loss)
-          params unflattened INSIDE the program (XLA slices/reshapes
-          are views — zero copy); grads flattened into one bf16 vector
-          accumulated over k microbatch chunks.
-      apply_half(p, m, v, g, step) x2 halves -> (p', m', v')
-          the uniform AdamW update on flat vector halves, paid once
-          per k chunks — which also amortizes the bandwidth-bound
-          optimizer (~25 ms) by k (a larger-global-batch pretrain
-          config; loss-parity of bf16 moments proven in
-          benchmarks/probes/_r3_moment_parity.py).
-    """
-    tpl = jax.eval_shape(
-        lambda: init_params(cfg, pcfg, jax.random.PRNGKey(0)))
-    leaves, treedef = jax.tree_util.tree_flatten(tpl)
-    shapes = [l.shape for l in leaves]
-    sizes = [int(np.prod(sh)) for sh in shapes]
-    offs = np.concatenate([[0], np.cumsum(sizes)]).tolist()
-    total = offs[-1]
-    half = ((total // 2) // 1024) * 1024
-
-    def unflatten(flat):
-        outs = []
-        for i, sh in enumerate(shapes):
-            outs.append(lax.dynamic_slice_in_dim(
-                flat, offs[i], sizes[i]).reshape(sh))
-        return jax.tree_util.tree_unflatten(treedef, outs)
-
-    def flatten_tree(tree):
-        ls = jax.tree_util.tree_leaves(tree)
-        return jnp.concatenate([l.reshape(-1) for l in ls])
-
-    def grad_acc(params_flat, acc_flat, batch):
-        params = unflatten(params_flat)
-        loss, grads = jax.value_and_grad(
-            lambda p: loss_fn(p, batch, cfg, pcfg, mesh))(params)
-        gflat = flatten_tree(grads).astype(acc_flat.dtype)
-        return acc_flat + gflat, loss
-
-    def apply_half(p, m, v, g, step, k):
-        return _adamw_leaf(p, m, v, g / k, step, lr)
-
-    grad_acc_j = jax.jit(grad_acc, donate_argnums=(1,))
-    apply_j = jax.jit(apply_half, donate_argnums=(0, 1, 2),
-                      static_argnums=(5,))
-
-    def init_state(seed=0):
-        params = init_params(cfg, pcfg, jax.random.PRNGKey(seed))
-        pf = flatten_tree(params).astype(pcfg.param_dtype)
-        md = pcfg.moment_dtype or pcfg.param_dtype
-        m = jnp.zeros((total,), md)
-        v = jnp.zeros((total,), md)
-        acc = jnp.zeros((total,), pcfg.param_dtype)
-        return pf, m, v, acc
-
-    def train_window(pf, m, v, acc, batches, step_no, k):
-        """k grad chunks + the split apply; returns new state+loss."""
-        for chunk in batches:
-            acc, loss = grad_acc_j(pf, acc, chunk)
-        stepa = jnp.asarray(step_no, jnp.float32)
-        outs = []
-        for lo_, hi_ in ((0, half), (half, total)):
-            ph, mh, vh, gh = (x[lo_:hi_] for x in (pf, m, v, acc))
-            outs.append(apply_j(ph, mh, vh, gh, stepa, k))
-        pf = jnp.concatenate([outs[0][0], outs[1][0]])
-        m = jnp.concatenate([outs[0][1], outs[1][1]])
-        v = jnp.concatenate([outs[0][2], outs[1][2]])
-        acc = jnp.zeros_like(acc)
-        return pf, m, v, acc, loss
-
-    return init_state, train_window, unflatten
 
 
 def setup(cfg: GPTConfig, pcfg: ParallelConfig, seed=0, devices=None):

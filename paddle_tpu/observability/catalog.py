@@ -4,7 +4,7 @@ Every ``obs.counter/gauge/histogram`` (and ``_count`` wrapper) call
 site in ``paddle_tpu/`` must use a name declared here; a lint-style
 test (``tests/test_metric_catalog.py``) AST-walks the package and
 fails on any emission whose name is missing, so dashboards, the
-Prometheus scrape endpoint, and the ratio-based perf gate can never
+Prometheus scrape endpoint and the benchmark's readers can never
 silently drift from what the code actually emits.
 
 Each entry: ``kind`` (counter|gauge|histogram), ``help`` (one line,

@@ -2,12 +2,11 @@
 
 The write side (metrics.py) answers "record this"; this module answers
 "what happened between two points in time" — the primitive every
-telemetry *consumer* needs (the auto-tuner scoring a candidate, the
-perf gate pinning a ratio, bench.py embedding a capture):
+telemetry *consumer* needs (the auto-tuner scoring a candidate, a test
+or a benchmark loop bracketing its window):
 
   * ``Snapshot`` — an indexed, immutable view of one ``REGISTRY``
-    export (or of a snapshot list re-loaded from a BENCH json's
-    embedded ``telemetry`` blob);
+    export (or of a snapshot list re-loaded from a persisted one);
   * ``delta(before, after)`` — counter/histogram movement between two
     snapshots plus the gauge end-state, with derived per-second rates;
   * ``window()`` — a context manager bracketing a block of work with
@@ -54,9 +53,9 @@ class Snapshot:
     def from_metrics(cls, metrics: List[dict],
                      ts: Optional[float] = None) -> "Snapshot":
         """Rebuild a Snapshot from a persisted snapshot list — e.g.
-        the ``telemetry.metrics`` blob bench.py embeds in each BENCH
-        json, so the perf gate reads the exact registry state that
-        produced the recorded numbers."""
+        ``obs.dump()``'s list written beside a run's numbers, so a
+        later reader sees the exact registry state that produced
+        them."""
         return cls(metrics, ts=ts if ts is not None else 0.0)
 
     # ------------------------------------------------------- lookups

@@ -3,8 +3,7 @@
 One funnel — ``record_step(dt_s, samples=, tokens=)`` — shared by the
 hapi trainer, the fleet pipeline facade, and user loops: it feeds the
 step-time histogram, the samples/s / tokens/s gauges, and (when the
-model's arithmetic cost is configured) the achieved-MFU gauge, using
-the same flops math as bench.py (cost_model.gpt_flops_per_token).
+model's arithmetic cost is configured) the achieved-MFU gauge.
 """
 from __future__ import annotations
 
@@ -22,7 +21,8 @@ _peak_flops: Optional[float] = None
 def configure(flops_per_token: Optional[float] = None,
               peak_flops: Optional[float] = None) -> None:
     """Declare the model's cost so record_step can derive MFU.
-    flops_per_token: e.g. cost_model.gpt_flops_per_token(cfg, seq);
+    flops_per_token: the caller's own count of its model's operations
+    a trained token;
     peak_flops: accelerator peak (default: the attached device's, via
     cost_model.attached_chip_spec)."""
     global _flops_per_token, _peak_flops

@@ -59,9 +59,10 @@ def flash_attention(q, k, v, causal: bool = False,
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
     s_q, s_k = qt.shape[2], kt.shape[2]
-    # tuned on v5e (benchmarks/probes/_attn_chain*.py): 512 blocks win over
-    # 1024 (VMEM pressure in the dkv/dq kernels); head_dim >= 128 is
-    # what keeps the MXU full — the model zoo defaults to 128-dim heads
+    # tuned on an earlier v5e (probes in git history before PR 30):
+    # 512 blocks win over 1024 (VMEM pressure in the dkv/dq kernels);
+    # head_dim >= 128 is what keeps the MXU full — the model zoo
+    # defaults to 128-dim heads
     bq = min(512, s_q)
     bk = min(512, s_k)
     blk = BlockSizes(
@@ -136,8 +137,8 @@ def flash_attention_maybe(q, k, v, causal=False, scale=None):
         return jnp.swapaxes(out, 1, 2)
     if q.shape[1] == k.shape[1] and sa2.supported(bhsd, q.dtype):
         # middle tier: q streams in blocks, k/v whole in VMEM
-        # (3.30 vs 3.64 ms/layer vs library flash at S=2048 —
-        # benchmarks/probes/_qblock_bench.py)
+        # (3.30 vs 3.64 ms/layer vs library flash at S=2048 on an
+        # earlier chip; the probe is in git history before PR 30)
         qt = jnp.swapaxes(q, 1, 2)
         kt = jnp.swapaxes(k, 1, 2)
         vt = jnp.swapaxes(v, 1, 2)

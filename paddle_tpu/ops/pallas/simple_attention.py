@@ -1,8 +1,8 @@
 """Monolithic Pallas attention for short sequences (TPU).
 
-Motivation (benchmarks/probes/_attn_*.py on v5e): at S<=1024 a whole (batch,
-head) slice — q/k/v [S,D] plus the full [S,S] score matrix — fits in
-VMEM (~7 MB of the ~16 MB/core), so the streaming-softmax machinery of
+Motivation (probes on an earlier v5e, in git history before PR 30): at
+S<=1024 a whole (batch, head) slice — q/k/v [S,D] plus the full [S,S]
+score matrix — fits in VMEM (~7 MB of the ~16 MB/core), so the streaming-softmax machinery of
 the general flash kernel (jax.experimental.pallas.ops.tpu.flash_attention)
 buys nothing and its multi-block pipeline costs ~20 us/program of
 overhead. This kernel does the whole slice in ONE program per (b, h):
@@ -34,7 +34,8 @@ def _pl():
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *, sm_scale, causal, bh):
     # bh heads per program: amortizes grid overhead (0.56 vs 0.76
-    # ms/layer at bh=2 on v5e — benchmarks/probes/_simple_attn_h2.py)
+    # ms/layer at bh=2 on an earlier v5e; the probe is in git history
+    # before PR 30)
     for hh in range(bh):
         q = q_ref[0, hh].astype(jnp.float32)        # [S, D]
         k = k_ref[0, hh].astype(jnp.float32)
@@ -105,8 +106,8 @@ def simple_attention(q, k, v, sm_scale, causal=True, interpret=False):
 def _fwd_block_h(s, d, h, dtype):
     """Heads per fwd program. bh=2 wins standalone (0.56 vs 0.76
     ms/layer) but LOSES ~4% end-to-end inside the remat train step
-    (VMEM pressure vs XLA scheduling — benchmarks/probes/_simple_attn_h2.py
-    vs bench.py runs), so stay at 1."""
+    (VMEM pressure vs XLA scheduling; both read on an earlier chip by
+    scripts in git history before PR 30), so stay at 1."""
     return 1
 
 

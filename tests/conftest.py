@@ -29,8 +29,9 @@ import pytest  # noqa: E402
 
 # tests/ top level carries only test modules plus these two helpers.
 # One-off measurement probes (the `_*.py` scripts that used to pollute
-# the tests dir and its grep results) live in benchmarks/probes/ where
-# pytest never collects them; this guard keeps it that way.
+# the tests dir and its grep results) do not belong here: a chip script
+# of one PR goes to the git-ignored _chip_scratch/; this guard keeps
+# it that way.
 _ALLOWED_NON_TEST = {"conftest.py", "op_test.py"}
 _strays = sorted(
     f for f in os.listdir(os.path.dirname(os.path.abspath(__file__)))
@@ -38,8 +39,8 @@ _strays = sorted(
     and f not in _ALLOWED_NON_TEST)
 if _strays:
     raise RuntimeError(
-        "non-test modules at tests/ top level: %s — move one-off "
-        "probe scripts to benchmarks/probes/" % ", ".join(_strays))
+        "non-test modules at tests/ top level: %s — one-off probe "
+        "scripts do not belong under tests/" % ", ".join(_strays))
 
 
 @pytest.fixture(autouse=True)

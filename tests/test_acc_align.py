@@ -60,7 +60,7 @@ def torch_loss(p, ids, nh=None):
 
 WIDTH_350M = dict(vocab_size=50304, hidden_size=1024, num_layers=24,
                   num_heads=8, max_seq_len=64)
-# the bench.py flagship model WIDTH (GPT-1.3B: h=2048, 16x128 heads,
+# the flagship model WIDTH (GPT-1.3B: h=2048, 16x128 heads,
 # V=50304). Depth reduced to L=6 and S=32 so the torch-CPU oracle stays
 # tractable (full L=24 takes >25 min on CPU); width is what exercises
 # the 16-head attention path and h=2048 init scaling.
@@ -75,7 +75,7 @@ WIDTH_1_3B = dict(vocab_size=50304, hidden_size=2048, num_layers=6,
     ("medium", MEDIUM, 1, 2, 3, 5e-3),
     # 350M-class width/depth with reduced tokens (B2/S64)
     ("350m_width", WIDTH_350M, 3, 2, 3, 5e-3),
-    # FULL flagship width/depth (the gpt1.3b bench.py model)
+    # FULL flagship width (the GPT-3 1.3B model of the train cells)
     ("bench_width_1_3b", WIDTH_1_3B, 4, 2, 3, 5e-3),
 ])
 def test_loss_curve_matches_torch(name, cfg_d, seed, batch, steps, tol):

@@ -139,7 +139,7 @@ def test_start_up_contracts_in_subprocesses():
               "'cfg': jax.config.jax_compilation_cache_dir, "
               "'backends': sorted(xb._backends)}))")
     smoke = _python("chip_smoke.py")
-    unset = _python("-c", "import paddle_tpu, bench, chip_smoke, "
+    unset = _python("-c", "import paddle_tpu, chip_smoke, "
                     "__graft_entry__, paddle_tpu.distributed.launch.main; "
                     + report, drop=("JAX_COMPILATION_CACHE_DIR",))
     given = _python("-c", report,

@@ -115,25 +115,6 @@ def test_capacity_guard(tiny_model):
         sess.submit(np.zeros(10, np.int32), 8)
 
 
-def test_sync_every_batched_retirement_same_outputs(tiny_model):
-    """sync_every>1 fetches token blocks instead of per-step tokens;
-    outputs are unchanged (retirement lags, wasted decodes discarded,
-    slot caches reset on admission)."""
-    m = tiny_model
-    rng = np.random.RandomState(21)
-    reqs = [(rng.randint(0, 256, (rng.randint(2, 10),))
-             .astype(np.int32), int(rng.randint(2, 7)))
-            for _ in range(5)]
-    sess = ContinuousBatchingSession(m, max_slots=2, max_length=64,
-                                    sync_every=4)
-    rids = [sess.submit(p, b) for p, b in reqs]
-    out = sess.run()
-    for rid, (p, b) in zip(rids, reqs):
-        np.testing.assert_array_equal(out[rid], _isolated(m, p, b),
-                                      err_msg=f"request {rid}")
-    assert sess.executable_counts()[1] == 1
-
-
 def test_run_delivers_each_request_once(tiny_model):
     """run() returns only undelivered completions and releases them —
     a second drain never re-delivers (review finding); request_id
@@ -163,23 +144,65 @@ def test_run_delivers_each_request_once(tiny_model):
     assert set(out3) == {rid3}
 
 
-def test_decode_block_mode_same_outputs(tiny_model):
-    """decode_block=k emits [slots, k] token blocks per dispatch (one
-    while_loop program — the DecodeSession block decoder over the slot
-    batch); outputs are unchanged and the executable count stays 1."""
-    m = tiny_model
+def _five_requests():
     rng = np.random.RandomState(41)
-    reqs = [(rng.randint(0, 256, (rng.randint(2, 10),))
+    return [(rng.randint(0, 256, (rng.randint(2, 10),))
              .astype(np.int32), int(rng.randint(2, 9)))
             for _ in range(5)]
+
+
+@pytest.mark.parametrize("decode_block", [1, 4, 16],
+                         ids=["block1", "block4", "block16"])
+def test_decode_block_mode_same_outputs(tiny_model, decode_block):
+    """decode_block=k emits [slots, k] token blocks per dispatch (one
+    while_loop program — the DecodeSession block decoder over the slot
+    batch); retirement lags up to k-1 steps, the wasted decodes are
+    discarded and the slot's cache is reset on admission, so outputs are
+    unchanged and the executable count stays 1."""
+    m = tiny_model
+    reqs = _five_requests()
     sess = ContinuousBatchingSession(m, max_slots=2, max_length=64,
-                                     decode_block=4)
+                                     decode_block=decode_block)
     rids = [sess.submit(p, b) for p, b in reqs]
     out = sess.run()
     for rid, (p, b) in zip(rids, reqs):
         np.testing.assert_array_equal(out[rid], _isolated(m, p, b),
                                       err_msg=f"request {rid}")
     assert sess.executable_counts()[1] == 1
+
+
+def test_a_session_without_decode_block_runs_the_block_program(tiny_model):
+    """No ``decode_block`` is a block of one step through the one decode
+    program: the tokens of ``decode_block=1`` and of the isolated decodes,
+    one decode executable, ``serving.decode_lane_steps`` = slots x
+    dispatches. A keyword the session does not have (``sync_every``) raises
+    ``TypeError``, as any other does."""
+    import paddle_tpu.observability as obs
+
+    obs.enable()
+    m = tiny_model
+    reqs = _five_requests()
+
+    def run(**session):
+        with obs.window() as w, ContinuousBatchingSession(
+                m, max_slots=2, max_length=64, **session) as sess:
+            rids = [sess.submit(p, b) for p, b in reqs]
+            out = sess.run()
+            assert sess.executable_counts()[1] == 1
+        return [out[r] for r in rids], w
+
+    got, w = run()
+    one, w_one = run(decode_block=1)
+    for a, b, (p, n) in zip(got, one, reqs):
+        np.testing.assert_array_equal(a, b)
+        np.testing.assert_array_equal(a, _isolated(m, p, n))
+    dispatches = w.value("serving.step_phase_s", phase="dispatch")
+    assert dispatches > 0
+    assert w.value("serving.decode_lane_steps") == 2 * dispatches
+    assert w_one.value("serving.decode_lane_steps") == 2 * dispatches
+    with pytest.raises(TypeError, match="sync_every"):
+        ContinuousBatchingSession(m, max_slots=2, max_length=64,
+                                  sync_every=2)
 
 
 def _both_paths(monkeypatch, reqs, **session):
@@ -295,19 +318,19 @@ def test_cache_write_program_under_block_diffusion(monkeypatch):
     assert _write_forms(w) == {"row_dma", "update_slice"}
 
 
-@pytest.mark.parametrize("session", [
-    dict(decode_block=4), dict(decode_block=4, sync_every=2),
-    dict(sync_every=3)], ids=["block4", "block4_sync2", "sync3"])
+@pytest.mark.parametrize("decode_block", [1, 4, 16],
+                         ids=["block1", "block4", "block16"])
 def test_decode_kernel_when_a_lane_steps_past_the_capacity(monkeypatch,
-                                                           session):
+                                                           decode_block):
     """A request that fills its slot to the last position (prompt + budget
-    - 1 == max_length) and whose budget ends inside a decode block: the
-    lane steps on, at lengths past the capacity, until the host retires
-    it, one or more dispatches later. The kernel then reads the whole slot
-    and no further, and every request's tokens equal the einsum path's;
-    the other slot admits and retires meanwhile."""
+    - 1 == max_length) and whose budget ends inside a decode block (at the
+    block's end where it is one step): the lane steps on, at lengths past
+    the capacity, until the block ends and the host retires it. The kernel
+    then reads the whole slot and no further, and every request's tokens
+    equal the einsum path's; the other slot admits and retires meanwhile,
+    its discarded steps overwritten by the next admit's reset."""
     (ref, w_ref), (got, w) = _both_paths(
-        monkeypatch, ((50, 15), (3, 5), (9, 6)), **session)
+        monkeypatch, ((50, 15), (3, 5), (9, 6)), decode_block=decode_block)
     assert [len(x) for x in ref] == [50 + 15, 3 + 5, 9 + 6]
     for a, b in zip(got, ref):
         np.testing.assert_array_equal(a, b)

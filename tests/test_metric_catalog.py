@@ -1,7 +1,7 @@
 """Lint-style gate: every metric the framework emits is declared in
 the single canonical catalog (observability/catalog.py), and the
 catalog carries no dead names — so dashboards, the Prometheus scrape
-endpoint, and the ratio-based perf gate can never silently drift from
+endpoint and the benchmark's readers can never silently drift from
 the emission sites (ISSUE 13 satellite).
 
 Pure AST walk over paddle_tpu/ — no imports of the walked modules, no

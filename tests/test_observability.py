@@ -215,8 +215,7 @@ def test_snapshot_delta_window_and_rates():
     assert w.value("t.read_ctr", k="a") == 5.0
     assert w.delta.dt >= 0.0
 
-    # from_metrics round-trips a persisted snapshot (the BENCH
-    # telemetry blob path the perf gate reads)
+    # from_metrics round-trips a persisted snapshot
     blob = json.loads(json.dumps(after.metrics))
     restored = obs.Snapshot.from_metrics(blob)
     assert restored.value("t.read_ctr", k="a") == 40.0

@@ -18,26 +18,13 @@ program's footprint. These gates pin the invariants on CPU, in seconds:
 Reference analog: the CI op-benchmark regression gate
 (/root/reference/tools/ci_op_benchmark.sh) — an automated tripwire, not
 a human remembering to re-measure.
-
-RATIO-BASED rungs (ISSUE 13): the gate pins WITHIN-WINDOW RATIOS (two
-quantities measured in the same capture: s4096/s1024 MFU,
-dataloader-fed/pinned, cb/per-step-decode) and telemetry-derived
-invariants read from the registry snapshot a bench json embeds under its
-``telemetry`` key. Absolute throughputs are reported informationally
-only — they are NOT asserted. ``check_ratio_rungs`` is exercised on a
-synthetic capture; the chip records the bands were first drawn from
-were deleted in PR 21, and the ledger's per-cell bounds are to replace
-the bands (ROADMAP).
 """
-import json
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from paddle_tpu.models.gpt import GPTConfig
 from paddle_tpu.models import gpt_hybrid as GH
-from paddle_tpu.observability import Snapshot
 
 FLAGSHIP = GPTConfig(vocab_size=50304, hidden_size=2048, num_layers=24,
                      num_heads=16, max_seq_len=1024)
@@ -159,146 +146,3 @@ def test_gradient_merge_accumulator_dtype():
     # decode's executable-count stability is gated in
     # tests/test_decode.py::test_decode_executable_stability
 
-
-# ===================================================================
-# Ratio-based regression rungs (ISSUE 13). The bands below were drawn
-# from an earlier chip record, deleted in PR 21 (s4096/s1024 0.870,
-# s2048/s1024 0.929, dataloader-fed/pinned 1.007, cb/per-step decode
-# 1.83 there); nothing on the current code has been measured against
-# them yet.
-#   * s4096/s1024 MFU ratio: the blocked kernel only dispatches where
-#     it measures faster, so the floor is pre-kernel-minus-margin.
-#   * dataloader-fed vs pinned batch: the loader must not throttle the
-#     step; the ceiling catches a formula bug (the loader cannot beat a
-#     pinned batch by 10%).
-#   * cb vs per-step decode, SAME window: the floor only trips when
-#     continuous batching falls below the naive path it exists to beat.
-RATIO_RUNGS = {
-    "train_s4096.mfu_ratio_vs_s1024": (0.82, 1.05),
-    "train_s2048.mfu_ratio_vs_s1024": (0.87, 1.10),
-    "train_dataloader_fed.vs_pinned_batch": (0.97, 1.10),
-    "serve_cb_block16.vs_decode_b8": (0.80, 6.0),
-}
-
-#: trace-time analytic bubble fraction ceiling per schedule family
-#: (read from the BENCH json's embedded telemetry snapshot)
-BUBBLE_CEILING = {"zbh1": 0.2, "zbvpp": 0.2}
-BUBBLE_CEILING_DEFAULT = 0.5
-
-S4096_MFU_TARGET = 0.62   # the s4096 roofline question (bench.py)
-
-
-def check_ratio_rungs(parsed):
-    """Gate one parsed BENCH document. Returns (checked, failures,
-    missing): ``checked`` maps every rung that was present to its
-    value, ``failures`` lists band violations, ``missing`` names rungs
-    this capture did not carry (informational — older captures predate
-    some rungs). Absolute throughputs are never asserted here."""
-    checked, failures, missing = {}, [], []
-    rungs = parsed.get("rungs") or {}
-    for name, (lo, hi) in RATIO_RUNGS.items():
-        rung_name, key = name.split(".")
-        v = rungs.get(rung_name) or {}
-        v = v.get(key) if isinstance(v, dict) else None
-        if v is None:
-            missing.append(name)
-            continue
-        checked[name] = v
-        if lo is not None and v < lo:
-            failures.append(f"{name}={v} below floor {lo}")
-        if hi is not None and v > hi:
-            failures.append(f"{name}={v} above ceiling {hi}")
-
-    # --- telemetry-derived rungs: the registry snapshot embedded in
-    # the same artifact (bench.py writes it under "telemetry")
-    tel = (parsed.get("telemetry") or {}).get("metrics")
-    if tel is None:
-        missing.append("telemetry")
-        return checked, failures, missing
-    snap = Snapshot.from_metrics(tel)
-    for d in snap.series("pipeline.bubble_fraction"):
-        sched = (d.get("labels") or {}).get("schedule", "?")
-        name = f"telemetry.bubble_fraction[{sched}]"
-        val = d.get("value", 0.0)
-        checked[name] = val
-        ceil = BUBBLE_CEILING.get(sched, BUBBLE_CEILING_DEFAULT)
-        if not (0.0 <= val <= ceil):
-            failures.append(f"{name}={val} outside [0, {ceil}]")
-    # a measured long-context rung must have gone through the
-    # instrumented dispatch chain — the kernel choice is recorded, not
-    # inferred
-    s4096 = rungs.get("train_s4096") or {}
-    if "mfu" in s4096:
-        n_disp = sum(d.get("value", 0)
-                     for d in snap.series("attn.dispatch"))
-        name = "telemetry.attn_dispatches"
-        checked[name] = n_disp
-        if n_disp <= 0:
-            failures.append(
-                f"{name}: s4096 measured but no attn.dispatch "
-                "counters in the embedded snapshot")
-    return checked, failures, missing
-
-
-def test_ratio_gate_trips_and_passes(tmp_path):
-    """The gate logic itself, against a BENCH json on disk (the exact
-    read path the real artifacts take): a healthy capture passes every
-    rung including the telemetry-derived ones; a regressed capture
-    fails on the regressed rungs and ONLY those."""
-    telemetry = {"ts": 0.0, "metrics": [
-        {"name": "pipeline.bubble_fraction", "type": "gauge",
-         "labels": {"schedule": "1f1b"}, "value": 0.27},
-        {"name": "pipeline.bubble_fraction", "type": "gauge",
-         "labels": {"schedule": "zbh1"}, "value": 0.03},
-        {"name": "attn.dispatch", "type": "counter",
-         "labels": {"kernel": "blocked_bq512_bkv512"}, "value": 2.0},
-        {"name": "train.mfu", "type": "gauge", "labels": {},
-         "value": 0.63},
-    ]}
-    good = {
-        "metric": "gpt1.3b_train_tokens_per_sec_per_chip",
-        "value": 15736.8, "mfu": 0.6779,
-        "rungs": {
-            "train_s2048": {"mfu": 0.6295,
-                            "mfu_ratio_vs_s1024": 0.9286},
-            "train_s4096": {"mfu": 0.63, "mfu_ratio_vs_s1024": 0.9294,
-                            "attn_kernel": "blocked_bq512_bkv512"},
-            "train_dataloader_fed": {"vs_pinned_batch": 1.0066},
-            "serve_cb_block16": {"tokens_per_sec": 423.3,
-                                 "vs_decode_b8": 1.832},
-            "decode_gpt1.3b_b8": {"tokens_per_sec": 231.1},
-        },
-        "telemetry": telemetry,
-    }
-    p = tmp_path / "BENCH_synthetic.json"
-    p.write_text(json.dumps({"n": 99, "parsed": good}))
-    parsed = json.loads(p.read_text())["parsed"]
-    checked, failures, missing = check_ratio_rungs(parsed)
-    assert not failures, failures
-    assert not missing
-    # >= 3 ratio rungs pinned, the headline one among them, plus the
-    # telemetry-derived bubble/dispatch invariants
-    assert len([k for k in checked if k in RATIO_RUNGS]) >= 3
-    assert "train_s4096.mfu_ratio_vs_s1024" in checked
-    assert "telemetry.bubble_fraction[1f1b]" in checked
-    assert "telemetry.attn_dispatches" in checked
-
-    # regressed capture: s4096 ratio collapses, zbh1 bubble explodes,
-    # cb falls below the naive decode path
-    bad = json.loads(json.dumps(good))
-    bad["rungs"]["train_s4096"]["mfu_ratio_vs_s1024"] = 0.70
-    bad["rungs"]["serve_cb_block16"]["vs_decode_b8"] = 0.5
-    bad["telemetry"]["metrics"][1]["value"] = 0.35   # zbh1 bubble
-    _, failures, _ = check_ratio_rungs(bad)
-    assert len(failures) == 3, failures
-    assert any("train_s4096" in f for f in failures)
-    assert any("vs_decode_b8" in f for f in failures)
-    assert any("zbh1" in f for f in failures)
-
-    # a capture missing a rung reports it missing — never a false trip
-    sparse = {"rungs": {"train_dataloader_fed":
-                        {"vs_pinned_batch": 1.0}}}
-    checked, failures, missing = check_ratio_rungs(sparse)
-    assert not failures
-    assert "train_s4096.mfu_ratio_vs_s1024" in missing
-    assert "telemetry" in missing

@@ -385,7 +385,9 @@ def test_slow_step_chaos_trips_the_deadline(lm):
 @pytest.mark.chaos
 def test_step_wide_failure_raises_and_session_stays_closeable(lm):
     """When DISJOINT slot subsets keep failing, bisection refuses to
-    quarantine innocents: step()/run() raise ServingStepError, and the
+    quarantine innocents: step()/run() raise ServingStepError, the step
+    that raised still delivers its admits' tokens (nothing stays pending
+    for a later step to credit to a slot's next request), and the
     exception path still releases the metrics-server refcount via the
     session lifecycle (context exit / close)."""
     os.environ[obs_server.PORT_ENV] = "0"
@@ -394,12 +396,12 @@ def test_step_wide_failure_raises_and_session_stays_closeable(lm):
     with ContinuousBatchingSession(lm, max_slots=2, max_length=16,
                                    step_backoff_s=0.0) as sess:
         assert obs_server.shared_server() is not None
-        sess.submit(_prompt(rng), 4)
-        sess.submit(_prompt(rng), 4)
-        sess.step()
+        rids = [sess.submit(_prompt(rng), 4), sess.submit(_prompt(rng), 4)]
         _chaos.install("serving.decode_step", kind="error")
         with pytest.raises(ServingStepError, match="disjoint"):
             sess.run()
+        assert sess._pending == []
+        assert [sess.generated(r) for r in rids] == [1, 1]
     # exception path through run(): the context exit released the ref
     assert obs_server.shared_server() is None
     sess.close()                               # double-close idempotent
@@ -435,18 +437,15 @@ def test_chaos_rules_inert_without_env(lm):
         _chaos.clear()
 
 
-def test_cancel_mid_sync_window_does_not_deadlock_results(lm):
-    """Regression (review finding): with sync_every>1, cancelling the
-    only running request mid-window used to wedge results() — pending
-    below the sync quantum blocked draining, the empty running set
-    blocked dispatch, and the non-empty pending blocked admission.
-    The partial window must flush so queued work proceeds."""
+def test_cancelling_the_only_running_request_serves_the_queue(lm):
+    """Cancelling the only running request between two steps leaves no
+    slot decoding: the next step admits the queued request into the freed
+    slot, serves it, and results() returns."""
     rng = np.random.RandomState(16)
-    sess = ContinuousBatchingSession(lm, max_slots=1, max_length=16,
-                                     sync_every=3)
+    sess = ContinuousBatchingSession(lm, max_slots=1, max_length=16)
     victim = sess.submit(_prompt(rng), 8)
     queued = sess.submit(_prompt(rng), 3)
-    sess.step()                                # 1 < sync_every pending
+    sess.step()
     assert sess.cancel(victim)
     t0 = time.perf_counter()
     res = sess.results()
