@@ -307,7 +307,10 @@ def train_phase(cfg, batch, seq, steps, *, scan_unroll, warmup=2, dp=1,
     Checks: step-0 loss within 0.5 of ln(vocab); every loss finite; the last
     loss below the first; nothing traced or compiled after warm-up; when
     ``expect_kernel`` is given, that Pallas kernel was dispatched and no
-    attention dispatch fell back on an error. Returns a dict of what it saw.
+    attention dispatch fell back on an error; where ZeRO-1 shards the
+    moments (dp > 1), no weight matrix of the layer stack has dp on its
+    layer dim (``zero1.moment_shard{dim}`` is printed). Returns a dict of
+    what it saw.
     """
     import jax
     import jax.numpy as jnp
@@ -328,6 +331,9 @@ def train_phase(cfg, batch, seq, steps, *, scan_unroll, warmup=2, dp=1,
     with obs.window() as w:
         mesh, params, opt_state, step = setup(cfg, pcfg, seed=seed,
                                               devices=devices)
+        over_layers = sorted(
+            k for k, m in opt_state["m"]["blocks"].items()
+            if m.ndim >= 3 and m.sharding.spec[0] == "dp")
         losses = []
         with mesh:
             t0 = time.perf_counter()
@@ -353,9 +359,12 @@ def train_phase(cfg, batch, seq, steps, *, scan_unroll, warmup=2, dp=1,
                 steady_s = time.perf_counter() - t0
     losses = [float(x) for x in losses]
     dispatch = _moved_counters(w.delta)
+    moments = _moved_counters(w.delta, prefix="zero1.moment_shard")
     peak = _peak_bytes(devices[0])
 
     print(f"[smoke] {tag}: attention dispatch {dispatch or '{}'}")
+    print(f"[smoke] {tag}: moment leaves by the dim dp took "
+          f"{moments or '{} (no moment is dp-sharded)'}")
     print(f"[smoke] {tag}: compile {cold.seconds:.1f}s in {cold()} "
           f"executables (first step {first_step_s:.1f}s wall)")
     print(f"[smoke] {tag}: steady {steady_s / steps * 1e3:.1f} ms/step "
@@ -378,6 +387,11 @@ def train_phase(cfg, batch, seq, steps, *, scan_unroll, warmup=2, dp=1,
         raise AssertionError(
             f"{tag}: {traces()} traces / {compiles()} compiles after "
             "warm-up (expected none)")
+    if over_layers:
+        raise AssertionError(
+            f"{tag}: ZeRO-1 put dp on the layer dim of {over_layers} "
+            f"({moments}): their dp gradient sum cannot be a "
+            "reduce-scatter")
     errors = {k: n for k, n in dispatch.items()
               if k.startswith("attn.dispatch_fallback") and "error" in k}
     if errors:
@@ -388,7 +402,7 @@ def train_phase(cfg, batch, seq, steps, *, scan_unroll, warmup=2, dp=1,
             f"{tag}: expected the {expect_kernel!r} Pallas kernel to be "
             f"dispatched, saw {dispatch}")
 
-    out = {"losses": losses, "dispatch": dispatch,
+    out = {"losses": losses, "dispatch": dispatch, "moments": moments,
            "param_devices": len(
                params["blocks"]["qkv_w"].sharding.device_set),
            "bytes_in_use": [(d.memory_stats() or {}).get("bytes_in_use")

@@ -199,6 +199,15 @@ def param_specs(cfg: GPTConfig, pcfg: ParallelConfig) -> Dict:
     }
 
 
+def _block_stack_dims(pcfg):
+    """Leading layer-stack dims of a ``params["blocks"]`` leaf as
+    shard_params lays it out: [L, ...] at pp == 1, [pp, L/pp, ...] under
+    pp, [pp, chunk, Lc, ...] where a stage holds several chunks."""
+    if pcfg.pp == 1:
+        return 1
+    return 2 + (pcfg.vpp_chunks > 1 or pcfg.pp_schedule == "zbvpp")
+
+
 def shard_params(params, mesh, cfg, pcfg):
     specs = param_specs(cfg, pcfg)
     if pcfg.pp > 1:
@@ -225,7 +234,6 @@ def shard_params(params, mesh, cfg, pcfg):
             params["blocks"] = jax.tree_util.tree_map(
                 lambda x: x.reshape((ng, Lc) + x.shape[1:])[vidx],
                 params["blocks"])
-            extra = (None,)
         elif v > 1:
             if L % (pcfg.pp * v):
                 raise ValueError(
@@ -238,18 +246,17 @@ def shard_params(params, mesh, cfg, pcfg):
                 lambda x: x.reshape((v, pcfg.pp, Lc) + x.shape[1:])
                 .swapaxes(0, 1),
                 params["blocks"])
-            extra = (None,)
         else:
             params["blocks"] = jax.tree_util.tree_map(
                 lambda x: x.reshape((pcfg.pp, L // pcfg.pp)
                                     + x.shape[1:]),
                 params["blocks"])
-            extra = ()
         flat_specs = param_specs(
             cfg, ParallelConfig(**{**pcfg.__dict__, "pp": 1}))["blocks"]
         specs = dict(specs)
+        unsharded = (None,) * (_block_stack_dims(pcfg) - 1)
         specs["blocks"] = jax.tree_util.tree_map(
-            lambda s: P("pp", *extra, None, *tuple(s)[1:]), flat_specs)
+            lambda s: P("pp", *unsharded, *tuple(s)[1:]), flat_specs)
     return jax.tree_util.tree_map(
         lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
         params, specs), specs
@@ -592,18 +599,35 @@ def loss_fn(params, batch, cfg, pcfg, mesh):
 def moment_specs(params, pcfg, specs):
     """P-spec tree for the Adam moments: the param spec, with ZeRO-1
     additionally sharding each not-already-dp-sharded leaf over dp on
-    its first divisible dim (DygraphShardingOptimizer's rank-ownership,
-    expressed as a sharding instead of per-rank slicing)."""
-    def spec_of(x, s):
+    its first divisible free dim (DygraphShardingOptimizer's
+    rank-ownership, expressed as a sharding instead of per-rank slicing).
+    A stacked block leaf takes a dim INSIDE the layer while one divides:
+    each layer's gradient is produced whole on every dp rank, so only a
+    shard within the layer lets its dp sum land as a reduce-scatter in
+    the owner's shard (a shard over the layers costs a full all-reduce,
+    then a send of the reduced layer to its owner). The layer dim is the
+    fallback of a leaf whose own dims do not divide. Counted once a leaf
+    in ``zero1.moment_shard{dim}``."""
+    from paddle_tpu import observability as obs
+
+    def spec_of(path, x, s):
         entry = list(tuple(s)) + [None] * (x.ndim - len(tuple(s)))
-        if pcfg.zero1 and pcfg.dp > 1 and \
-                "dp" not in jax.tree_util.tree_leaves(entry):
-            dims = [i for i, e in enumerate(entry) if e is None
+        if not (pcfg.zero1 and pcfg.dp > 1):
+            return P(*entry)
+        stack = _block_stack_dims(pcfg) \
+            if path[0] == jax.tree_util.DictKey("blocks") else 0
+        took = "none"
+        if "dp" not in jax.tree_util.tree_leaves(entry):
+            free = [i for i, e in enumerate(entry) if e is None
                     and x.shape[i] % pcfg.dp == 0]
-            if dims:
-                entry[dims[0]] = "dp"
+            in_layer = [i for i in free if i >= stack]
+            if free:
+                entry[(in_layer or free)[0]] = "dp"
+                took = "in_layer" if in_layer else "layer"
+        if obs.enabled():
+            obs.counter("zero1.moment_shard", dim=took).inc()
         return P(*entry)
-    return jax.tree_util.tree_map(spec_of, params, specs)
+    return jax.tree_util.tree_map_with_path(spec_of, params, specs)
 
 
 def adamw_init(params, pcfg, mesh, specs, mspecs=None):
@@ -786,6 +810,21 @@ def _train_grads_1f1b(params, batch, cfg, pcfg, mesh):
     return loss, grads
 
 
+def _grads_to_owner(pcfg, mesh, state_specs):
+    """ZeRO-1's half of the gradient reduction: constrain the gradient
+    tree to the moments' specs before the update, so that the dp sum of
+    a leaf lowers to a reduce-scatter into the shard that owns its
+    moments (an all-reduce, then a send of the layer to its owner,
+    otherwise) and the update runs on the shard. Placed once, after any
+    accumulation. Identity where no moment is dp-sharded."""
+    if state_specs is None or not (pcfg.zero1 and pcfg.dp > 1):
+        return lambda grads: grads
+    mspecs = state_specs[1]
+    return lambda grads: jax.tree_util.tree_map(
+        lambda g, s: lax.with_sharding_constraint(
+            g, NamedSharding(mesh, s)), grads, mspecs)
+
+
 def _validate_pp_schedule(pcfg):
     """Shared pp-schedule validation for every engine builder (fused
     train step, split accum engines) — the deadlock/compat guards must
@@ -862,6 +901,7 @@ def build_train_step(cfg: GPTConfig, pcfg: ParallelConfig, mesh: Mesh,
     out_sh = None
     if state_specs is not None:
         out_sh = _state_out_shardings(mesh, *state_specs)
+    to_owner = _grads_to_owner(pcfg, mesh, state_specs)
 
     k = pcfg.gradient_merge_steps
     if k > 1:
@@ -886,7 +926,7 @@ def build_train_step(cfg: GPTConfig, pcfg: ParallelConfig, mesh: Mesh,
 
             zeros = jax.tree_util.tree_map(jnp.zeros_like, params)
             (acc, lsum), _ = jax.lax.scan(body, (zeros, 0.0), chunks)
-            grads = jax.tree_util.tree_map(lambda g: g / k, acc)
+            grads = to_owner(jax.tree_util.tree_map(lambda g: g / k, acc))
             new_params, new_opt = adamw_update(params, grads, opt_state,
                                                lr=lr)
             return new_params, new_opt, lsum / k
@@ -896,7 +936,8 @@ def build_train_step(cfg: GPTConfig, pcfg: ParallelConfig, mesh: Mesh,
 
     def train_step(params, opt_state, batch):
         loss, grads = grads_of(params, batch)
-        new_params, new_opt = adamw_update(params, grads, opt_state, lr=lr)
+        new_params, new_opt = adamw_update(params, to_owner(grads),
+                                           opt_state, lr=lr)
         return new_params, new_opt, loss
 
     return jax.jit(train_step, donate_argnums=(0, 1),
@@ -942,9 +983,10 @@ def build_accum_steps(cfg: GPTConfig, pcfg: ParallelConfig, mesh: Mesh,
     ring (see _make_grad_acc), so gradient merge composes with pp in
     the split engine exactly as in the fused one."""
     grad_step = _make_grad_acc(cfg, pcfg, mesh)
+    to_owner = _grads_to_owner(pcfg, mesh, state_specs)
 
     def apply_step(params, opt_state, acc, k):
-        grads = jax.tree_util.tree_map(lambda a: a / k, acc)
+        grads = to_owner(jax.tree_util.tree_map(lambda a: a / k, acc))
         new_p, new_o = adamw_update(params, grads, opt_state, lr=lr)
         zeroed = jax.tree_util.tree_map(jnp.zeros_like, acc)
         return new_p, new_o, zeroed

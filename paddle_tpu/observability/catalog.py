@@ -207,6 +207,13 @@ CATALOG = {
         "counter", "KV cache writes at trace time, by the form the shape "
         "and backend chose (row_dma: one Pallas program of copies; "
         "update_slice: XLA's dynamic_update_slice)", ("kernel",)),
+    "zero1.moment_shard": _m(
+        "counter", "Adam moment leaves at trace time, by the dim ZeRO-1 "
+        "gave to dp (in_layer: a dim of the leaf's own shape, where a "
+        "layer's dp gradient sum lands as a reduce-scatter; layer: a "
+        "layer-stack dim, the fallback of a leaf whose own dims do not "
+        "divide; none: the leaf carries dp already or no dim divides)",
+        ("dim",)),
     "attn.autotune_candidate_errors": _m(
         "counter", "autotune candidates the compiler or runtime "
         "refused (text kept in the table entry)", ("kernel",)),

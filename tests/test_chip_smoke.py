@@ -45,6 +45,31 @@ def test_multichip_phase_tiny():
     assert [r["param_devices"] for r in rs] == [4, 4]
     assert all(r["losses"][-1] < r["losses"][0] and not r["dispatch"]
                for r in rs)
+    # both layouts shard the moments inside the layer; the two [L, 3h|4h]
+    # biases that tp holds have no dim of their own left (and two layers
+    # do not divide by dp=4)
+    assert [r["moments"] for r in rs] == [
+        {"zero1.moment_shard{dim=in_layer}": 14,
+         "zero1.moment_shard{dim=none}": 2},
+        {"zero1.moment_shard{dim=in_layer}": 14,
+         "zero1.moment_shard{dim=layer}": 2}]
+
+
+def test_train_phase_refuses_moments_over_the_layer_dim(monkeypatch):
+    import jax
+    from jax.sharding import PartitionSpec as P
+    from paddle_tpu.models import gpt_hybrid
+
+    def over_layers(params, pcfg, specs):
+        return jax.tree_util.tree_map(
+            lambda x, s: P("dp", *tuple(s)[1:]) if x.ndim >= 3 else s,
+            params, specs)
+    monkeypatch.setattr(gpt_hybrid, "moment_specs", over_layers)
+    with pytest.raises(AssertionError, match="dp on the layer dim of "
+                       r"\['fc1_w', 'fc2_w', 'proj_w', 'qkv_w'\]"):
+        chip_smoke.train_phase(TINY, batch=8, seq=32, steps=1,
+                               scan_unroll=1, dp=2, tp=2, sp=True,
+                               devices=jax.devices()[:4])
 
 
 def test_serve_phase_tiny():

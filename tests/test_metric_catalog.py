@@ -123,6 +123,15 @@ def test_training_robustness_counters_cataloged():
         assert any(where in s for s in sites), (name, sites)
 
 
+def test_zero1_moment_shard_counter_cataloged():
+    """ISSUE 31: where ZeRO-1 put dp, once a moment leaf, is a counter of
+    the train engine with the one label the chip smoke reads."""
+    entry = catalog.CATALOG["zero1.moment_shard"]
+    assert entry["kind"] == "counter" and entry["labels"] == ("dim",)
+    sites = _emitted_names().get("zero1.moment_shard", [])
+    assert sites and all("models/gpt_hybrid.py" in s for s in sites), sites
+
+
 def test_catalog_entries_well_formed():
     for name, d in catalog.CATALOG.items():
         assert d["kind"] in ("counter", "gauge", "histogram"), name
