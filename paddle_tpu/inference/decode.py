@@ -52,9 +52,112 @@ from paddle_tpu.inference.admission import (AdmissionRejected,  # noqa: F401
                                             RequestResult, RequestState,
                                             ServingStepError)
 
-# Per-layer fixed-capacity cache. k/v: [B, C, num_kv_heads, head_dim];
-# length: [B] int32 — number of valid positions per sequence.
-StaticCache = collections.namedtuple("StaticCache", ["k", "v", "length"])
+# What a slot of the batched decode step is doing (_masked_step)
+_LANE_EMPTY, _LANE_STEPPING, _LANE_PAUSED = 0, 1, 2
+
+
+# A layer's cache entry is one of two kinds, and a session asks both the
+# same things, on raw arrays inside its traced programs:
+#
+#   slot(i), cleared()       a fresh request's one-lane slice of slot i
+#   put_slot(part, i, n)     that slice written back after a prefill of n
+#   prefilling(lens) ..      around a prefill padded past lane b's lens[b]
+#     .. prefilled(lens)       positions: only those count
+#   stepping(lane) ..        around one decode step of which only the
+#     .. stepped(old, active)  stepping lanes' one position counts
+#
+# ``kind`` names it for the gauges; ``rewindable``: a pass can be taken
+# back by putting the length back (generation by diffusion over blocks).
+
+class StaticCache(collections.namedtuple("StaticCache",
+                                         ["k", "v", "length"])):
+    """Per-layer fixed-capacity KV cache. k/v: [B, C, num_kv_heads,
+    head_dim]; length: [B] int32, the number of valid positions per
+    sequence. A position that does not count is a dead row: it is
+    written past the length the entry is left at, and the next position
+    that counts overwrites it."""
+    __slots__ = ()
+    kind, rewindable = "kv", True
+
+    def slot(self, i):
+        return StaticCache(*(lax.dynamic_slice_in_dim(a, i, 1, 0)
+                             for a in self))
+
+    def cleared(self):
+        # fresh slot: the valid region restarts at 0
+        return self._replace(length=jnp.zeros_like(self.length))
+
+    def put_slot(self, part, i, n):
+        return StaticCache(
+            lax.dynamic_update_slice_in_dim(self.k, part.k, i, 0),
+            lax.dynamic_update_slice_in_dim(self.v, part.v, i, 0),
+            lax.dynamic_update_index_in_dim(self.length, n, i, 0))
+
+    def prefilling(self, lens):
+        return self
+
+    def prefilled(self, lens):
+        # the prefill wrote the full padded block: the lengths are the
+        # true prompt lengths (decode steps overwrite the padding's rows)
+        return self._replace(length=lens)
+
+    def stepping(self, lane):
+        # an empty lane is shown at length 0, so attention reads one
+        # block of it and not the retired request's whole text; the
+        # write lands at position 0 of a slot that the next admit's
+        # prefill overwrites from 0. A paused lane is shown as it is:
+        # the row the step writes at its length is dead
+        return self._replace(
+            length=jnp.where(lane == _LANE_EMPTY, 0, self.length))
+
+    def stepped(self, old, active):
+        return self._replace(
+            length=jnp.where(active, self.length, old.length))
+
+
+class RecurrentCache(collections.namedtuple(
+        "RecurrentCache", ["conv", "ssm", "length", "take"],
+        defaults=(None,))):
+    """Per-layer state of a recurrent (state-space) mixer, whatever the
+    length. conv: [K - 1, B, I], the last inputs of the layer's causal
+    convolution; ssm: [B, N, I], the scan state; float32, the channels
+    along the last dim. length: [B] int32, positions consumed. A state has
+    no dead row, so a position that does not count must not reach it:
+    ``take`` ([B] int32; None: all) tells the model how many of a call's
+    positions count in each lane, the model holds the others out of its
+    update, and nothing is selected or copied afterwards. ``take`` is of
+    one call, not a leaf the session keeps."""
+    __slots__ = ()
+    kind, rewindable = "recurrent", False
+
+    def slot(self, i):
+        return RecurrentCache(lax.dynamic_slice_in_dim(self.conv, i, 1, 1),
+                              lax.dynamic_slice_in_dim(self.ssm, i, 1, 0),
+                              lax.dynamic_slice_in_dim(self.length, i, 1, 0))
+
+    def cleared(self):
+        # fresh slot: a zeroed state
+        return RecurrentCache(*(jnp.zeros_like(a) for a in self[:3]))
+
+    def put_slot(self, part, i, n):
+        return RecurrentCache(
+            lax.dynamic_update_slice_in_dim(self.conv, part.conv, i, 1),
+            lax.dynamic_update_slice_in_dim(self.ssm, part.ssm, i, 0),
+            lax.dynamic_update_index_in_dim(self.length, n, i, 0))
+
+    def prefilling(self, lens):
+        return self._replace(take=jnp.broadcast_to(
+            jnp.asarray(lens, jnp.int32), self.length.shape))
+
+    def prefilled(self, lens):
+        return self._replace(take=None)
+
+    def stepping(self, lane):
+        return self._replace(
+            take=(lane == _LANE_STEPPING).astype(jnp.int32))
+
+    def stepped(self, old, active):
+        return self._replace(take=None)
 
 
 def init_static_cache(batch_size, capacity, num_kv_heads, head_dim,
@@ -67,6 +170,22 @@ def init_static_cache(batch_size, capacity, num_kv_heads, head_dim,
     v = zeros([batch_size, capacity, num_kv_heads, head_dim], dtype=dtype)
     length = zeros([batch_size], dtype="int32")
     return StaticCache(k, v, length)
+
+
+def init_recurrent_cache(batch_size, channels, states, window):
+    """Allocate one layer's recurrent state: zeros, float32."""
+    _chaos.hit("serving.cache_alloc", batch=batch_size, capacity=0)
+    from paddle_tpu.ops.creation import zeros
+    return RecurrentCache(
+        zeros([window, batch_size, channels], dtype="float32"),
+        zeros([batch_size, states, channels], dtype="float32"),
+        zeros([batch_size], dtype="int32"))
+
+
+def _entries(caches):
+    """A model's cache entries (of Tensors) as entries of raw arrays."""
+    return [type(c)(*(None if t is None else t._data for t in c))
+            for c in caches]
 
 
 @functools.partial(jax.jit, static_argnames="interpret")
@@ -323,10 +442,11 @@ def _collect_model_state(model):
     return out
 
 
-def _bind_and_run(model, state_tensors, state_arrays, ids_arr,
-                  cache_treedef, cache_arrays, with_expert_load=None):
+def _bind_and_run(model, state_tensors, state_arrays, ids_arr, entries,
+                  with_expert_load=None):
     """Rebind traced state into the live model and run its cached
-    forward (the jit.StaticFunction discipline, serving-only).
+    forward (the jit.StaticFunction discipline, serving-only) over the
+    cache ``entries`` (of raw arrays, as is what it returns).
     ``with_expert_load``: a lane mask; the model's
     ``forward_with_expert_load`` is run instead and its load vector
     returned third."""
@@ -335,10 +455,8 @@ def _bind_and_run(model, state_tensors, state_arrays, ids_arr,
     try:
         for t, a in zip(state_tensors, state_arrays):
             t._data = a
-        caches = jax.tree_util.tree_unflatten(
-            cache_treedef,
-            [Tensor._wrap(a, True) for a in cache_arrays])
-        caches = [StaticCache(*c) for c in caches]
+        caches = jax.tree_util.tree_map(lambda a: Tensor._wrap(a, True),
+                                        list(entries))
         ids = Tensor._wrap(ids_arr, True)
         with paddle.no_grad():
             if with_expert_load is None:
@@ -346,12 +464,9 @@ def _bind_and_run(model, state_tensors, state_arrays, ids_arr,
             else:
                 logits, caches, load = model.forward_with_expert_load(
                     ids, caches, with_expert_load)
-        cache_out = [a._data for a in jax.tree_util.tree_leaves(
-            [tuple(c) for c in caches],
-            is_leaf=lambda x: isinstance(x, Tensor))]
         if with_expert_load is None:
-            return logits._data, cache_out
-        return logits._data, cache_out, load
+            return logits._data, _entries(caches)
+        return logits._data, _entries(caches), load
     finally:
         for t, s in zip(state_tensors, saved):
             t._data = s
@@ -457,32 +572,35 @@ class DecodeSession(_SessionLifecycle):
     def _n_cache_leaves(self):
         if not hasattr(self, "_cache_leaves_n"):
             c = self._model.init_cache(1, max_length=8)
-            self._cache_leaves_n = len(jax.tree_util.tree_leaves(
-                [tuple(x._data for x in layer) for layer in c]))
+            self._cache_leaves_n = len(
+                jax.tree_util.tree_leaves(_entries(c)))
         return self._cache_leaves_n
 
-    def _run_model(self, state_arrays, ids_arr, cache_arrays):
-        return _bind_and_run(self._model, self._state, state_arrays,
-                             ids_arr, self._cache_treedef, cache_arrays)
+    def _run_model(self, state_arrays, ids_arr, cache_arrays, lens=None):
+        """The model over the flat cache leaves; ``lens``: the call is a
+        prefill padded past each sequence's ``lens`` positions."""
+        entries = jax.tree_util.tree_unflatten(self._cache_treedef,
+                                               list(cache_arrays))
+        if lens is not None:
+            entries = [e.prefilling(lens) for e in entries]
+        logits, entries = _bind_and_run(self._model, self._state,
+                                        state_arrays, ids_arr, entries)
+        if lens is not None:
+            entries = [e.prefilled(lens) for e in entries]
+        return logits, jax.tree_util.tree_leaves(entries)
 
     def _prefill_pure(self, *flat):
         n = len(self._state)
         state, (ids, lens, key) = flat[:n], flat[n:n + 3]
         cache_arrays = flat[n + 3:]
-        logits, cache_out = self._run_model(state, ids, cache_arrays)
+        # the prompts are padded to the bucket: each cache entry is told
+        # the true lengths, by its own rule
+        logits, cache_out = self._run_model(state, ids, cache_arrays, lens)
         # last VALID position's logits, per sequence
         b = ids.shape[0]
         last = logits[jnp.arange(b), lens - 1]
         nxt, key = _sample(last, key, self._temperature, self._top_p,
                            self._top_k)
-        # prefill wrote the full padded block: reset lengths to the true
-        # prompt lengths (padding slots get overwritten by decode steps).
-        # The length leaf is located structurally via the cache treedef,
-        # not sniffed by dtype.
-        layers = jax.tree_util.tree_unflatten(self._cache_treedef,
-                                              cache_out)
-        layers = [(k, v, lens) for (k, v, _l) in layers]
-        cache_out = jax.tree_util.tree_leaves(layers)
         return nxt, key, cache_out
 
     def _decode_pure(self, *flat):
@@ -561,10 +679,10 @@ class DecodeSession(_SessionLifecycle):
                       self._max_length)
         padded = jnp.pad(ids, ((0, 0), (0, bucket - s)))
         lens = jnp.full((b,), s, jnp.int32)
-        caches = self._model.init_cache(b, max_length=self._max_length)
-        self._cache_treedef = jax.tree_util.tree_structure(
-            [tuple(c) for c in caches])
-        cache_arrays = [x._data for c in caches for x in c]
+        caches = _entries(
+            self._model.init_cache(b, max_length=self._max_length))
+        self._cache_treedef = jax.tree_util.tree_structure(caches)
+        cache_arrays = jax.tree_util.tree_leaves(caches)
         state = [t._data for t in self._state]
         if seed is None:
             from paddle_tpu.core import generator as gen_mod
@@ -654,10 +772,6 @@ class DecodeSession(_SessionLifecycle):
                 self._decode_jit._cache_size()
                 + self._decode_block_jit._cache_size())
 
-
-
-# What a slot of the batched decode step is doing (_masked_step)
-_LANE_EMPTY, _LANE_STEPPING, _LANE_PAUSED = 0, 1, 2
 
 
 class _Phase:
@@ -816,11 +930,15 @@ class ContinuousBatchingSession(_SessionLifecycle):
         self._eos = eos_token_id
         self._state_t = _collect_model_state(model)
 
-        caches = model.init_cache(self._slots,
-                                  max_length=self._max_length)
-        self._cache_treedef = jax.tree_util.tree_structure(
-            [tuple(c) for c in caches])
-        self._cache_arrays = [x._data for c in caches for x in c]
+        caches = _entries(model.init_cache(self._slots,
+                                           max_length=self._max_length))
+        self._cache_treedef = jax.tree_util.tree_structure(caches)
+        self._cache_arrays = jax.tree_util.tree_leaves(caches)
+        if _met._ENABLED:
+            for kind in sorted({c.kind for c in caches}):
+                _met.REGISTRY.gauge("cache.bytes", kind=kind).set(sum(
+                    a.nbytes for c in caches if c.kind == kind
+                    for a in jax.tree_util.tree_leaves(c)))
         self._tokens = jnp.zeros((self._slots,), jnp.int32)
         self._key = jax.random.PRNGKey(seed)
 
@@ -897,75 +1015,52 @@ class ContinuousBatchingSession(_SessionLifecycle):
         self._closed = False
 
     # ---------------- compiled programs ------------------------------
-    def _slot_slice(self, cache_arrays, slot):
-        layers = jax.tree_util.tree_unflatten(self._cache_treedef,
-                                              cache_arrays)
-        sliced = [tuple(lax.dynamic_slice_in_dim(a, slot, 1, 0)
-                        for a in layer) for layer in layers]
-        # fresh slot: the valid region restarts at 0
-        sliced = [(k, v, jnp.zeros_like(ln))
-                  for (k, v, ln) in sliced]
-        return jax.tree_util.tree_leaves(sliced)
+    def _entries(self, cache_arrays):
+        return jax.tree_util.tree_unflatten(self._cache_treedef,
+                                            list(cache_arrays))
 
-    def _slot_unslice(self, cache_arrays, slot_leaves, slot, plen):
-        full = jax.tree_util.tree_unflatten(self._cache_treedef,
-                                            cache_arrays)
-        part = jax.tree_util.tree_unflatten(self._cache_treedef,
-                                            slot_leaves)
-        out = []
-        for (fk, fv, fl), (pk, pv, _pl) in zip(full, part):
-            out.append((
-                lax.dynamic_update_slice_in_dim(fk, pk, slot, 0),
-                lax.dynamic_update_slice_in_dim(fv, pv, slot, 0),
-                lax.dynamic_update_index_in_dim(fl, plen, slot, 0)))
-        return jax.tree_util.tree_leaves(out)
+    @staticmethod
+    def _slot_slice(entries, slot):
+        """A fresh request's one-lane entries of ``slot`` (every slice
+        before any clearing: the order the programs have had)."""
+        sliced = [e.slot(slot) for e in entries]
+        return [e.cleared() for e in sliced]
 
     @jax.named_scope("admit")
     def _admit_pure(self, *flat):
         n = len(self._state_t)
         state = flat[:n]
         ids, plen, slot, tokens, key = flat[n:n + 5]
-        cache_arrays = flat[n + 5:]
-        slot_leaves = self._slot_slice(cache_arrays, slot)
-        logits, slot_out = _bind_and_run(
-            self._model, self._state_t, state, ids,
-            self._cache_treedef, slot_leaves)
+        full = self._entries(flat[n + 5:])
+        # the prompt is padded to its bucket: plen of the positions count
+        fresh = [e.prefilling(plen) for e in self._slot_slice(full, slot)]
+        logits, part = _bind_and_run(
+            self._model, self._state_t, state, ids, fresh)
         last = logits[0, plen - 1]
         nxt, key = _sample(last[None], key, self._temperature,
                            self._top_p, self._top_k)
         tokens = lax.dynamic_update_index_in_dim(tokens, nxt[0],
                                                  slot, 0)
-        cache_arrays = self._slot_unslice(cache_arrays, slot_out,
-                                          slot, plen)
-        return tokens, key, cache_arrays
+        full = [e.put_slot(p, slot, plen) for e, p in zip(full, part)]
+        return tokens, key, jax.tree_util.tree_leaves(full)
 
     @jax.named_scope("decode_step")
     def _masked_step(self, state, tok, key, lane, cache_arrays):
         """ONE masked decode step — the single home of the per-slot
         semantics. ``lane`` says what each slot is doing (_LANE_*): only
-        a stepping lane takes its new token and length. Every other lane
-        passes its token through and keeps its length pinned. A paused lane (a
-        live request left out of a recovery probe) is shown to the
-        model as it is: the k/v row the step writes at its length is
-        dead, its own next step overwrites it. An empty lane is shown
-        at length 0, so attention reads one block of it and not the
-        retired request's whole text; the write lands at position 0 of
-        a slot that the next admit's prefill overwrites from 0."""
+        a stepping lane takes its new token, and only its one new
+        position counts in the cache. Every other lane passes its token
+        through and its cache entries keep it where it was, each kind by
+        its own rule (``StaticCache.stepping``, ``RecurrentCache``'s)."""
         active = lane == _LANE_STEPPING
-        old = jax.tree_util.tree_unflatten(self._cache_treedef,
-                                           list(cache_arrays))
-        shown = [(k, v, jnp.where(lane == _LANE_EMPTY, 0, lo))
-                 for (k, v, lo) in old]
-        logits, cache_out = _bind_and_run(
-            self._model, self._state_t, state, tok[:, None],
-            self._cache_treedef, jax.tree_util.tree_leaves(shown))
+        old = self._entries(cache_arrays)
+        shown = [e.stepping(lane) for e in old]
+        logits, new = _bind_and_run(
+            self._model, self._state_t, state, tok[:, None], shown)
         nxt, key = _sample(logits[:, -1], key, self._temperature,
                            self._top_p, self._top_k)
         nxt = jnp.where(active, nxt, tok)
-        new = jax.tree_util.tree_unflatten(self._cache_treedef,
-                                           cache_out)
-        fixed = [(k, v, jnp.where(active, ln, lo))
-                 for (k, v, ln), (_k, _v, lo) in zip(new, old)]
+        fixed = [e.stepped(o, active) for e, o in zip(new, old)]
         return nxt, key, jax.tree_util.tree_leaves(fixed)
 
     def _decode_block_pure(self, *flat):
@@ -994,6 +1089,15 @@ class ContinuousBatchingSession(_SessionLifecycle):
     # ---------------- generation by diffusion over blocks -------------
     def _init_block_diffusion(self, denoising_steps, remasking,
                               confidence_threshold):
+        fixed = [type(e).__name__ for e in self._entries(self._cache_arrays)
+                 if not e.rewindable]
+        if fixed:
+            # a denoising pass is taken back by keeping the length; a
+            # state that has consumed a position cannot give it back
+            raise ValueError(
+                'generation="block_diffusion" takes every denoising pass '
+                f"back, which a {fixed[0]} cannot do "
+                f"({len(fixed)} of the model's cache entries)")
         model = self._model
         blk = int(model.block_length)       # the model's block mask's
         if remasking not in ("low_confidence_static",
@@ -1032,12 +1136,12 @@ class ContinuousBatchingSession(_SessionLifecycle):
         n = len(self._state_t)
         state = flat[:n]
         ids, plen, slot = flat[n:n + 3]
-        cache_arrays = flat[n + 3:]
-        slot_leaves = self._slot_slice(cache_arrays, slot)
-        _logits, slot_out = _bind_and_run(
+        full = self._entries(flat[n + 3:])
+        _logits, part = _bind_and_run(
             self._model, self._state_t, state, ids,
-            self._cache_treedef, slot_leaves)
-        return self._slot_unslice(cache_arrays, slot_out, slot, plen)
+            self._slot_slice(full, slot))
+        return jax.tree_util.tree_leaves(
+            [e.put_slot(p, slot, plen) for e, p in zip(full, part)])
 
     def _block_pure(self, *flat):
         """One block for every stepping lane in ONE program: the
@@ -1051,24 +1155,20 @@ class ContinuousBatchingSession(_SessionLifecycle):
         cache_arrays = tuple(flat[n + 4:])
         blk, steps = self._block_length, self._denoising_steps
         active = lane == _LANE_STEPPING
-        old = jax.tree_util.tree_unflatten(self._cache_treedef,
-                                           list(cache_arrays))
+        old = self._entries(cache_arrays)
         # an empty lane is shown at length 0, as in _masked_step
-        shown = [jnp.where(lane == _LANE_EMPTY, 0, lo)
-                 for (_k, _v, lo) in old]
+        shown = [jnp.where(lane == _LANE_EMPTY, 0, e.length) for e in old]
         counts = jnp.asarray(self._transfer_counts, jnp.int32)
 
         def run_pass(ids, caches):
             """The model over the block at the lengths shown, whatever
             length an earlier pass left in ``caches``."""
-            layers = jax.tree_util.tree_unflatten(self._cache_treedef,
-                                                  list(caches))
-            layers = [(k, v, ln) for (k, v, _l), ln in zip(layers, shown)]
+            layers = [e._replace(length=ln)
+                      for e, ln in zip(self._entries(caches), shown)]
             logits, cache_out, *load = _bind_and_run(
-                self._model, self._state_t, state, ids, self._cache_treedef,
-                jax.tree_util.tree_leaves(layers),
+                self._model, self._state_t, state, ids, layers,
                 with_expert_load=active if self._expert_load_len else None)
-            return logits, tuple(cache_out), \
+            return logits, tuple(jax.tree_util.tree_leaves(cache_out)), \
                 load[0] if load else jnp.zeros((0,), jnp.int32)
 
         @jax.named_scope("block_denoise")
@@ -1102,10 +1202,9 @@ class ContinuousBatchingSession(_SessionLifecycle):
             0, steps, denoise, carry)
         with jax.named_scope("block_commit"):
             _logits, caches, load_c = run_pass(ids, caches)
-        new = jax.tree_util.tree_unflatten(self._cache_treedef,
-                                           list(caches))
-        fixed = [(k, v, jnp.where(active, lo + blk, lo))
-                 for (k, v, _l), (_k, _v, lo) in zip(new, old)]
+        fixed = [e._replace(length=jnp.where(active, o.length + blk,
+                                             o.length))
+                 for e, o in zip(self._entries(caches), old)]
         out = jnp.concatenate([ids.reshape(-1), fixed_at.reshape(-1),
                                load + load_c])
         return out, key, jax.tree_util.tree_leaves(fixed)
@@ -1672,6 +1771,15 @@ class ContinuousBatchingSession(_SessionLifecycle):
         in a long-lived serving session."""
         return {rid: res.ids for rid, res in self.results().items()}
 
+    def cache_entries(self):
+        """The layers' cache entries (``StaticCache`` / ``RecurrentCache``
+        of device arrays) as the last dispatched program left them, for
+        a check or a debugger: slot ``i`` is index ``i`` of a leaf's slot
+        dim, and an entry's ``length`` says how many positions that slot
+        has consumed. The next dispatch donates these arrays: fetch what
+        is wanted before the session is stepped again."""
+        return self._entries(self._cache_arrays)
+
     def close(self):
         """Cancel in-flight work, then release shared resources.
         Queued and running requests transition to CANCELLED (their
@@ -1694,6 +1802,12 @@ class ContinuousBatchingSession(_SessionLifecycle):
         if getattr(self, "_health_unreg", None) is not None:
             self._health_unreg()
             self._health_unreg = None
+        # the compiled programs are bound methods, so the session is a
+        # reference cycle: let go of the cache and of the model here and
+        # not when the collector next looks (a closed session must not
+        # hold a chip's memory against the next model)
+        self._cache_arrays = []
+        self._model = self._state_t = None
         super().close()
 
     def executable_counts(self):

@@ -6,7 +6,8 @@ Submodules import lazily — `from paddle_tpu.models import gpt` etc.
 """
 import importlib
 
-__all__ = ["gpt", "gpt_hybrid", "llama", "bert", "moe", "sdar_moe", "resnet"]
+__all__ = ["gpt", "gpt_hybrid", "llama", "bert", "moe", "sdar_moe", "jamba",
+           "resnet"]
 
 
 def __getattr__(name):

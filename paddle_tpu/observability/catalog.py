@@ -185,6 +185,17 @@ CATALOG = {
     "moe.experts_touched": _m(
         "counter", "experts that a token of any lane reached, summed over "
         "moe.layer_passes: the expert weights the passes had to read"),
+    # ------------------------------------------- state-space layers
+    "ssm.scan_dispatch": _m(
+        "counter", "prefill scans of a state-space mixer at trace time, "
+        "by the form the backend chose (chunked: the Pallas kernel, on a "
+        "TPU; sequential: a lax.scan over time, elsewhere); neither is a "
+        "fallback", ("kernel",)),
+    "cache.bytes": _m(
+        "gauge", "bytes of a serving session's cache at its "
+        "construction, by the kind of entry (kv: keys and values by "
+        "position; recurrent: a state-space layer's window and scan "
+        "state, whatever the length)", ("kind",)),
     # ----------------------------------------------------- dataloader
     "dataloader.fetch_wait_s": _m(
         "histogram", "time the consumer waited on the loader"),

@@ -25,6 +25,13 @@ only the blocks that hold a valid position,
   exponential unit computes.
 - GQA: the ``g`` query heads of a KV head are a static loop over the chunk
   already in registers.
+- One KV head under eight or more query heads (multi-query attention,
+  ``_kernel_shared``): the VPU form would hold one position a register (a
+  row of ``Hkv`` = 1 fills one sublane of eight) and walk it ``g`` times.
+  There the queries are the rows: ``q [g, D] . k [block, D]^T`` and
+  ``p [g, block] . v [block, D]`` go to the MXU at ``highest`` precision
+  (float32 operands, float32 sums), a whole block at a time under the same
+  ring of copies, and the kernel is bound by the copies again.
 
 A length at or past the capacity ``C`` reads all ``C`` positions and no
 more, as the XLA path's mask does: a serving session steps a lane up to a
@@ -91,9 +98,10 @@ def gate_reason(q_shape, cache_shape, cache_dtype):
     return None if need <= _VMEM_BUDGET else "vmem"
 
 
-def _kernel(lens_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems, *,
-            block, chunk):
-    nslots, g, hkv, d = q_ref.shape
+def _block_ring(lens_ref, k_hbm, v_hbm, kbuf, vbuf, sems, nslots, block):
+    """What both kernels walk the cache with: (held, nblocks, copies, ring,
+    fetch, primed) over the valid blocks of all slots, ``primed`` the
+    cursor after the first ``_DEPTH - 1`` copies have been started."""
     capacity = k_hbm.shape[1]
 
     def held(b):
@@ -127,6 +135,21 @@ def _kernel(lens_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems, *,
         done = j + 1 >= nblocks(jnp.minimum(b, nslots - 1))
         return (jnp.where(done, b + 1, b), jnp.where(done, 0, j + 1),
                 ring(buf))
+
+    def primed():
+        cursor = (jnp.int32(0), jnp.int32(0), jnp.int32(0))
+        for _ in range(_DEPTH - 1):
+            cursor = fetch(cursor)
+        return cursor
+
+    return held, nblocks, copies, ring, fetch, primed
+
+
+def _kernel(lens_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems, *,
+            block, chunk):
+    nslots, g, hkv, d = q_ref.shape
+    held, nblocks, copies, ring, fetch, primed = _block_ring(
+        lens_ref, k_hbm, v_hbm, kbuf, vbuf, sems, nslots, block)
 
     pos_thin = lax.broadcasted_iota(jnp.int32, (chunk, hkv, 1), 0)
     pos_full = lax.broadcasted_iota(jnp.int32, (chunk, hkv, d), 0)
@@ -181,10 +204,87 @@ def _kernel(lens_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems, *,
             o_ref[b, i] = acc / l
         return cursor, buf
 
-    cursor = (jnp.int32(0), jnp.int32(0), jnp.int32(0))
-    for _ in range(_DEPTH - 1):
-        cursor = fetch(cursor)
-    lax.fori_loop(0, nslots, slot_body, (cursor, jnp.int32(0)))
+    lax.fori_loop(0, nslots, slot_body, (primed(), jnp.int32(0)))
+
+
+def shared_kv(hkv, g):
+    """Whether the call is ``_kernel_shared``'s: one KV head, and enough
+    query heads on it to fill a register's sublanes."""
+    return hkv == 1 and g >= 8
+
+
+def _kernel_shared(lens_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems,
+                   *, block):
+    """One KV head: q_ref/o_ref [B, G, D] (G: the query heads, padded to
+    whole registers), k_hbm/v_hbm [B, C, D], the ring [_DEPTH, block, D]."""
+    nslots, rows, d = q_ref.shape
+    held, nblocks, copies, ring, fetch, primed = _block_ring(
+        lens_ref, k_hbm, v_hbm, kbuf, vbuf, sems, nslots, block)
+    highest = dict(precision=lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
+
+    col = lax.broadcasted_iota(jnp.int32, (rows, block), 1)
+    row = lax.broadcasted_iota(jnp.int32, (block, d), 0)
+
+    def slot_body(b, carry):
+        n = held(b)
+        q = q_ref[b]
+
+        def block_body(j, carry):
+            cursor, buf, (m, l, acc) = carry
+            cursor = fetch(cursor)      # into the buffer freed a block ago
+            for cp in copies(b, j, buf):
+                cp.wait()
+            here = n - j * block        # positions of the block that count
+            s = lax.dot_general(q, kbuf[buf].astype(jnp.float32),
+                                (((1,), (1,)), ((), ())), **highest)
+            s = jnp.where(col < here, s, NEG_INF)
+            # nothing stored past the length reaches the result
+            v = jnp.where(row < here, vbuf[buf].astype(jnp.float32), 0.0)
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp2(m - m_new)
+            p = jnp.exp2(s - m_new)
+            l = alpha * l + jnp.sum(p, axis=1, keepdims=True)
+            acc = alpha * acc + lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())), **highest)
+            return cursor, ring(buf), (m_new, l, acc)
+
+        init = (jnp.full((rows, 1), NEG_INF, jnp.float32),
+                jnp.zeros((rows, 1), jnp.float32),
+                jnp.zeros((rows, d), jnp.float32))
+        cursor, buf, (_m, l, acc) = lax.fori_loop(0, nblocks(b), block_body,
+                                                  (*carry, init))
+        o_ref[b] = acc / l
+        return cursor, buf
+
+    lax.fori_loop(0, nslots, slot_body, (primed(), jnp.int32(0)))
+
+
+def _decode_shared(q, kbuf, vbuf, lens, block, interpret):
+    """``decode_attention`` where one KV head serves every query head."""
+    b, _s, h, d = q.shape
+    c = kbuf.shape[1]
+    rows = -(-h // 8) * 8               # whole registers of queries
+    qg = q.astype(jnp.float32).reshape(b, h, d) \
+        * (math.log2(math.e) / math.sqrt(d))
+    qg = jnp.pad(qg, ((0, 0), (0, rows - h), (0, 0)))
+    out = pl.pallas_call(
+        functools.partial(_kernel_shared, block=block),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM),
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+            scratch_shapes=[pltpu.VMEM((_DEPTH, block, d), kbuf.dtype),
+                            pltpu.VMEM((_DEPTH, block, d), vbuf.dtype),
+                            pltpu.SemaphoreType.DMA((2, _DEPTH))]),
+        out_shape=jax.ShapeDtypeStruct((b, rows, d), jnp.float32),
+        interpret=interpret,
+    )(lens.astype(jnp.int32), qg, kbuf.reshape(b, c, d),
+      vbuf.reshape(b, c, d))
+    return out[:, :h].reshape(b, 1, h, d)
 
 
 def decode_attention(q, kbuf, vbuf, lens, *, block=None, chunk=None,
@@ -203,6 +303,8 @@ def decode_attention(q, kbuf, vbuf, lens, *, block=None, chunk=None,
     if c % block or block % chunk:
         raise ValueError(f"decode_attention: capacity {c}, block {block}, "
                          f"chunk {chunk} must divide in turn")
+    if shared_kv(hkv, g):
+        return _decode_shared(q, kbuf, vbuf, lens, block, interpret)
     # [B, 1, Hkv*g, D] -> [B, g, Hkv, D]: one [Hkv, D] tile per query group,
     # scaled once for a softmax in base 2
     qg = jnp.swapaxes(q.astype(jnp.float32).reshape(b, hkv, g, d), 1, 2)

@@ -66,6 +66,26 @@ def test_nothing_past_the_valid_length_reaches_the_result(g):
     assert float(jnp.max(jnp.abs(out - ref))) <= 1e-5
 
 
+@pytest.mark.parametrize("lens", [RAGGED, (0,) * 4, PAST_CAPACITY],
+                         ids=["ragged", "all_at_0", "past_capacity"])
+@pytest.mark.parametrize("g", [8, 20], ids=["mqa8", "mqa20"])
+def test_one_kv_head_under_many_queries_goes_through_the_mxu_form(g, lens):
+    """``Hkv`` = 1 and ``g`` >= 8 (ISSUE 32's shape: 20 query heads over
+    one KV head): whole blocks as two float32 products at ``highest``; the
+    einsum path's result, and nothing stored past the length reaches it."""
+    assert da.shared_kv(1, g) and not da.shared_kv(1, 4) \
+        and not da.shared_kv(2, 20)
+    q, kn, vn, kbuf, vbuf, lens = _inputs(lens, g, hkv=1, seed=2)
+    ref, kbuf, vbuf, _ = decode._cache_attention(q, kn, vn, kbuf, vbuf, lens)
+    past = jnp.arange(C)[None, :, None, None] \
+        > jnp.minimum(lens, C - 1)[:, None, None, None]
+    out = _kernel(q, jnp.where(past, jnp.nan, kbuf),
+                  jnp.where(past, jnp.nan, vbuf), lens)
+    assert out.dtype == jnp.float32 and out.shape == q.shape
+    assert bool(jnp.all(jnp.isfinite(out)))
+    assert float(jnp.max(jnp.abs(out - ref))) <= 1e-5
+
+
 def test_sizes_come_from_the_cache_shape_in_code():
     # the serving cells: 256 KB of K a block, 32 registers of scores a chunk
     assert da.sizes(2048, 16, 128, 4) == (32, 16)
@@ -165,7 +185,8 @@ def no_compile_cache():
     (2, 512, 16, 1, jnp.float32),
     (8, 2048, 8, 4, jnp.float32),
     (8, 1024, 2, 2, jnp.float32),
-], ids=["longctx", "chat", "reference_check", "gqa4", "hkv2"])
+    (256, 3072, 1, 20, jnp.float32),
+], ids=["longctx", "chat", "reference_check", "gqa4", "hkv2", "shared_kv"])
 def test_kernel_compiles_for_the_v5e(one_chip, no_compile_cache, b, c, hkv,
                                      g, dtype):
     def spec(shape, dt):
