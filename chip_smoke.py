@@ -298,6 +298,18 @@ def _seeded_ids(cfg, batch, seq, seed):
         0, cfg.vocab_size, (batch, seq)))
 
 
+def _step_collectives(hlo_text, kinds=("collective-permute", "all-to-all")):
+    """{"<kind> <result>": count} of the collectives of ``kinds`` that a
+    compiled step spells out (an asynchronous one by its start)."""
+    import collections
+    import re
+    found = re.findall(
+        r"= \(?(\w+\[[\d,]*\])\S*(?: [^=]*)? (%s)(?:-start)?\("
+        % "|".join(kinds), hlo_text)
+    return dict(collections.Counter(
+        f"{kind} {shape}" for shape, kind in found))
+
+
 def train_phase(cfg, batch, seq, steps, *, scan_unroll, warmup=2, dp=1,
                 tp=1, sp=False, zero1=True, devices=None,
                 expect_kernel=None, seed=0):
@@ -309,8 +321,9 @@ def train_phase(cfg, batch, seq, steps, *, scan_unroll, warmup=2, dp=1,
     ``expect_kernel`` is given, that Pallas kernel was dispatched and no
     attention dispatch fell back on an error; where ZeRO-1 shards the
     moments (dp > 1), no weight matrix of the layer stack has dp on its
-    layer dim (``zero1.moment_shard{dim}`` is printed). Returns a dict of
-    what it saw.
+    layer dim (``zero1.moment_shard{dim}`` is printed). On more than one
+    device the compiled step's collective-permutes and all-to-alls are
+    printed by result shape. Returns a dict of what it saw.
     """
     import jax
     import jax.numpy as jnp
@@ -333,7 +346,7 @@ def train_phase(cfg, batch, seq, steps, *, scan_unroll, warmup=2, dp=1,
                                               devices=devices)
         over_layers = sorted(
             k for k, m in opt_state["m"]["blocks"].items()
-            if m.ndim >= 3 and m.sharding.spec[0] == "dp")
+            if k.endswith("_w") and m.sharding.spec[0] == "dp")
         losses = []
         with mesh:
             t0 = time.perf_counter()
@@ -357,6 +370,11 @@ def train_phase(cfg, batch, seq, steps, *, scan_unroll, warmup=2, dp=1,
                     losses.append(loss)
                 jax.block_until_ready(loss)
                 steady_s = time.perf_counter() - t0
+        # a qkv leaf laid so that a tp rank's columns are not its own
+        # heads shows here as activation-sized permutes and all-to-alls
+        collectives = _step_collectives(
+            step.lower(params, opt_state, (ids, ids)).compile().as_text()) \
+            if len(devices) > 1 else {}
     losses = [float(x) for x in losses]
     dispatch = _moved_counters(w.delta)
     moments = _moved_counters(w.delta, prefix="zero1.moment_shard")
@@ -365,6 +383,9 @@ def train_phase(cfg, batch, seq, steps, *, scan_unroll, warmup=2, dp=1,
     print(f"[smoke] {tag}: attention dispatch {dispatch or '{}'}")
     print(f"[smoke] {tag}: moment leaves by the dim dp took "
           f"{moments or '{} (no moment is dp-sharded)'}")
+    if len(devices) > 1:
+        print(f"[smoke] {tag}: the step's collective-permutes and "
+              f"all-to-alls by result {collectives or '{} (none)'}")
     print(f"[smoke] {tag}: compile {cold.seconds:.1f}s in {cold()} "
           f"executables (first step {first_step_s:.1f}s wall)")
     print(f"[smoke] {tag}: steady {steady_s / steps * 1e3:.1f} ms/step "
@@ -403,6 +424,7 @@ def train_phase(cfg, batch, seq, steps, *, scan_unroll, warmup=2, dp=1,
             f"dispatched, saw {dispatch}")
 
     out = {"losses": losses, "dispatch": dispatch, "moments": moments,
+           "collectives": collectives,
            "param_devices": len(
                params["blocks"]["qkv_w"].sharding.device_set),
            "bytes_in_use": [(d.memory_stats() or {}).get("bytes_in_use")

@@ -165,12 +165,14 @@ def init_params(cfg: GPTConfig, pcfg: ParallelConfig, key) -> Dict:
 
 
 def param_specs(cfg: GPTConfig, pcfg: ParallelConfig) -> Dict:
-    """NamedSharding specs: tp = Megatron; pp = leading stage dim; ep = dp."""
+    """NamedSharding specs: tp = Megatron; pp = leading stage dim; ep = dp.
+    They are the specs of the tree shard_params makes, in which qkv_w lies
+    [L, h, 3, h] and qkv_b [L, 3, h] (``_qkv_per_matrix``)."""
     pp = "pp" if pcfg.pp > 1 else None
     moe = pcfg.num_experts > 0
     blocks = {
         "ln1_g": P(pp, None), "ln1_b": P(pp, None),
-        "qkv_w": P(pp, None, "tp"), "qkv_b": P(pp, "tp"),
+        "qkv_w": P(pp, None, None, "tp"), "qkv_b": P(pp, None, "tp"),
         "proj_w": P(pp, "tp", None), "proj_b": P(pp, None),
         "ln2_g": P(pp, None), "ln2_b": P(pp, None),
     }
@@ -208,8 +210,28 @@ def _block_stack_dims(pcfg):
     return 2 + (pcfg.vpp_chunks > 1 or pcfg.pp_schedule == "zbvpp")
 
 
+def _qkv_per_matrix(blocks, cfg):
+    """qkv_w [L, h, 3h] -> [L, h, 3, h] and qkv_b [L, 3h] -> [L, 3, h], so
+    that a shard of the last dim over tp is a rank's own heads of q, of k
+    and of v, which is what ``_attend`` runs on (a shard of the flat 3h
+    straddles the q|k boundary, and XLA then moves every layer's product
+    and gathers its weight). Row-major: W[..., i, c*h + j] == W'[..., i,
+    c, j], the same function of the same stored numbers, so a tree saved in
+    the flat shape is read by this reshape and one already in the new shape
+    passes through."""
+    L, h = cfg.num_layers, cfg.hidden_size
+    return {**blocks,
+            "qkv_w": blocks["qkv_w"].reshape(L, h, 3, h),
+            "qkv_b": blocks["qkv_b"].reshape(L, 3, h)}
+
+
 def shard_params(params, mesh, cfg, pcfg):
+    """``init_params``' tree (or one saved by an earlier version) laid out
+    for the mesh and placed on it: returns (params, specs). The one door to
+    a mesh of more than one device: ``_block`` reads a flat qkv leaf too,
+    but only this layout keeps a tp rank's columns its own heads'."""
     specs = param_specs(cfg, pcfg)
+    params = {**params, "blocks": _qkv_per_matrix(params["blocks"], cfg)}
     if pcfg.pp > 1:
         # blocks leaves [L, ...] -> [pp, L/pp, ...] (vpp>1:
         # [pp, v, L/(pp*v), ...] — virtual stage sigma = j*pp + s lives
@@ -217,7 +239,6 @@ def shard_params(params, mesh, cfg, pcfg):
         # unsharded, trailing dims keep their tp/ep spec
         L = cfg.num_layers
         v = pcfg.vpp_chunks
-        params = dict(params)
         if pcfg.pp_schedule == "zbvpp":
             # ZB-V placement: virtual stage sigma (of 2*pp) owns layers
             # [sigma*Lc, (sigma+1)*Lc); device s holds vstage s at
@@ -388,14 +409,20 @@ def _block(x, lp, cfg, pcfg, mesh):
     x = _constrain(x, act_spec, mesh)
     hres = x
     hx = _layer_norm(x, lp["ln1_g"], lp["ln1_b"])
+    # [h, 3, h/tp] as shard_params lays it, or a free view of
+    # init_params' flat [h, 3h]: columns [q | k | v] either way
+    qkv_w = lp["qkv_w"].reshape(hx.shape[-1], 3, -1)
+    qkv_b = lp["qkv_b"].reshape(3, -1)
+    # the product comes out [3, b, s, h/tp]: q, k and v are whole slabs
+    # (as [b, s, 3, h/tp] each is strided, and the one-chip train cells
+    # read -0.30 % and +0.07 % where this order reads +0.13 % and +0.63 %:
+    # PERF.md section 6, PR 33)
     if cm:
-        qkv = checkpoint_name(
-            _cm_column(hx, lp["qkv_w"], lp["qkv_b"], mesh), "qkv")
+        qkv = jnp.moveaxis(_cm_column(hx, qkv_w, qkv_b, mesh), 2, 0)
     else:
-        qkv = checkpoint_name(hx @ lp["qkv_w"] + lp["qkv_b"], "qkv")
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    attn = checkpoint_name(_attend(q, k, v, cfg.num_heads, mesh),
-                           "attn_out")
+        qkv = jnp.einsum("bsh,hcn->cbsn", hx, qkv_w) + qkv_b[:, None, None]
+    qkv = checkpoint_name(qkv, "qkv")
+    attn = checkpoint_name(_attend(*qkv, cfg.num_heads, mesh), "attn_out")
     if cm:
         attn = checkpoint_name(
             _cm_row(attn, lp["proj_w"], lp["proj_b"], mesh), "proj")

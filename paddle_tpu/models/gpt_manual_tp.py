@@ -51,8 +51,8 @@ def block_manual_tp(x, lp, cfg: GPTConfig, pcfg, tp_axis="tp"):
 
     Local param shapes (h=hidden, hl=h/tp, m=ffn, ml=m/tp):
       qkv_w [h, 3, hl]  (column-parallel, heads grouped per shard —
-                         the [h, 3h] flat weight reshaped to [h, 3, h]
-                         so the last dim shards per-matrix, not across
+                         gpt_hybrid.shard_params stores it [h, 3, h] so
+                         the last dim shards per-matrix, not across
                          the q|k|v concat)
       qkv_b [3, hl]     proj_w [hl, h] (row-parallel)   proj_b [h]
       fc1_w [h, ml]     fc1_b [ml]     fc2_w [ml, h]    fc2_b [h]
@@ -234,7 +234,8 @@ def ce_vocab_parallel(h, wte_local, labels, tp_axis="tp",
 
 def _manual_blk_flat_specs(moe: bool):
     """Per-layer (no stacking dims) manual partition entries for the
-    reshaped block tree; the leading stacking dims ('pp' + chunk/layer)
+    block tree as gpt_hybrid.shard_params lays it out (qkv_w [h, 3, h],
+    qkv_b [3, h]); the leading stacking dims ('pp' + chunk/layer)
     are prepended per-leaf by rank in `_manual_blk_specs`. moe=True is
     the manual-EP layout (tp=1): expert dims shard over 'dp', dense
     weights replicate."""
@@ -269,31 +270,6 @@ def _manual_blk_specs(blocks, moe: bool):
              *flat[k])
         for k, v in blocks.items()
     }
-
-
-def _reshape_qkv(blocks):
-    """[..., h, 3h] -> [..., h, 3, h] (and bias [..., 3h] -> [..., 3, h])
-    so the manual in_specs shard the last dim PER MATRIX instead of
-    across the q|k|v concat (a flat 3h/tp chunk would straddle the q/k
-    boundary). Row-major reshape: W[..., i, k*h + j] == W'[..., i, k, j]
-    — exactly the split(qkv, 3, -1) the GSPMD path computes, so both
-    paths are the same function of the same stored parameters. GSPMD
-    repartitions the weight at the shard_map boundary (a once-per-step
-    tp all-to-all of ~half the qkv bytes; if this ever shows up on a
-    profile, store the zb-manual engine's qkv in [h, 3, h] layout)."""
-    b = dict(blocks)
-    qw, qb = b["qkv_w"], b["qkv_b"]
-    h3 = qw.shape[-1]
-    b["qkv_w"] = qw.reshape(qw.shape[:-1] + (3, h3 // 3))
-    b["qkv_b"] = qb.reshape(qb.shape[:-1] + (3, h3 // 3))
-    return b
-
-
-def _unreshape_qkv_grads(bgrads, like):
-    g = dict(bgrads)
-    g["qkv_w"] = g["qkv_w"].reshape(like["qkv_w"].shape)
-    g["qkv_b"] = g["qkv_b"].reshape(like["qkv_b"].shape)
-    return g
 
 
 def train_grads_zb_manual_tp(params, batch, cfg: GPTConfig, pcfg, mesh):
@@ -343,7 +319,6 @@ def train_grads_zb_manual_tp(params, batch, cfg: GPTConfig, pcfg, mesh):
     lbl_mb = pipeline_microbatch(labels, m)
     blocks = jax.tree_util.tree_map(lambda p: p.astype(cdt),
                                     params["blocks"])
-    blocks = _reshape_qkv(blocks)
     # non-divisible vocab: pad the head's wte rows up to a multiple of
     # tp (ce_vocab_parallel masks the pad rows to -inf, so they carry
     # no mass and zero grads); the embedding side keeps the true wte.
@@ -403,7 +378,6 @@ def train_grads_zb_manual_tp(params, batch, cfg: GPTConfig, pcfg, mesh):
         out_specs=(P(), blk_specs, hp_specs, dx0_spec))(
             blocks, mb, lbl_mb, head_params)
 
-    bgrads = _unreshape_qkv_grads(bgrads, params["blocks"])
     dwte_e, dwpe = embed_vjp(dx0.reshape(b, s, -1).astype(x.dtype))
     return loss, {
         "wte": dwte_e.astype(jnp.float32)
@@ -535,7 +509,6 @@ def train_grads_zb_manual_ep(params, batch, cfg: GPTConfig, pcfg,
     lbl_mb = pipeline_microbatch(labels, m)
     blocks = jax.tree_util.tree_map(lambda p: p.astype(cdt),
                                     params["blocks"])
-    blocks = _reshape_qkv(blocks)
     head_params = {"wte": params["wte"], "lnf_g": params["lnf_g"],
                    "lnf_b": params["lnf_b"]}
 
@@ -593,7 +566,6 @@ def train_grads_zb_manual_ep(params, batch, cfg: GPTConfig, pcfg,
     # the per-member losses are partial (1/dp-scaled local means):
     # their sum is the global loss
     loss = jnp.sum(loss)
-    bgrads = _unreshape_qkv_grads(bgrads, params["blocks"])
     dwte_e, dwpe = embed_vjp(dx0.reshape(b, s, -1).astype(x.dtype))
     return loss, {
         "wte": dwte_e.astype(jnp.float32) + hgrads["wte"],

@@ -131,11 +131,14 @@ def matmul_reduce_scatter(x, w, axis_name: str):
 
 def sp_column_matmul_local(x_local, w_local, axis_name: str):
     """Per-device body for allgather(x, seq)@W: x_local [B, S/n, K]
-    (sequence shard), w_local [K, F/n] (column shard) ->
-    [B, S, F/n]."""
+    (sequence shard), w_local [K, ..., F/n] (column shard; dims between
+    are whole, as the 3 of a qkv weight [K, 3, F/n]) ->
+    [B, S, ..., F/n]."""
     xt = jnp.swapaxes(x_local, 0, 1)              # [S/n, B, K]
-    ot = all_gather_matmul(xt, w_local, axis_name)  # [S, B, F/n]
-    return jnp.swapaxes(ot, 0, 1)
+    ot = all_gather_matmul(xt, w_local.reshape(w_local.shape[0], -1),
+                           axis_name)             # [S, B, prod(.., F/n)]
+    return jnp.swapaxes(ot, 0, 1).reshape(
+        x_local.shape[0], -1, *w_local.shape[1:])
 
 
 def sp_row_matmul_local(x_local, w_local, axis_name: str):
@@ -171,14 +174,16 @@ def _smap(fn, mesh, in_specs, out_specs, axis_name):
 
 def sp_column_matmul(x, w, mesh, axis_name="mp"):
     """Global-array form (eager or jit): x [B, S, K] sequence-sharded
-    over `axis_name`, w [K, F] column-sharded. Ring-overlapped; output
-    [B, S, F] gathered on S, sharded on F. Composes under an enclosing
-    manual region (pp) via mesh inheritance."""
+    over `axis_name`, w [K, ..., F] sharded on its last dim. Ring-
+    overlapped; output [B, S, ..., F] gathered on S, sharded on F.
+    Composes under an enclosing manual region (pp) via mesh
+    inheritance."""
     from jax.sharding import PartitionSpec as P
+    whole = (None,) * (w.ndim - 2)
     return _smap(
         lambda a, b: sp_column_matmul_local(a, b, axis_name),
-        mesh, (P(None, axis_name, None), P(None, axis_name)),
-        P(None, None, axis_name), axis_name)(x, w)
+        mesh, (P(None, axis_name, None), P(None, *whole, axis_name)),
+        P(None, None, *whole, axis_name), axis_name)(x, w)
 
 
 def sp_row_matmul(x, w, mesh, axis_name="mp"):

@@ -29,7 +29,9 @@ def torch_forward(p, ids, nh=None):
     nh = nh if nh is not None else CFG["num_heads"]
     for i in range(L):
         h = F.layer_norm(x, (x.shape[-1],), p["ln1_g"][i], p["ln1_b"][i])
-        qkv = h @ p["qkv_w"][i] + p["qkv_b"][i]
+        # setup's tree holds qkv_w [L, h, 3, h]: columns [q | k | v]
+        qkv = h @ p["qkv_w"][i].reshape(x.shape[-1], -1) \
+            + p["qkv_b"][i].reshape(-1)
         q, k, v = qkv.chunk(3, dim=-1)
         b, s, hid = q.shape
         d = hid // nh

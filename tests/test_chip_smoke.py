@@ -45,6 +45,10 @@ def test_multichip_phase_tiny():
     assert [r["param_devices"] for r in rs] == [4, 4]
     assert all(r["losses"][-1] < r["losses"][0] and not r["dispatch"]
                for r in rs)
+    # the compiled step's permutes and all-to-alls are read and printed;
+    # no tp rank has to be sent another's heads of q, k or v
+    assert all(isinstance(r["collectives"], dict) for r in rs)
+    assert not [k for k in rs[1]["collectives"] if k.endswith("[4,32,64]")]
     # both layouts shard the moments inside the layer; the two [L, 3h|4h]
     # biases that tp holds have no dim of their own left (and two layers
     # do not divide by dp=4)

@@ -116,16 +116,17 @@ def test_moment_specs_put_dp_inside_the_layer(case):
         assert blocks(ms)["fc1_w"] == P(None, "dp", "tp")
         assert blocks(ms)["fc2_w"] == P(None, "tp", "dp")
         assert blocks(ms)["proj_w"] == P(None, "tp", "dp")
-        assert blocks(ms)["qkv_w"] == P(None, "dp", "tp")
+        assert blocks(ms)["qkv_w"] == P(None, "dp", None, "tp")
         assert blocks(ms)["ln1_g"] == P(None, "dp")
-        # [L, 3h] over tp has no free dim of its own: the layer dim
-        assert blocks(ms)["qkv_b"] == P("dp", "tp")
+        # [L, 3, h] over tp has no free dim of its own that divides: the
+        # layer dim
+        assert blocks(ms)["qkv_b"] == P("dp", None, "tp")
         assert ms["wte"] == P("tp", "dp")
         assert ticks == {"zero1.moment_shard{dim=in_layer}": 14,
                          "zero1.moment_shard{dim=layer}": 2}
     elif case == "inner_dims_do_not_divide":
         _, _, ms, ticks = _specs_of(ODD, tp=1, sp=False)
-        assert blocks(ms)["qkv_w"] == P("dp", None, "tp")
+        assert blocks(ms)["qkv_w"] == P("dp", None, None, "tp")
         assert blocks(ms)["proj_w"] == P("dp", "tp", None)
         assert blocks(ms)["ln1_g"] == P("dp", None)
         assert ms["wpe"] == P("dp", None) and ms["lnf_g"] == P(None)
@@ -140,7 +141,7 @@ def test_moment_specs_put_dp_inside_the_layer(case):
         assert blocks(ms)["fc1_w"] == P("pp", None, "dp", "tp")
         assert blocks(ms)["ln1_g"] == P("pp", None, "dp")
         # the fallback of a leaf with no free dim of its own
-        assert blocks(ms)["qkv_b"] == P("pp", "dp", "tp")
+        assert blocks(ms)["qkv_b"] == P("pp", "dp", None, "tp")
         assert ticks["zero1.moment_shard{dim=layer}"] == 2
         # [pp, chunk, Lc, ...] where a stage holds two chunks
         params, _, ms, _ = _specs_of(CFG, pp=2, tp=1, sp=False,
@@ -205,3 +206,100 @@ def test_moments_saved_over_the_layer_dim_restore_inside_the_layer(tmp_path):
     for k in old:
         assert into[k]._data.sharding.spec == mspecs["blocks"][k] != old[k]
         np.testing.assert_array_equal(np.asarray(into[k]._data), want[k])
+
+
+# ------------------------------------------------ the qkv layout on the mesh
+
+def _plain_logits(p, ids):
+    """The benchmark's plain reference (``benchmarks/ledger/arch/
+    gpt_dense.py``, what ``matches_reference`` compares a train cell with)
+    on ``init_params``' own tree: the product over the flat [h, 3h] weight,
+    split in thirds (columns [q | k | v])."""
+    import importlib.util
+    import os
+    spec = importlib.util.spec_from_file_location(
+        "ledger_arch_gpt_dense", os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "benchmarks", "ledger", "arch", "gpt_dense.py"))
+    arch = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(arch)
+    return arch.reference_logits(p, ids, CFG.num_heads)
+
+
+def _plain_loss(p, ids):
+    logp = jax.nn.log_softmax(_plain_logits(p, ids)[:, :-1], -1)
+    return -jnp.mean(jnp.take_along_axis(logp, ids[:, 1:, None], -1))
+
+
+@pytest.mark.parametrize("pcfg_kw", [
+    dict(dp=1, pp=1, tp=1),
+    dict(dp=2, pp=1, tp=2, sp=True),
+    dict(dp=1, pp=2, tp=2, microbatches=4),
+    # the manual-tp stage body (models/gpt_manual_tp.py)
+    dict(dp=1, pp=2, tp=2, microbatches=4, pp_schedule="zbh1"),
+], ids=["one_device", "dp2_tp2_sp", "pp2_tp2", "manual_tp"])
+def test_qkv_layout_parity_with_a_plain_split_in_thirds(pcfg_kw):
+    """``shard_params`` lays qkv_w [L, h, 3, h] with tp on the last dim:
+    the same function of ``init_params``' numbers as the flat product
+    split in thirds, logits and gradients, on every engine."""
+    pcfg = ParallelConfig(param_dtype=jnp.float32, fused_ce=False,
+                          compute_dtype=jnp.float32, **pcfg_kw)
+    L, h = CFG.num_layers, CFG.hidden_size
+    ids = _batch()[0]
+    flat = init_params(CFG, pcfg, jax.random.PRNGKey(0))
+    # a bias of zeros would not show a bias laid out wrongly
+    flat["blocks"]["qkv_b"] = 0.02 * jax.random.normal(
+        jax.random.PRNGKey(1), (L, 3 * h))
+    assert flat["blocks"]["qkv_w"].shape == (L, h, 3 * h)
+    want_loss, want = jax.value_and_grad(_plain_loss)(flat, ids)
+    mesh = build_mesh(pcfg, jax.devices()[:pcfg.dp * pcfg.pp * pcfg.tp])
+    with mesh:
+        params, specs = shard_params(flat, mesh, CFG, pcfg)
+        blocks = params["blocks"]
+        assert blocks["qkv_w"].shape[-3:] == (h, 3, h)
+        assert blocks["qkv_b"].shape[-2:] == (3, h)
+        assert tuple(specs["blocks"]["qkv_w"])[-3:] == (None, None, "tp")
+        assert blocks["qkv_w"].sharding.shard_shape(
+            blocks["qkv_w"].shape)[-1] == h // pcfg.tp
+        if pcfg.pp_schedule == "zbh1":
+            from paddle_tpu.models.gpt_hybrid import _train_grads_1f1b
+            loss, got = jax.jit(lambda p: _train_grads_1f1b(
+                p, (ids, ids), CFG, pcfg, mesh))(params)
+        else:
+            from paddle_tpu.models.gpt_hybrid import forward
+            logits = jax.jit(
+                lambda p: forward(p, ids, CFG, pcfg, mesh))(params)
+            np.testing.assert_allclose(logits, _plain_logits(flat, ids),
+                                       atol=1e-5, rtol=0)
+            loss, got = jax.jit(jax.value_and_grad(
+                lambda p: loss_fn(p, (ids, ids), CFG, pcfg, mesh)))(params)
+    np.testing.assert_allclose(loss, want_loss, atol=1e-5, rtol=0)
+    for leaf in ("qkv_w", "qkv_b", "proj_w"):
+        np.testing.assert_allclose(
+            np.asarray(got["blocks"][leaf]).reshape(
+                want["blocks"][leaf].shape),
+            want["blocks"][leaf], atol=1e-5, rtol=0, err_msg=leaf)
+
+
+def test_a_tree_saved_in_the_flat_shape_loads_into_the_new_one(tmp_path):
+    """An older checkpoint holds qkv_w [L, h, 3h]: ``shard_params`` reads
+    it by the reshape it applies to ``init_params``' tree, and a tree saved
+    from ``setup`` ([L, h, 3, h]) passes through unchanged."""
+    pcfg = ParallelConfig(dp=1, pp=1, tp=2, param_dtype=jnp.float32,
+                          compute_dtype=jnp.float32)
+    L, h = CFG.num_layers, CFG.hidden_size
+    old = init_params(CFG, pcfg, jax.random.PRNGKey(0))["blocks"]
+    np.savez(tmp_path / "old.npz", **old)
+    saved = dict(np.load(tmp_path / "old.npz"))
+    assert saved["qkv_w"].shape == (L, h, 3 * h)
+    mesh, params, _, _ = setup(CFG, pcfg, seed=0, devices=jax.devices()[:2])
+    for tree in ({**params, "blocks": saved}, params):
+        with mesh:
+            loaded, _ = shard_params(tree, mesh, CFG, pcfg)
+        for k, v in loaded["blocks"].items():
+            assert v.shape == params["blocks"][k].shape, k
+            assert v.sharding == params["blocks"][k].sharding, k
+            np.testing.assert_array_equal(v, params["blocks"][k])
+    # column c*h + j of the flat weight is [c, j] of the new one
+    np.testing.assert_array_equal(
+        params["blocks"]["qkv_w"][1, :, 2, 5], saved["qkv_w"][1, :, 2 * h + 5])

@@ -261,8 +261,8 @@ def test_named_scopes_reach_the_lowered_train_step():
     pcfg = gh.ParallelConfig(param_dtype=jnp.float32,
                              compute_dtype=jnp.float32, fused_ce=True)
     mesh = gh.build_mesh(pcfg, jax.devices()[:1])
-    params = gh.init_params(cfg, pcfg, jax.random.PRNGKey(0))
-    specs = gh.param_specs(cfg, pcfg)
+    params, specs = gh.shard_params(
+        gh.init_params(cfg, pcfg, jax.random.PRNGKey(0)), mesh, cfg, pcfg)
     opt = gh.adamw_init(params, pcfg, mesh, specs)
     step = gh.build_train_step(cfg, pcfg, mesh)
     ids = jnp.zeros((2, 32), jnp.int32)
