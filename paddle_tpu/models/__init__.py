@@ -7,7 +7,7 @@ Submodules import lazily — `from paddle_tpu.models import gpt` etc.
 import importlib
 
 __all__ = ["gpt", "gpt_hybrid", "llama", "bert", "moe", "sdar_moe", "jamba",
-           "resnet"]
+           "lfm2_moe", "resnet"]
 
 
 def __getattr__(name):

@@ -28,7 +28,7 @@ from __future__ import annotations
 import functools
 import math
 from dataclasses import dataclass
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import numpy as np
 import jax
@@ -722,6 +722,35 @@ def adamw_update(params, grads, opt_state, lr=3e-4, b1=0.9, b2=0.95,
 
 
 # --------------------------- train step ------------------------------------
+@dataclass(frozen=True)
+class TrainModel:
+    """What the engine asks of a model: ``build_train_step`` and ``setup``
+    take one, the GPT of this module (``GPT``) where none is given. The
+    mesh, AdamW and its moments' specs, ``_grads_to_owner``, the jitted and
+    donated step and ``_ce_from_hidden`` are the engine's and the same for
+    every model.
+
+    ``init_params(cfg, pcfg, key)``, ``shard_params(params, mesh, cfg,
+    pcfg) -> (params, specs)`` and ``loss_fn(params, batch, cfg, pcfg,
+    mesh)`` as the GPT's are. ``frozen``: top-level keys of the parameter
+    tree that the optimizer must not touch: no gradient is taken with
+    respect to them, they get no moments and no decay. A model that names
+    any returns ``(loss, aux)`` from its ``loss_fn`` and brings
+    ``update_frozen(frozen leaves, aux) -> frozen leaves``, its own update
+    of them, which the step applies after AdamW's."""
+    init_params: Callable
+    shard_params: Callable
+    loss_fn: Callable
+    frozen: Tuple[str, ...] = ()
+    update_frozen: Optional[Callable] = None
+
+    def split(self, tree):
+        """(what the optimizer sees, the frozen leaves) of a parameter
+        tree or of a tree of its shape (its specs)."""
+        return ({k: v for k, v in tree.items() if k not in self.frozen},
+                {k: tree[k] for k in self.frozen})
+
+
 def _train_grads_1f1b(params, batch, cfg, pcfg, mesh):
     """Loss + grads via the compiled-1F1B pipeline (O(pp) activation
     liveness — parallel/pipeline_1f1b.py) instead of jax.grad over the
@@ -909,17 +938,22 @@ def _validate_pp_schedule(pcfg):
             "route); running WITHOUT the ring overlap", stacklevel=3)
 
 
-def build_train_step(cfg: GPTConfig, pcfg: ParallelConfig, mesh: Mesh,
-                     lr=3e-4, state_specs=None):
-    _validate_pp_schedule(pcfg)
-    if pcfg.pp > 1 and pcfg.pp_schedule in ("1f1b", "zbh1", "zbvpp"):
-        def grads_of(params, batch):
-            return _train_grads_1f1b(params, batch, cfg, pcfg, mesh)
-    else:
-        def grads_of(params, batch):
-            return jax.value_and_grad(
-                lambda p: loss_fn(p, batch, cfg, pcfg, mesh))(params)
+def _lr_at(lr, opt_state):
+    """The step's learning rate: ``lr`` itself, or ``lr(step)`` of a
+    schedule (the count of the step being taken, 1 at the first)."""
+    return lr(opt_state["step"] + 1) if callable(lr) else lr
 
+
+def build_train_step(cfg: GPTConfig, pcfg: ParallelConfig, mesh: Mesh,
+                     lr=3e-4, state_specs=None, model: TrainModel = None):
+    """The jitted, donated ``step(params, opt_state, batch) -> (params,
+    opt_state, loss)`` of ``model`` (the GPT where none is given).
+    ``state_specs``: (the parameters' specs, the moments' specs), the
+    second over the leaves the optimizer sees. ``lr``: a number, or a
+    schedule ``lr(step)`` (``_lr_at``; the gradient-merge step takes a
+    number)."""
+    model = model or GPT
+    _validate_pp_schedule(pcfg)
     # pin the step's outputs to the INPUT state shardings: left to
     # GSPMD, the output spec can drift (e.g. wte P('tp',None) ->
     # P(None,'tp')), which both reshards every step and makes the
@@ -929,6 +963,18 @@ def build_train_step(cfg: GPTConfig, pcfg: ParallelConfig, mesh: Mesh,
     if state_specs is not None:
         out_sh = _state_out_shardings(mesh, *state_specs)
     to_owner = _grads_to_owner(pcfg, mesh, state_specs)
+    if model.frozen:
+        return jax.jit(
+            _frozen_train_step(cfg, pcfg, mesh, lr, model, to_owner),
+            donate_argnums=(0, 1), out_shardings=out_sh)
+
+    if pcfg.pp > 1 and pcfg.pp_schedule in ("1f1b", "zbh1", "zbvpp"):
+        def grads_of(params, batch):
+            return _train_grads_1f1b(params, batch, cfg, pcfg, mesh)
+    else:
+        def grads_of(params, batch):
+            return jax.value_and_grad(
+                lambda p: model.loss_fn(p, batch, cfg, pcfg, mesh))(params)
 
     k = pcfg.gradient_merge_steps
     if k > 1:
@@ -964,11 +1010,34 @@ def build_train_step(cfg: GPTConfig, pcfg: ParallelConfig, mesh: Mesh,
     def train_step(params, opt_state, batch):
         loss, grads = grads_of(params, batch)
         new_params, new_opt = adamw_update(params, to_owner(grads),
-                                           opt_state, lr=lr)
+                                           opt_state,
+                                           lr=_lr_at(lr, opt_state))
         return new_params, new_opt, loss
 
     return jax.jit(train_step, donate_argnums=(0, 1),
                    out_shardings=out_sh)
+
+
+def _frozen_train_step(cfg, pcfg, mesh, lr, model, to_owner):
+    """``build_train_step``'s step for a model that keeps leaves from the
+    optimizer: the gradient is taken with respect to the others alone,
+    AdamW updates those, and the model's own rule the frozen ones from what
+    its ``loss_fn`` returned beside the loss."""
+    if pcfg.pp > 1 or pcfg.gradient_merge_steps > 1:
+        raise ValueError("a model with frozen leaves trains at pp == 1 "
+                         "and gradient_merge_steps == 1")
+
+    def train_step(params, opt_state, batch):
+        train, frozen = model.split(params)
+        (loss, aux), grads = jax.value_and_grad(
+            lambda t: model.loss_fn({**t, **frozen}, batch, cfg, pcfg,
+                                    mesh), has_aux=True)(train)
+        new_train, new_opt = adamw_update(train, to_owner(grads), opt_state,
+                                          lr=_lr_at(lr, opt_state))
+        return ({**new_train, **model.update_frozen(frozen, aux)},
+                new_opt, loss)
+
+    return train_step
 
 
 def _make_grad_acc(cfg, pcfg, mesh):
@@ -1054,15 +1123,23 @@ def _adamw_leaf(p, m, v, g, step, lr, b1=0.9, b2=0.95, eps=1e-8,
             m_new.astype(m.dtype), v_new.astype(v.dtype))
 
 
-def setup(cfg: GPTConfig, pcfg: ParallelConfig, seed=0, devices=None):
-    """Returns (mesh, params, opt_state, train_step)."""
+#: the model of this module, and the engine's default
+GPT = TrainModel(init_params, shard_params, loss_fn)
+
+
+def setup(cfg: GPTConfig, pcfg: ParallelConfig, seed=0, devices=None,
+          model: TrainModel = None, lr=3e-4):
+    """Returns (mesh, params, opt_state, train_step) of ``model`` (the GPT
+    where none is given); moments only for what its optimizer sees."""
+    model = model or GPT
     mesh = build_mesh(pcfg, devices)
     key = jax.random.PRNGKey(seed)
-    params = init_params(cfg, pcfg, key)
+    params = model.init_params(cfg, pcfg, key)
     with mesh:
-        params, specs = shard_params(params, mesh, cfg, pcfg)
-        mspecs = moment_specs(params, pcfg, specs)
-        opt_state = adamw_init(params, pcfg, mesh, specs, mspecs=mspecs)
-    step_fn = build_train_step(cfg, pcfg, mesh, lr=3e-4,
-                               state_specs=(specs, mspecs))
+        params, specs = model.shard_params(params, mesh, cfg, pcfg)
+        seen, seen_specs = model.split(params)[0], model.split(specs)[0]
+        mspecs = moment_specs(seen, pcfg, seen_specs)
+        opt_state = adamw_init(seen, pcfg, mesh, seen_specs, mspecs=mspecs)
+    step_fn = build_train_step(cfg, pcfg, mesh, lr=lr,
+                               state_specs=(specs, mspecs), model=model)
     return mesh, params, opt_state, step_fn

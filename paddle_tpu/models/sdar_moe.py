@@ -143,6 +143,112 @@ def _gmm_tiling(k, n):
     return (128, k, tn)
 
 
+def _widest(n, most):
+    """The widest divisor of n that is a multiple of 128 and at most
+    ``most`` (n itself where there is none)."""
+    return max((c for c in range(128, n + 1, 128)
+                if n % c == 0 and c <= most), default=n)
+
+
+def _row_tile(m):
+    """256 rows where the (padded) row count divides, else the 128 it is
+    padded to."""
+    return 256 if m % 256 == 0 else 128
+
+
+def _gmm_dx_tiling(m, k, n, itemsize=2):
+    """Tiles of the rows' cotangent, [m, k] x [held, n, k]^T: 256 rows, the
+    whole contraction, a weight tile within 3 MB. Measured on the v5e at
+    the train cell's shapes (65,536 rows in 64 groups, 16 held, bf16;
+    PERF.md, PR 34; even split / a skewed one): [., 3072] -> 2048 at (256,
+    3072, 512) 2.25 / 2.36 ms, (128, 3072, 256) 2.71 / 2.76, (512, 1536,
+    1024) 2.04 / 2.56; [., 2048] -> 1536 at (256, 2048, 768) 1.38 / 1.47,
+    (128, 2048, 512) 1.50 / 1.56. Rows of 512 win on an even split and
+    lose on a skewed one, which is what routing gives. Operands wider than
+    bf16 (``itemsize``) take proportionally narrower tiles."""
+    return (_row_tile(m), k, _widest(n, 3 * 2 ** 20 // (itemsize * k)))
+
+
+def _gmm_dw_tiling(m, k, n, itemsize=2):
+    """Tiles (rows, k, n) of the weights' cotangent, [k, m] x [m, n] ->
+    [held, k, n]: 256 rows a step into a [tk, tn] float32 accumulator of up
+    to 1024 x 1024. Same measurement: [2048, .] x [., 3072] at (256, 1024,
+    1024) 1.52 / 1.80 ms, (128, 512, 1024) 2.07 / 2.30, (512, 1024, 1024)
+    1.38 / 1.92; [1536, .] x [., 2048] at (256, 768, 1024) 0.87 / 1.00,
+    (128, 512, 1024) 1.10 / 1.22, (512, 768, 1024) 0.79 / 1.06. The
+    forward's tiles stay PR 28's: at these shapes the forward's time is
+    its passes over the [65536, .] output, and no tile moved it by more
+    than 6 % (4.17 ms at (128, 2048, 512), 3.93 at the best)."""
+    return (_row_tile(m), _widest(k, 2048 // itemsize),
+            _widest(n, 2048 // itemsize))
+
+
+def _count_dispatch(which):
+    """Ticked where a backward pass is built (``_gmm_fwd``, ``_gmm_bwd``):
+    a forward-only trace (serving) moves no ``moe.*`` name unless its
+    model counts the expert load, and ``ragged_dot`` differentiates
+    itself, out of this module's sight."""
+    if _met._ENABLED:
+        _met.REGISTRY.counter("moe.grouped_dispatch", kernel="gmm",
+                              **{"pass": which}).inc()
+
+
+def _pad_rows(x):
+    """Rows up to the kernels' row tile of 128."""
+    return jnp.pad(x, ((0, -x.shape[0] % 128), (0, 0)))
+
+
+def _gmm_forward(lhs, rhs, group_sizes, out_dtype, interpret):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+    m, k = lhs.shape
+    return gmm(_pad_rows(lhs), rhs, group_sizes,
+               preferred_element_type=out_dtype,
+               tiling=_gmm_tiling(k, rhs.shape[2]), interpret=interpret)[:m]
+
+
+#: the kernel's product [m, k] x [held, k, n] -> [m, n] (float32
+#: accumulation, stored as ``out_dtype``: a float32 [65536, 3072] result
+#: that is rounded afterwards costs a pass over 805 MB) with a backward
+#: pass (the raw kernel has no differentiation rule)
+_gmm = jax.custom_vjp(_gmm_forward, nondiff_argnums=(3, 4))
+
+
+def _gmm_fwd(lhs, rhs, group_sizes, out_dtype, interpret):
+    _count_dispatch("fwd")
+    return (_gmm_forward(lhs, rhs, group_sizes, out_dtype, interpret),
+            (lhs, rhs, group_sizes))
+
+
+def _gmm_bwd(out_dtype, interpret, residuals, g):
+    """Cotangent of the rows: the same grouped product against the held
+    weights transposed; of the weights: the transposed grouped product
+    (``tgmm``) of rows and cotangent over the same groups, the held ones
+    only. Both accumulate in float32 and come out in their primal's dtype;
+    rows of absent groups receive zero (the kernel does not visit them and
+    zeroes what it left) and contribute to no weight."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
+    lhs, rhs, group_sizes = residuals
+    m, k = lhs.shape
+    n = rhs.shape[2]
+    with jax.named_scope("moe_experts_bwd"):
+        _count_dispatch("dx")
+        _count_dispatch("dw")
+        g = _pad_rows(g.astype(lhs.dtype))
+        d_lhs = gmm(g, rhs, group_sizes, preferred_element_type=lhs.dtype,
+                    tiling=_gmm_dx_tiling(g.shape[0], n, k, g.dtype.itemsize),
+                    transpose_rhs=True,
+                    interpret=interpret)[:m]
+        d_rhs = tgmm(_pad_rows(lhs).swapaxes(0, 1), g, group_sizes,
+                     preferred_element_type=rhs.dtype,
+                     tiling=_gmm_dw_tiling(g.shape[0], k, n,
+                                           g.dtype.itemsize),
+                     num_actual_groups=rhs.shape[0], interpret=interpret)
+    return d_lhs, d_rhs, None
+
+
+_gmm.defvjp(_gmm_fwd, _gmm_bwd)
+
+
 def _grouped(lhs, rhs, group_sizes, out_dtype, interpret=False):
     """Rows of ``lhs`` [m, k] in consecutive groups, group g of
     ``group_sizes[g]`` rows times ``rhs[g]`` [k, n]; ``rhs`` holds the
@@ -150,21 +256,16 @@ def _grouped(lhs, rhs, group_sizes, out_dtype, interpret=False):
 
     On a TPU the megablox grouped matmul (a Pallas kernel that visits
     only the (row tile, group) pairs that exist, so each touched expert's
-    weights are streamed about once); elsewhere ``jax.lax.ragged_dot``,
-    which on the v5e read the same weights at less than half the rate
-    (PERF.md, PR 28)."""
+    weights are streamed about once), differentiable through ``_gmm_bwd``;
+    elsewhere ``jax.lax.ragged_dot`` and its own differentiation, which on
+    the v5e read the same weights at less than half the rate (PERF.md,
+    PR 28)."""
     held = rhs.shape[0]
     if not (_kernel_backend() or interpret):
         out = lax.ragged_dot(lhs, rhs, group_sizes[:held],
                              preferred_element_type=jnp.float32)
     else:
-        from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
-        m, k = lhs.shape
-        pad = -m % 128                      # the kernel's row tile
-        out = gmm(jnp.pad(lhs, ((0, pad), (0, 0))), rhs, group_sizes,
-                  preferred_element_type=jnp.float32,
-                  tiling=_gmm_tiling(k, rhs.shape[2]),
-                  interpret=interpret)[:m]
+        out = _gmm(lhs, rhs, group_sizes, out_dtype, interpret)
     if held < group_sizes.shape[0]:
         rows = jnp.arange(lhs.shape[0])[:, None]
         out = jnp.where(rows < jnp.sum(group_sizes[:held]), out, 0.0)

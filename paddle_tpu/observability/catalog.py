@@ -173,18 +173,31 @@ CATALOG = {
         "(queue/slot pressure past thresholds)"),
     # ---------------------------------------------- mixture of experts
     "moe.assignments": _m(
-        "counter", "(token, expert) pairs routed in the stepping lanes of "
-        "the block-diffusion passes, all layers"),
+        "counter", "(token, expert) pairs routed, all layers: in the "
+        "stepping lanes of the block-diffusion passes; in training, over "
+        "every step (ticked once from the train state's running sum)"),
     "moe.busiest_expert_assignments": _m(
         "counter", "pairs routed to the busiest expert of a layer in a "
-        "pass, summed over layers and passes: x num_experts / "
-        "moe.assignments is 1.0 where the load is even"),
+        "pass (in training: in a step), summed over layers and passes: x "
+        "num_experts / moe.assignments is 1.0 where the load is even"),
     "moe.layer_passes": _m(
         "counter", "expert layers run: layers x passes of the "
-        "block-diffusion dispatches"),
+        "block-diffusion dispatches; in training, the expert layers whose "
+        "running sums were read"),
     "moe.experts_touched": _m(
         "counter", "experts that a token of any lane reached, summed over "
         "moe.layer_passes: the expert weights the passes had to read"),
+    "moe.held_assignments": _m(
+        "counter", "pairs routed to the experts held here (expert_offset "
+        ".. + num_local_experts), of moe.assignments: what the grouped "
+        "products really multiplied"),
+    "moe.grouped_dispatch": _m(
+        "counter", "grouped products of a differentiated expert layer at "
+        "trace time, by kernel (gmm: the megablox Pallas kernels, on a "
+        "TPU; jax.lax.ragged_dot, elsewhere, differentiates itself and is "
+        "not counted) and pass (fwd; dx: the rows' cotangent; dw: the "
+        "weights', tgmm); a forward-only trace ticks nothing",
+        ("kernel", "pass")),
     # ------------------------------------------- state-space layers
     "ssm.scan_dispatch": _m(
         "counter", "prefill scans of a state-space mixer at trace time, "
