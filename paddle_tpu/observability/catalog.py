@@ -227,6 +227,10 @@ CATALOG = {
     "attn.dispatch_fallback": _m(
         "counter", "shape-gate rejections falling back to XLA",
         ("reason",)),
+    "attn.matmul_operands": _m(
+        "counter", "traced calls of an attention kernel, by the dtype "
+        "its matrix products take their operands in (the arrays' own; "
+        "every product accumulates in float32)", ("kernel", "dtype")),
     "cache.write_dispatch": _m(
         "counter", "KV cache writes at trace time, by the form the shape "
         "and backend chose (row_dma: one Pallas program of copies; "

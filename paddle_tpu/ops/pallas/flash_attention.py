@@ -91,7 +91,13 @@ def flash_attention_maybe(q, k, v, causal=False, scale=None):
     [S,S] scores no longer fit (S<=2048), q-block kernel for the
     non-causal middle tier, then the q×kv-blocked flash kernel for the
     MAC-bound long-S regime (S>=4096 — VMEM residency O(block^2), no
-    S-cap), with the jax library flash kernel as the final tier."""
+    S-cap), with the jax library flash kernel as the final tier.
+
+    The blocked kernel's products take their operands in the dtype of
+    q, k and v as they are handed over (bf16 arrays multiply natively,
+    float32 arrays in float32 operands) and accumulate in float32; a
+    traced call of it ticks ``attn.matmul_operands{kernel=blocked,
+    dtype}`` beside ``attn.dispatch{kernel=blocked}``."""
     if jax.default_backend() != "tpu":
         return None
     if not _supported(q, k, v):

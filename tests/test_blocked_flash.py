@@ -142,3 +142,151 @@ def test_dispatch_fallback_counted_not_raised(monkeypatch):
     assert delta("head_dim", q, q) == 1.0
     q = jnp.zeros((1, 100, 2, 64), jnp.float32)     # ragged seq
     assert delta("seq_len", q, q) == 1.0
+
+
+# --------------------------------- the products' operands (PR 35)
+
+def _normalised_errors(q, k, v, w, bq, bkv):
+    """(out, dq, dk, dv) of the kernel on the arrays as they are, against
+    the float32 reference on the same values: max-abs error over the
+    reference's max-abs, as chip_smoke.check_kernel reports them."""
+    run = lambda q, k, v: bf.attention_bhsd(
+        q, k, v, causal=True, interpret=True, block_q=bq, block_kv=bkv)
+    out, vjp = jax.vjp(run, q, k, v)
+    got = (out,) + vjp(w)
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    ref, ref_vjp = jax.vjp(lambda q, k, v: _xla_ref(q, k, v, True), *f32)
+    want = (ref,) + ref_vjp(w.astype(jnp.float32))
+    assert [g.dtype for g in got] == [q.dtype] * 4
+    return [float(np.max(np.abs(np.asarray(g, np.float32) - np.asarray(r)))
+                  / np.max(np.abs(np.asarray(r))))
+            for g, r in zip(got, want)]
+
+
+@pytest.mark.parametrize("dtype, d, s, bq, bkv", [
+    (jnp.bfloat16, 64, 256, 128, 128),
+    (jnp.bfloat16, 128, 256, 128, 128),
+    (jnp.bfloat16, 64, 512, 128, 256),
+    (jnp.float16, 64, 256, 128, 128),
+], ids=["bf16-d64", "bf16-d128", "bf16-d64-bq128-bkv256", "f16-d64"])
+def test_parity_in_the_arrays_dtype(dtype, d, s, bq, bkv):
+    # bf16 / float16 arrays multiply natively: p and ds are rounded once,
+    # to the operands' dtype, at their product, and each result once at
+    # its store. The tolerance is four unit roundoffs of that dtype at the
+    # largest entry (2 * eps: 1.6e-2 bf16, 2.0e-3 float16). Read in
+    # interpret mode over two seeds when the products changed: 0.5-1.4
+    # roundoffs, and 0.5-1.4 with float32 operands too: the store's own
+    # rounding leads, not the operands'. A product or a tile left out
+    # reads 50 roundoffs and more.
+    q, k, v, w = (x.astype(dtype) for x in _qkvw(1, 2, s, s, d, seed=1))
+    errs = _normalised_errors(q, k, v, w, bq, bkv)
+    tol = 2 * float(jnp.finfo(dtype).eps)
+    assert all(e <= tol for e in errs), (errs, tol)
+
+
+def _kernel_products(jaxpr, found=None):
+    """(lhs dtype, rhs dtype, result dtype) of every dot_general inside the
+    pallas_call bodies of ``jaxpr``, through every nested jaxpr."""
+    found = [] if found is None else found
+
+    def subjaxprs(params):
+        for val in params.values():
+            for x in (val if isinstance(val, (tuple, list)) else (val,)):
+                if hasattr(x, "jaxpr") and hasattr(x, "consts"):
+                    yield x.jaxpr                       # ClosedJaxpr
+                elif hasattr(x, "eqns"):
+                    yield x
+
+    def walk(jp, inside):
+        for eqn in jp.eqns:
+            kernel = inside or eqn.primitive.name == "pallas_call"
+            if inside and eqn.primitive.name == "dot_general":
+                found.append(tuple(str(a.aval.dtype) for a in
+                                   (*eqn.invars, *eqn.outvars)))
+            for sub in subjaxprs(eqn.params):
+                walk(sub, kernel)
+    walk(jaxpr, False)
+    return found
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_every_product_takes_the_arrays_dtype(dtype):
+    # the forward's 2 products, dq's 3, dk/dv's 4, each in a masked and an
+    # unmasked body: operands as the arrays are, float32 accumulation
+    q = jnp.zeros((1, 1, 256, 64), dtype)
+    run = lambda q, k, v: bf.attention_bhsd(
+        q, k, v, causal=True, interpret=True, block_q=128, block_kv=128)
+    fwd = _kernel_products(jax.make_jaxpr(run)(q, q, q).jaxpr)
+    both = _kernel_products(jax.make_jaxpr(jax.grad(
+        lambda q, k, v: run(q, k, v).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2)))(q, q, q).jaxpr)
+    name = str(jnp.dtype(dtype))
+    assert len(fwd) == 2 * 2 and len(both) == 2 * (2 + 3 + 4)
+    assert set(fwd + both) == {(name, name, "float32")}
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_matmul_operands_counted_once_a_traced_call(monkeypatch, dtype):
+    """``attn.matmul_operands{kernel=blocked, dtype}`` ticks once a traced
+    call, with the arrays' dtype, beside ``attn.dispatch`` and its one
+    label (the train loop's check wants exactly one ``attn.dispatch{``
+    key)."""
+    import paddle_tpu.observability as obs
+    from paddle_tpu.ops.pallas import flash_attention as fa
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    q = jnp.zeros((1, 8192, 2, 64), dtype)          # [B, S, H, D]
+    with obs.window() as w:
+        jax.make_jaxpr(lambda q, k, v: fa.flash_attention_maybe(
+            q, k, v, causal=True))(q, q, q)
+    from chip_smoke import _moved_counters
+    assert _moved_counters(w.delta) == {
+        "attn.dispatch{kernel=blocked}": 1,
+        f"attn.matmul_operands{{dtype={jnp.dtype(dtype)},kernel=blocked}}": 1}
+
+
+# ------------------------------------------- compiled for the chip, not run
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    # a compile for a described chip cannot be read back from the cache
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    cc.reset_cache()
+
+
+@pytest.mark.parametrize("shape, dtype, causal", [
+    ((2, 32, 8192, 64), jnp.bfloat16, True),
+    ((2, 8, 4096, 128), jnp.bfloat16, False),
+    ((1, 2, 4096, 64), jnp.float32, True),
+], ids=["lfm2_cell", "d128_noncausal", "f32"])
+def test_kernels_compile_for_the_v5e(one_chip, no_compile_cache, shape,
+                                     dtype, causal):
+    # what interpret mode cannot show: Mosaic takes the three kernels as
+    # written (the lane repeat, the transposed tile, the prefetched tables)
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def fwd_bwd(q, k, v, w):
+        out, vjp = jax.vjp(lambda q, k, v: bf.attention_bhsd(
+            q, k, v, causal=causal), q, k, v)
+        return (out,) + vjp(w)
+    compiled = jax.jit(fwd_bwd).lower(x, x, x, x).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 3

@@ -134,7 +134,10 @@ def test_kernel_phase_interpret():
              ("causal_skip", (1, 1, 256, 64), True, None),
              ("qblock", (1, 1, 256, 64), False, None),
              ("blocked", (1, 1, 256, 64), True, (128, 128)),
-             ("blocked", (1, 1, 256, 64), False, (128, 128)))
+             ("blocked", (1, 1, 256, 64), False, (128, 128)),
+             # the tiny twin of the LFM2 cell's call: head width 64, a
+             # batch of heads, bf16 (check_kernel's default dtype)
+             ("blocked", (2, 4, 256, 64), True, (128, 128)))
     assert len(chip_smoke.kernel_phase(cases, interpret=True)) == len(cases)
     with pytest.raises(AssertionError, match="off its float32 reference"):
         chip_smoke.check_kernel(*cases[0], interpret=True, tol=0.0)
@@ -143,9 +146,10 @@ def test_kernel_phase_interpret():
 def test_blocked_flash_passes_dimension_semantics():
     from paddle_tpu.ops.pallas import blocked_flash
     params = blocked_flash._compiler_params()
+    # (b, h, tile): the walk over a slice's tiles carries the scratch
     assert tuple(str(s).lower().rsplit(".", 1)[-1]
                  for s in params.dimension_semantics) == (
-        "parallel", "parallel", "parallel", "arbitrary")
+        "parallel", "parallel", "arbitrary")
 
 
 def test_unknown_device_kind_raises():
