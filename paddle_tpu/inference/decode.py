@@ -774,29 +774,137 @@ class DecodeSession(_SessionLifecycle):
 
 
 
+#: the parts of a session's wall time, from its first step()'s entry to its
+#: last one's return (``_CycleAccount``)
+_PARTS = ("caller", "no_work", "expire", "admit", "dispatch", "fetch_wait",
+          "fetch_copy", "deliver", "other")
+#: the parts whose seconds a cycle are also a histogram: those a reader of
+#: the benchmark takes a median of. The counters carry every part's sum.
+_CYCLE_HIST_PARTS = ("caller", "fetch_copy")
+
+
+class _CycleAccount:
+    """The session's wall clock, partitioned: every second from the first
+    ``step()``'s entry to the last one's return is in exactly one of
+    ``_PARTS``. ``mark(part, now)`` closes the running part at a clock read
+    some span already made and opens the next, so the parts sum to the
+    wall.
+
+    ``caller`` is a ``step()``'s return to the next one's entry where the
+    session held work (something running or queued), ``no_work`` the same
+    gap where it held none (a gap is split at the first ``submit()`` after
+    an empty return); ``other`` is what is left of a step beside its
+    phases (the gauges, the health report, the recovery path).
+
+    ``starving`` is set from the instant the fetch's wait returned (every
+    program the session had enqueued is done) to the return of the next
+    successful device call (``fed``): by the session's own books nothing
+    of its can be running on the chip then, and the seconds marked
+    meanwhile are also ``starved``, part by part. That is a LOWER bound
+    on the idle the host causes: the launch latency after the call
+    returns is not seen, nor an admit program that ends before the
+    dispatch behind it is enqueued. Nothing starves while the session is
+    ``idle`` (from a return with no work to the next ``submit()``: nobody
+    waits, so ``no_work`` never starves), and ``fetch_wait`` cannot (the
+    chip runs the block).
+
+    A cycle runs from a ``step()``'s return to the next one's return: the
+    caller's gap, then the step. ``seconds`` and ``starved`` hold the
+    running cycle's and are emptied by ``end_cycle``. While metrics are off
+    nothing calls ``mark`` and no clock is read."""
+
+    __slots__ = ("part", "t", "starving", "idle", "decoded", "seconds",
+                 "starved")
+
+    def __init__(self):
+        self.part = self.t = None
+        self.starving = True        # nothing is enqueued yet
+        self.idle = False
+        self.decoded = False        # this cycle dispatched a decode block
+        self.seconds = dict.fromkeys(_PARTS, 0.0)
+        self.starved = dict.fromkeys(_PARTS, 0.0)
+
+    def mark(self, part, now):
+        if self.t is not None:
+            self.seconds[self.part] += now - self.t
+            if self.starving and not self.idle:
+                self.starved[self.part] += now - self.t
+        self.part, self.t = part, now
+
+    def fed(self, now=None, decode=False):
+        """A device call returned: the chip has work again. ``now`` splits
+        the running part there (the dispatch's phase ends at that read
+        already and passes none)."""
+        if now is not None:
+            self.mark(self.part, now)
+        self.starving = False
+        self.decoded |= decode
+
+    def drained(self, now):
+        """The fetch's wait returned: every program the session enqueued
+        is done, and the copy of its tokens starts."""
+        self.mark("fetch_copy", now)
+        self.starving = True
+
+    def submitted(self, now):
+        """A request was queued: if the session held none, the rest of
+        the gap is the caller's."""
+        if self.part == "no_work":
+            self.mark("caller", now)
+            self.idle = False
+
+    def end_cycle(self, gap):
+        """At ``step()``'s return: names the gap that opens (no clock is
+        read: the step's span closed at ``t``) and hands out the cycle's
+        ``(seconds, starved, decoded)``."""
+        out = self.seconds, self.starved, self.decoded
+        self.part, self.idle = gap, gap == "no_work"
+        self.decoded = False
+        self.seconds = dict.fromkeys(_PARTS, 0.0)
+        self.starved = dict.fromkeys(_PARTS, 0.0)
+        return out
+
+    def forget(self):
+        """A step ran with metrics off: the open part has no end that was
+        read, so the account starts again at the next timed entry, as a new
+        session's does (what that step enqueued or fetched is not known)."""
+        self.part = self.t = None
+        self.idle = False
+        self.starving = True
+
+
 class _Phase:
     """One timed phase of the serving step: the span (a RecordEvent, so it
-    shows in a running jax.profiler trace) and its seconds into a
-    histogram, off the same two clock reads. While metrics are off it does
+    shows in a running jax.profiler trace), its seconds into a histogram
+    (if it has one) and its part of the session's ``_CycleAccount`` (if it
+    has one: ``part`` runs from the phase's entry, ``other`` from its
+    exit), all off the same two clock reads. While metrics are off it does
     nothing and reads no clock; ``t0`` is then None and ``seconds`` 0."""
 
-    __slots__ = ("_span", "_hist", "t0", "seconds")
+    __slots__ = ("_span", "_hist", "_account", "_part", "t0", "seconds")
 
-    def __init__(self, span, hist):
+    def __init__(self, span, hist=None, account=None, part=None):
         self._span, self._hist = span, hist
+        self._account, self._part = account, part
         self.t0, self.seconds = None, 0.0
 
     def __enter__(self):
         if _met._ENABLED:
             self._span.begin()
             self.t0 = time.perf_counter()
+            if self._account is not None:
+                self._account.mark(self._part, self.t0)
         return self
 
     def __exit__(self, *exc):
         if self.t0 is not None:
-            self.seconds = time.perf_counter() - self.t0
+            now = time.perf_counter()
+            self.seconds = now - self.t0
             self._span.end()
-            self._hist.observe(self.seconds)
+            if self._hist is not None:
+                self._hist.observe(self.seconds)
+            if self._account is not None:
+                self._account.mark("other", now)
         return False
 
 
@@ -976,6 +1084,18 @@ class ContinuousBatchingSession(_SessionLifecycle):
         self._h_step = reg.histogram("serving.step_s")
         self._h_step_host = reg.histogram("serving.step_host_s")
         self._fetch_s = 0.0     # seconds this step() waited in its fetch
+        # the wall-clock account: the operator's sums a part, and a
+        # cycle's seconds for a median over the cycles that decoded
+        self._account = _CycleAccount()
+        self._c_cycle = {part: reg.counter("serving.cycle_s", part=part)
+                         for part in _PARTS}
+        self._c_starved = {part: reg.counter("serving.starved_s", part=part)
+                           for part in _PARTS
+                           if part not in ("no_work", "fetch_wait")}
+        self._h_cycle_part = {
+            part: reg.histogram("serving.cycle_part_s", part=part)
+            for part in _CYCLE_HIST_PARTS}
+        self._h_cycle_starved = reg.histogram("serving.cycle_starved_s")
         self._block_length = None
         if generation == "block_diffusion":
             if decode_block:
@@ -1232,10 +1352,11 @@ class ContinuousBatchingSession(_SessionLifecycle):
 
         with _Phase(RecordEvent("serving.dispatch", slots=len(slots),
                                 passes=passes),
-                    self._h_phase["dispatch"]):
+                    self._h_phase["dispatch"], self._account, "dispatch"):
             out, self._key, self._cache_arrays = self._device_call(
                 "serving.decode_step", {"slots": slots}, call, retries)
         if _met._ENABLED:
+            self._account.fed(decode=True)
             r = _met.REGISTRY
             r.counter("serving.block_dispatches").inc()
             # every lane computes every pass, whoever sits in it
@@ -1343,6 +1464,7 @@ class ContinuousBatchingSession(_SessionLifecycle):
             self._finish(victim, RequestState.REJECTED)
         self._queue.append(req)
         if _met._ENABLED:
+            self._account.submitted(req.t_submit)
             r = _met.REGISTRY
             r.counter("serving.requests_submitted").inc()
             r.gauge("serving.queue_depth").set(len(self._queue))
@@ -1473,11 +1595,12 @@ class ContinuousBatchingSession(_SessionLifecycle):
                 *self._cache_arrays)
 
         with _Phase(RecordEvent("serving.dispatch", slots=len(slots)),
-                    self._h_phase["dispatch"]):
+                    self._h_phase["dispatch"], self._account, "dispatch"):
             out = self._device_call("serving.decode_step",
                                     {"slots": slots}, call, retries)
         stepped = [self._running[slot] for slot in slots]
         if _met._ENABLED:
+            self._account.fed(decode=True)
             r = _met.REGISTRY
             # every lane computes every step, whoever sits in it
             r.counter("serving.decode_lane_steps").inc(self._slots * steps)
@@ -1557,7 +1680,7 @@ class ContinuousBatchingSession(_SessionLifecycle):
             with _Phase(RecordEvent("serving.admit", rid=req.rid,
                                     slot=slot, plen=req.plen,
                                     bucket=bucket),
-                        self._h_phase["admit"]):
+                        self._h_phase["admit"], self._account, "admit"):
                 self._admit_one(state, req, slot, bucket)
 
     def _prefill_len(self, req):
@@ -1605,6 +1728,7 @@ class ContinuousBatchingSession(_SessionLifecycle):
             r.counter("serving.prefill_tokens").inc(plen)
             r.counter("serving.prefill_padded_tokens").inc(bucket)
             req.t_admit = time.perf_counter()
+            self._account.fed(req.t_admit)
             r.histogram("serving.queue_wait_s").observe(
                 req.t_admit - req.t_submit)
         # the admit's sampled token is the request's first output;
@@ -1630,12 +1754,28 @@ class ContinuousBatchingSession(_SessionLifecycle):
         entries = self._pending
         self._pending = []
         _chaos.hit("serving.drain", n=len(entries))
+        arrays = [t for (_k, _s, t) in entries]
         with _Phase(RecordEvent("serving.fetch", entries=len(entries)),
-                    self._h_phase["fetch"]) as fetch:
-            fetched = jax.device_get([t for (_k, _s, t) in entries])
+                    self._h_phase["fetch"], self._account,
+                    "fetch_wait") as fetch:
+            if fetch.t0 is not None:
+                # the fetch's two halves: the chip runs the block
+                # (fetch_wait), then has nothing of this session's to run
+                # while the tokens cross (fetch_copy). The copies are
+                # started first, so that the split delays none.
+                for a in arrays:
+                    a.copy_to_host_async()
+                with RecordEvent("serving.fetch_wait"):
+                    jax.block_until_ready(arrays)
+                self._account.drained(time.perf_counter())
+                with RecordEvent("serving.fetch_copy"):
+                    fetched = jax.device_get(arrays)
+            else:
+                fetched = jax.device_get(arrays)
         self._fetch_s += fetch.seconds
         with _Phase(RecordEvent("serving.deliver", entries=len(entries)),
-                    self._h_phase["deliver"]) as deliver:
+                    self._h_phase["deliver"], self._account,
+                    "deliver") as deliver:
             self._deliver(entries, fetched, deliver.t0)
 
     def _deliver(self, entries, fetched, now):
@@ -1694,19 +1834,54 @@ class ContinuousBatchingSession(_SessionLifecycle):
         Returns the list of request ids that reached a terminal state
         during this step."""
         self._fetch_s = 0.0
-        with _Phase(RecordEvent("serving.step",
-                                running=len(self._running),
-                                queued=len(self._queue)),
-                    self._h_step) as whole:
-            done = self._step()
-        if whole.t0 is not None:
-            # the host's own time: an upper bound on the idle it causes
-            self._h_step_host.observe(whole.seconds - self._fetch_s)
-        return done
+        whole = _Phase(RecordEvent("serving.step",
+                                   running=len(self._running),
+                                   queued=len(self._queue)),
+                       self._h_step, self._account, "other")
+        try:
+            with whole:
+                done = self._step()
+            if whole.t0 is not None:
+                # the host's own time inside the step; the idle it causes
+                # also holds the tokens' copy and the caller's gap
+                # (serving.cycle_starved_s)
+                self._h_step_host.observe(whole.seconds - self._fetch_s)
+            return done
+        finally:
+            # a step that failed closed its parts too
+            self._end_cycle(whole.t0 is not None)
+
+    def _end_cycle(self, timed):
+        """Flush the cycle that this ``step()``'s return ends: its seconds
+        and starved seconds a part into the counters and, if it dispatched
+        a decode block, the parts a reader takes a median of
+        (``_CYCLE_HIST_PARTS``) and the starved seconds into the histograms
+        (zeros included, so that a median is one over cycles). The starved
+        histogram sums the parts ``serving.starved_s`` has a series for, so
+        the two agree whatever was toggled meanwhile."""
+        if not timed:
+            self._account.forget()
+            return
+        seconds, starved, decoded = self._account.end_cycle(
+            "caller" if self._queue or self._running else "no_work")
+        for part, s in seconds.items():
+            if s:
+                self._c_cycle[part].inc(s)
+        cycle_starved = 0.0
+        for part, counter in self._c_starved.items():
+            if starved[part]:
+                counter.inc(starved[part])
+                cycle_starved += starved[part]
+        if decoded:
+            for part, hist in self._h_cycle_part.items():
+                hist.observe(seconds[part])
+            self._h_cycle_starved.observe(cycle_starved)
 
     def _step(self):
         before = set(self._done)
-        self._expire_deadlines()
+        with _Phase(RecordEvent("serving.expire"), None, self._account,
+                    "expire"):
+            self._expire_deadlines()
         self._admit_ready()
         if _met._ENABLED:
             r = _met.REGISTRY

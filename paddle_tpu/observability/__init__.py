@@ -38,6 +38,7 @@ from .metrics import (  # noqa: F401
 )
 from .compile_tracker import (  # noqa: F401
     count_compiles, count_traces, install as _install_compile_hook,
+    programs as compiled_programs, reset as _reset_compile_spans,
 )
 from .server import MetricsServer  # noqa: F401
 from .snapshots import (  # noqa: F401
@@ -72,6 +73,7 @@ def to_jsonl() -> str:
 
 def reset() -> None:
     REGISTRY.reset()
+    _reset_compile_spans()
 
 
 def dump(path=None, format: str = "json"):

@@ -62,6 +62,10 @@ CATALOG = {
     "jit.backend_compile_s": _m(
         "counter", "seconds in the backend's compile, or in the "
         "retrieval on a persistent-cache hit"),
+    "jit.compile_wall_s": _m(
+        "counter", "wall seconds jax spent tracing, lowering and "
+        "compiling: the length of the union of the three events' time "
+        "spans, so nested and repeated traces count once"),
     "jit.cache_hits": _m(
         "counter", "executables found in the persistent compile cache"),
     "jit.cache_misses": _m(
@@ -136,6 +140,27 @@ CATALOG = {
     "serving.step_phase_s": _m(
         "histogram", "wall time of one phase of step(): admit (one "
         "request), dispatch, fetch, deliver", ("phase",)),
+    "serving.cycle_s": _m(
+        "counter", "a session's wall seconds from its first step()'s "
+        "entry to its last one's return, each in exactly one part: "
+        "caller (a return to the next entry, work held), no_work (the "
+        "same gap, none held), expire, admit, dispatch, fetch_wait (the "
+        "chip runs the block), fetch_copy (its tokens cross), deliver, "
+        "other (the rest of step()); the parts sum to the wall",
+        ("part",)),
+    "serving.starved_s": _m(
+        "counter", "the seconds of serving.cycle_s between a fetch's "
+        "wait returning and the next device call's return, while the "
+        "session held work: by its own books the chip had nothing of its "
+        "to run (a lower bound on the idle the host causes)", ("part",)),
+    "serving.cycle_part_s": _m(
+        "histogram", "seconds of one part (caller, fetch_copy: the two "
+        "a median is read of; serving.cycle_s carries every part's sum) "
+        "in one cycle (a step()'s return to the next one's return) that "
+        "dispatched a decode block, zeros included", ("part",)),
+    "serving.cycle_starved_s": _m(
+        "histogram", "starved seconds, all parts, of one cycle that "
+        "dispatched a decode block"),
     "serving.queue_wait_s": _m(
         "histogram", "submit to admit program dispatched, per request"),
     "serving.first_token_hold_s": _m(

@@ -95,8 +95,10 @@ def test_cell_traced_tiny_reports_what_the_counters_give():
     got = {k: v["value"] for k, v in out["metrics"].items()}
     # no device plane on the CPU: no roofline, no time share, no idle share
     assert set(got) == {"train_dispatch_ms", "train_mfu", "setup_compile_s",
+                        "setup_compile_wall_s",
                         "train_moe_busiest_expert_load",
                         "train_moe_held_share"}
+    assert 0 < got["setup_compile_wall_s"] <= got["setup_compile_s"]
     assert got["train_moe_busiest_expert_load"] >= 1.0
     assert 0 < got["train_moe_held_share"] < 100
 

@@ -346,6 +346,148 @@ def test_compile_seconds_kept_by_phase():
     assert read() == first
 
 
+def test_compile_wall_counts_nested_traces_once():
+    """``jit.compile_wall_s`` is the union of the compile events' time
+    spans: under the three sums, and strictly under them where a jitted
+    function is traced inside another's trace; the table names both."""
+    import jax
+    import jax.numpy as jnp
+    names = ("jit.trace_s", "jit.lower_s", "jit.backend_compile_s")
+
+    def read():
+        return (obs.counter("jit.compile_wall_s").value,
+                sum(obs.counter(n).value for n in names))
+
+    @jax.jit
+    def wall_probe_inner(x):
+        return jnp.tanh(x) * 2
+
+    @jax.jit
+    def wall_probe_outer(x):
+        return wall_probe_inner(x) + 1
+
+    wall0, sums0 = read()
+    t0 = time.time()
+    wall_probe_outer(jnp.ones((3,)))
+    t1 = time.time()
+    wall, sums = read()
+    assert 0 < wall - wall0 < sums - sums0
+    assert wall - wall0 <= t1 - t0
+    # the inner trace's seconds are counted twice by the sums, once here
+    table = obs.compiled_programs()
+    inner, outer = table["wall_probe_inner"], table["wall_probe_outer"]
+    assert sums - sums0 - (wall - wall0) >= inner["trace_s"] * 0.99
+    assert inner["builds"] == 0 and inner["trace_s"] > 0 \
+        and inner["lower_s"] == inner["backend_compile_s"] == 0
+    assert outer["builds"] == 1 and outer["cache_hits"] in (0, 1)
+    assert min(outer[k] for k in ("trace_s", "lower_s",
+                                  "backend_compile_s")) > 0
+    assert t0 <= outer["first"] <= inner["first"] <= inner["last"] \
+        <= outer["last"] <= t1
+    # a second call moves nothing; the table is a copy
+    wall_probe_outer(jnp.ones((3,)))
+    assert read() == (wall, sums)
+    table["wall_probe_outer"]["builds"] = 99
+    assert obs.compiled_programs()["wall_probe_outer"]["builds"] == 1
+
+
+def test_span_union_counts_every_second_once():
+    from paddle_tpu.observability import compile_tracker as ct
+    u = ct._SpanUnion()
+    assert u.add(10.0, 11.0) == 1.0             # alone
+    assert u.add(10.2, 10.8) == 0.0             # nested in it
+    assert u.add(10.0, 11.0) == 0.0             # repeated
+    assert u.add(12.0, 13.0) == 1.0             # apart
+    assert u.add(10.5, 12.5) == pytest.approx(1.0)      # bridges the gap
+    assert u._ivs == [[10.0, 13.0, pytest.approx(3.0)]]
+    # spans arrive at their end: the inner ones, then the one around them
+    assert [u.add(20.0 + i, 20.5 + i) for i in range(4)] == [0.5] * 4
+    assert u.add(19.0, 25.0) == pytest.approx(6.0 - 2.0)
+    assert len(u._ivs) == 2
+
+
+def test_span_union_stays_short_and_never_overcounts(monkeypatch):
+    """Past the cap the oldest intervals are joined: a span that covers
+    the joined one whole still adds exactly what was missing, one that
+    cuts into it is credited with the whole overlap."""
+    from paddle_tpu.observability import compile_tracker as ct
+    monkeypatch.setattr(ct, "_MAX_INTERVALS", 4)
+    u = ct._SpanUnion()
+    total = sum(u.add(float(i), i + 0.25) for i in range(10))
+    assert total == 2.5 and len(u._ivs) == 4
+    assert u._ivs[0] == [0.0, 6.25, 1.75]       # seven joined, gaps kept
+    assert u.add(5.5, 5.75) == 0.0              # cuts in: never over
+    assert u.add(-1.0, 10.0) == pytest.approx(11.0 - 2.5)
+    assert u._ivs == [[-1.0, 10.0, pytest.approx(11.0)]]
+
+
+def test_compiled_programs_table_is_bounded(monkeypatch):
+    """Names past the bound are summed under ``_other``; ``jit(f)`` and
+    ``f`` are one key; a cache hit is the compile's whose span ends next."""
+    from paddle_tpu.observability import compile_tracker as ct
+    monkeypatch.setattr(ct, "_MAX_PROGRAMS", 2)
+    spans = ct._CompileSpans()
+    assert spans.add("trace_s", 1.0, 2.0, "f") == 1.0
+    assert spans.add("lower_s", 2.0, 2.5, "jit(f)") == 0.5
+    spans.cache_hit()
+    assert spans.add("backend_compile_s", 2.5, 2.75, "jit(f)") == 0.25
+    spans.add("backend_compile_s", 3.0, 4.0, "jit(g)")
+    spans.add("trace_s", 5.0, 5.5, "h")
+    spans.add("backend_compile_s", 6.0, 6.5, "jit(k)")
+    table = spans.table()
+    assert sorted(table) == ["_other", "f", "g"]
+    assert table["f"] == {"builds": 1, "trace_s": 1.0, "lower_s": 0.5,
+                          "backend_compile_s": 0.25, "cache_hits": 1,
+                          "first": 1.0, "last": 2.75}
+    assert table["g"]["builds"] == 1 and table["g"]["cache_hits"] == 0
+    assert table["_other"]["builds"] == 1 \
+        and table["_other"]["trace_s"] == 0.5
+    # a hit is claimed on the thread it fired on: another thread's compile,
+    # whose span may end first, does not take it
+    import threading
+    spans.cache_hit()
+    other = threading.Thread(target=spans.add, args=(
+        "backend_compile_s", 7.0, 7.5, "jit(g)"))
+    other.start()
+    other.join()
+    assert spans.table()["g"]["cache_hits"] == 0
+    spans.add("backend_compile_s", 7.0, 8.0, "jit(f)")
+    assert spans.table()["f"]["cache_hits"] == 2
+    spans.clear()
+    assert spans.table() == {} and spans.add("trace_s", 1.0, 2.0, "f") == 1.0
+
+
+def test_reset_forgets_the_compile_spans_with_their_counter():
+    """``obs.reset()`` zeroes ``jit.compile_wall_s``; the union and the
+    table behind ``compiled_programs()`` go with it, so the counter and the
+    table's seconds still say the same after it."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.observability import compile_tracker as ct
+
+    @jax.jit
+    def reset_probe(x):
+        return jnp.cos(x) - 1
+
+    reset_probe(jnp.ones((5,)))
+    assert "reset_probe" in obs.compiled_programs()
+    assert obs.counter("jit.compile_wall_s").value > 0
+    obs.reset()
+    assert obs.compiled_programs() == {} and ct._SPANS._union._ivs == []
+    assert obs.counter("jit.compile_wall_s").value == 0
+
+    @jax.jit
+    def reset_probe_again(x):
+        return jnp.cos(x) - 2
+
+    reset_probe_again(jnp.ones((5,)))
+    table = obs.compiled_programs()
+    assert "reset_probe" not in table and "reset_probe_again" in table
+    assert 0 < obs.counter("jit.compile_wall_s").value <= sum(
+        e[k] for e in table.values()
+        for k in ("trace_s", "lower_s", "backend_compile_s"))
+
+
 def test_global_compile_counter_and_static_function_stats():
     before = obs.counter("jit.xla_compiles").value
 
@@ -581,6 +723,52 @@ def test_disabled_session_step_reads_no_clock_for_metrics(monkeypatch):
         assert clock.reads == 1
     finally:
         obs.enable()
+    sess.close()
+
+
+def test_the_fetch_is_split_only_while_metrics_are_on(monkeypatch):
+    """The account's on-cost, counted: a timed step that admits nothing
+    reads the session's clock at most four times more than before the
+    account (9: the step, the deadline scan, dispatch, fetch, deliver) and
+    waits for the block once (the split of the fetch); with metrics off
+    there is no wait, no split, and the deadline scan's one read."""
+    import jax
+    from paddle_tpu.inference import decode
+    paddle.seed(11)
+    sess = decode.ContinuousBatchingSession(_TinyLM(), max_slots=2,
+                                            max_length=16, decode_block=2)
+    sess.submit(np.arange(3), 8)
+    sess.step()                                 # compiled, admitted
+    clock = _CountingClock()
+    waits = []
+    wait = jax.block_until_ready
+
+    def counted_wait(x):
+        waits.append(1)
+        return wait(x)
+
+    monkeypatch.setattr(decode, "time", clock)
+    monkeypatch.setattr(jax, "block_until_ready", counted_wait)
+    starved = obs.histogram("serving.cycle_starved_s")
+    seen = starved.count
+    sess.step()
+    assert 9 < clock.reads <= 9 + 4, clock.reads
+    assert len(waits) == 1 and starved.count == seen + 1
+    obs.disable()
+    try:
+        clock.reads = 0
+        sess.step()
+        assert clock.reads == 1 and len(waits) == 1
+        assert sess._account.t is None, "the open part is forgotten"
+        assert sess._account.starving, "as a new session's account"
+    finally:
+        obs.enable()
+    assert starved.count == seen + 1
+    with obs.window() as moved:
+        sess.step()             # timed again: the account starts anew,
+    assert moved.value("serving.cycle_starved_s") == 1
+    assert moved.hist("serving.cycle_part_s",       # with no gap to count
+                      part="caller")["sum"] == 0
     sess.close()
 
 
