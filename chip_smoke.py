@@ -10,7 +10,8 @@ seeded weights) through the entry points a user calls:
   compiled for the chip at the shape of the regime it is routed for and
   compared with a float32 ``jax.numpy`` attention; the decode attention
   kernel (forward only) against the float32 einsums of the XLA path; the
-  cache write's program against ``dynamic_update_slice``, bit for bit;
+  cache write's program against ``dynamic_update_slice``, bit for bit; the
+  expert layer on the rows of the experts it holds against all its rows;
 * train   — ``gpt_hybrid.setup`` + a few steps at B4xS1024 on a fixed batch;
 * serve   — ``ContinuousBatchingSession`` answering sixteen requests, with
   request 0 checked against ``DecodeSession.generate``, every one-token
@@ -284,6 +285,70 @@ def check_cache_write(shape=CACHE_WRITE_SHAPE, s=1, interpret=False):
         raise AssertionError(f"kernel cache_write {list(shape)}: {differ} "
                              "elements differ from dynamic_update_slice")
     return differ
+
+
+#: the LFM2 train cell's expert layer: tokens a step, hidden, expert width,
+#: experts routed over, experts held, experts a token
+EXPERT_ROWS_SHAPE = (16384, 2048, 1536, 64, 16, 4)
+
+
+def check_expert_rows(shape=EXPERT_ROWS_SHAPE, dtype="bfloat16", tol=1e-2):
+    """``sdar_moe.expert_ffn`` holding a share of the experts (on a TPU
+    through the grouped kernels) under a uniform routing, which the held
+    experts' row buffer must hold in one trip: the layer against one pass
+    over all ``T x k`` rows, in value and in ``h``'s gradient. Returns the
+    two relative L2 errors."""
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu.observability as obs
+    from paddle_tpu.models import sdar_moe
+
+    t, hid, inter, experts, held, top_k = shape
+    keys = jax.random.split(jax.random.PRNGKey(2), 5)
+    h = jax.random.normal(keys[0], (t, hid), dtype)
+    gate_up, down = (
+        (0.02 * jax.random.normal(k, s)).astype(dtype) for k, s in (
+            (keys[1], (held, hid, 2 * inter)), (keys[2], (held, inter, hid))))
+    weights, index = jax.lax.top_k(
+        jax.random.uniform(keys[3], (t, experts)), top_k)
+    probe = jax.random.normal(keys[4], (t, hid), jnp.float32)
+    rows = sdar_moe._held_rows(t * top_k, held, experts)
+    held_pairs = int(jnp.sum(index < held))
+
+    def through(layer):
+        def loss(h, *operands):
+            y = layer(h, *operands)
+            return jnp.sum(y.astype(jnp.float32) * probe), y
+        (_, y), d_h = jax.jit(jax.value_and_grad(loss, has_aux=True))(
+            h, weights, index, gate_up, down)
+        return y, d_h
+
+    with obs.window() as w:
+        got = through(lambda h, weights, index, *ws: sdar_moe.expert_ffn(
+            h, weights, index, *ws, 0, experts))
+    moved = _moved_counters(w.delta, "moe.")
+    want = through(lambda h, weights, index, *ws: sdar_moe._ffn_rows(
+        t * top_k, 0, *sdar_moe._sort_pairs(index, 0, experts), h, weights,
+        *ws).astype(h.dtype))
+
+    def rel(a, b):
+        a, b = (x.astype(jnp.float32) for x in (a, b))
+        return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+    errs = tuple(map(rel, got, want))
+    print(f"[smoke] expert rows [{t}, {hid}] {jnp.dtype(dtype).name}, {held} "
+          f"of {experts} experts held, top-{top_k}: {held_pairs} held pairs "
+          f"in a buffer of {rows} rows against all {t * top_k}: "
+          f"out={errs[0]:.2e} dh={errs[1]:.2e}; counters {moved}", flush=True)
+    if held_pairs > rows or "moe.row_buffer{rows=held}" not in moved:
+        raise AssertionError(
+            f"expert rows: one trip over the held experts' buffer of {rows} "
+            f"rows is not what ran: {held_pairs} held pairs, {moved}")
+    if not max(errs) <= tol:
+        raise AssertionError(f"expert rows [{t}, {hid}]: the buffer of "
+                             f"{rows} rows off all {t * top_k} beyond "
+                             f"{tol}: {errs}")
+    return errs
 
 
 def kernel_phase(cases=KERNEL_CASES, interpret=False, tol=2e-2, dtype=None):
@@ -656,6 +721,7 @@ def main(argv=None):
         kernel_phase()
         check_decode_kernel()
         check_cache_write()
+        check_expert_rows()
         train_phase(cfg, batch=4, seq=1024, steps=4,
                     scan_unroll=SCAN_UNROLL, expect_kernel="simple")
         serve_phase(GPTConfig.gpt3_1p3b(), max_slots=8, max_length=512,

@@ -26,6 +26,7 @@ Serving contract (``ContinuousBatchingSession(generation=
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -272,6 +273,124 @@ def _grouped(lhs, rhs, group_sizes, out_dtype, interpret=False):
     return out.astype(out_dtype)
 
 
+#: the rows a holder of a share of the experts works on at a time, over the
+#: held experts' even share of the (token, expert) pairs (rounded up to the
+#: kernels' row tile of 256). The sort puts the held experts' pairs first, and
+#: the layer walks them this many rows a trip, so every [T*k, .] array of the
+#: layer is [rows, .]; a step whose routing gives the held experts more pairs
+#: takes another trip, so no pair is dropped and no capacity enters the
+#: mathematics: the slack sets how often a step takes two trips, never a
+#: result. 1.5: the train cell's loop holds a run's held share to 23-28 % of
+#: the pairs where even is 25 %, and 37.5 % fits; one expert's 1.22 x of its
+#: even load is one expert's, not sixteen together (PERF.md, PR 37)
+_HELD_ROWS_SLACK = 1.5
+
+
+def _held_rows(pairs, held, num_experts):
+    even = pairs * held / num_experts
+    return min(pairs, -(-int(even * _HELD_ROWS_SLACK) // 256) * 256)
+
+
+def _count_row_buffer():
+    """Ticked where an ``expert_ffn`` that holds a share of the experts and
+    walks its rows a buffer at a time is traced."""
+    if _met._ENABLED:
+        _met.REGISTRY.counter("moe.row_buffer", rows="held").inc()
+
+
+def _sort_pairs(index, expert_offset, num_experts):
+    """Routing [T, k] -> (the pairs in the order of their experts, the held
+    experts first so that their rows lead it; pairs an expert, in that
+    order)."""
+    label = (index.reshape(-1) - expert_offset) % num_experts
+    return (jnp.argsort(label, stable=True),
+            jnp.zeros((num_experts,), jnp.int32).at[label].add(1))
+
+
+def _ffn_rows(m, first, order, group_sizes, h, weights, w_gate_up, w_down):
+    """The layer's part from the ``m`` rows of the sorted order that start
+    at ``first`` (0 where ``m`` is all T*k) -> [T, H]: in ``h``'s dtype
+    where every expert is held, else the float32 sum the caller rounds."""
+    t, k = weights.shape
+    held, inter = w_down.shape[:2]
+    every_pair_has_a_row = held == group_sizes.shape[0]
+    if m == t * k:
+        pair = order
+    else:
+        pair = lax.dynamic_slice(jnp.pad(order, (0, -(t * k) % m)),
+                                 (first,), (m,))
+        # sizes that are true of these rows: what of each held group lies
+        # among them, and the rest as one more group without weights, as an
+        # absent expert's rows are
+        end = jnp.cumsum(group_sizes[:held])
+        inside = jnp.clip(jnp.minimum(end, first + m) - jnp.maximum(
+            end - group_sizes[:held], first), 0)
+        group_sizes = jnp.append(inside, m - jnp.sum(inside))
+    token = pair // k
+    rows = h[token]                                         # [m, H]
+    gate_up = _grouped(rows, w_gate_up, group_sizes, h.dtype)
+    act = jax.nn.silu(gate_up[:, :inter]) * gate_up[:, inter:]
+    out = _grouped(act, w_down, group_sizes, jnp.float32)
+    out = out * weights.reshape(-1)[pair][:, None]
+    if every_pair_has_a_row:
+        back = jnp.argsort(order)                           # pair -> row
+        return jnp.sum(out[back].reshape(t, k, -1), axis=1).astype(h.dtype)
+    # rows of no held expert are zeros and add nothing
+    return jnp.zeros((t, out.shape[1]), jnp.float32).at[token].add(out)
+
+
+def _over_held_rows(rows, group_sizes, held, trip, start):
+    """``trip(first, carry)`` for ``first`` = 0, ``rows``, ... while held
+    pairs lie at or past it: one trip in a step whose held experts drew no
+    more than ``rows`` pairs."""
+    n_held = jnp.sum(group_sizes[:held])
+    return lax.while_loop(
+        lambda c: c[0] < n_held,
+        lambda c: (c[0] + rows, trip(*c)), (jnp.int32(0), start))[1]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _ffn_held_rows(rows, order, group_sizes, *operands):
+    """``_ffn_rows`` over the held experts' pairs, ``rows`` of them a trip
+    -> [T, H] float32. A loop and not a ``cond`` between this buffer and all
+    T*k rows: both forms of a ``cond`` are traced and compiled (the train
+    cell's set-up 71 -> 93 s warm), and differentiated through it keeps
+    both forms' residuals, the idle form's as zeros (4.97 GB of temporaries
+    a layer where its own rule took 2.09; PERF.md, PR 37). So the rule is
+    its own: the residuals are the arguments, and the backward pass walks
+    the same trips, each running its forward pass again as the layer's
+    ``jax.checkpoint`` does."""
+    h, w_down = operands[0], operands[-1]
+    return _over_held_rows(
+        rows, group_sizes, w_down.shape[0],
+        lambda first, y: y + _ffn_rows(rows, first, order, group_sizes,
+                                       *operands),
+        jnp.zeros((h.shape[0], w_down.shape[2]), jnp.float32))
+
+
+def _ffn_held_rows_fwd(rows, *args):
+    return _ffn_held_rows(rows, *args), args
+
+
+def _ffn_held_rows_bwd(rows, args, g):
+    order, group_sizes, *operands = args
+
+    def trip(first, grads):
+        # checkpointed, so that the forward pass is run again inside the
+        # backward one under its own names: traced by ``jax.vjp`` itself its
+        # kernels are ``jvp(jit(gmm))`` to XLA, and a reader of the device
+        # trace that knows ``gmm`` and ``tgmm`` would miss them
+        part = jax.vjp(jax.checkpoint(functools.partial(
+            _ffn_rows, rows, first, order, group_sizes)), *operands)[1](g)
+        return jax.tree_util.tree_map(jnp.add, grads, part)
+    return (None, None, *_over_held_rows(
+        rows, group_sizes, operands[-1].shape[0], trip,
+        tuple(jnp.zeros_like(x) for x in operands)))
+
+
+_ffn_held_rows.defvjp(_ffn_held_rows_fwd, _ffn_held_rows_bwd)
+
+
 @jax.named_scope("moe_experts")
 def expert_ffn(h, weights, index, w_gate_up, w_down, expert_offset,
                num_experts):
@@ -279,20 +398,18 @@ def expert_ffn(h, weights, index, w_gate_up, w_down, expert_offset,
     Wd_e``. h [T, H]; weights/index [T, k] over ALL experts; w_gate_up
     [E_local, H, 2I] (gate columns first), w_down [E_local, I, H], the
     experts ``expert_offset .. expert_offset + E_local``. Dropless: every
-    pair routed to a held expert is computed, whatever the load."""
+    pair routed to a held expert is computed, whatever the load. A holder
+    of a share of the experts walks its pairs ``_held_rows`` rows a trip:
+    one trip, unless the routing gives its experts more."""
     t, k = index.shape
-    inter = w_down.shape[1]
-    # held experts first, so that their rows lead the sorted order
-    label = (index.reshape(-1) - expert_offset) % num_experts
-    order = jnp.argsort(label, stable=True)
-    group_sizes = jnp.zeros((num_experts,), jnp.int32).at[label].add(1)
-    rows = h[order // k]                                    # [T*k, H]
-    gate_up = _grouped(rows, w_gate_up, group_sizes, h.dtype)
-    act = jax.nn.silu(gate_up[:, :inter]) * gate_up[:, inter:]
-    out = _grouped(act, w_down, group_sizes, jnp.float32)
-    out = out * weights.reshape(-1)[order][:, None]
-    back = jnp.argsort(order)                               # pair -> row
-    return jnp.sum(out[back].reshape(t, k, -1), axis=1).astype(h.dtype)
+    order, group_sizes = _sort_pairs(index, expert_offset, num_experts)
+    rows = _held_rows(t * k, w_down.shape[0], num_experts)
+    if rows == t * k:
+        return _ffn_rows(rows, 0, order, group_sizes, h, weights, w_gate_up,
+                         w_down).astype(h.dtype)
+    _count_row_buffer()
+    return _ffn_held_rows(rows, order, group_sizes, h, weights, w_gate_up,
+                          w_down).astype(h.dtype)
 
 
 def decoder_layer(cfg, x, p, cache=None):

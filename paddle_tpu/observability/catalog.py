@@ -223,6 +223,12 @@ CATALOG = {
         "not counted) and pass (fwd; dx: the rows' cotangent; dw: the "
         "weights', tgmm); a forward-only trace ticks nothing",
         ("kernel", "pass")),
+    "moe.row_buffer": _m(
+        "counter", "expert layers that hold a share of the experts and "
+        "walk the held experts' pairs a buffer of rows a trip (rows=held: "
+        "their even share of the pairs x 1.5), at trace time; a layer that "
+        "holds every expert passes over all its pairs once and ticks "
+        "nothing", ("rows",)),
     # ------------------------------------------- state-space layers
     "ssm.scan_dispatch": _m(
         "counter", "prefill scans of a state-space mixer at trace time, "

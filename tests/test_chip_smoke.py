@@ -129,6 +129,24 @@ def test_decode_kernel_check_interpret():
                                        tol=0.0)
 
 
+EXPERT_ROWS_TINY = (1024, 32, 16, 8, 2, 2)
+
+
+def test_expert_rows_check_tiny():
+    errs = chip_smoke.check_expert_rows(EXPERT_ROWS_TINY, dtype="float32",
+                                        tol=1e-5)
+    assert max(errs) <= 1e-5
+
+
+def test_expert_rows_check_refuses_a_run_of_two_trips(monkeypatch):
+    """A buffer too small for the held pairs sends the layer on a second
+    trip: the result is right and the check still refuses it."""
+    from paddle_tpu.models import sdar_moe
+    monkeypatch.setattr(sdar_moe, "_HELD_ROWS_SLACK", 0.5)
+    with pytest.raises(AssertionError, match="is not what ran"):
+        chip_smoke.check_expert_rows(EXPERT_ROWS_TINY, dtype="float32")
+
+
 def test_kernel_phase_interpret():
     cases = (("simple", (1, 2, 128, 64), True, None),
              ("causal_skip", (1, 1, 256, 64), True, None),

@@ -214,6 +214,71 @@ def test_a_differentiated_kernel_trace_counts_its_three_passes():
     assert moved == {"fwd": 1, "dx": 1, "dw": 1}
 
 
+# ------------------------------------- the held experts' buffer (ISSUE 37)
+
+def _row_buffers(delta):
+    return {c["labels"]["rows"]: c["value"] for c in delta.changed()
+            if c["name"] == "moe.row_buffer"}
+
+
+@pytest.mark.parametrize("held, moved", [
+    (2, {"held": 1}), (8, {})], ids=["a_share", "every_expert"])
+def test_a_traced_layer_counts_the_buffer_it_built(held, moved):
+    """512 tokens x 2 of 8 experts: a holder of two walks its pairs a
+    buffer of 512 rows a trip; a holder of all eight passes over all 1,024
+    once and counts nothing."""
+    cfg = lm.LFM2MoeConfig.tiny(num_local_experts=held, expert_offset=0)
+    h = cfg.hidden_size
+    p = {"router": jnp.ones((h, 8)),
+         "gate_up": jnp.ones((held, h, 2 * cfg.moe_intermediate_size)),
+         "down": jnp.ones((held, cfg.moe_intermediate_size, h))}
+    with obs.window() as w:
+        jax.make_jaxpr(lambda u: lm.moe_ffn(u, p, jnp.zeros((8,)), cfg))(
+            jnp.ones((2, 256, h)))
+    assert _row_buffers(w.delta) == moved
+
+
+def test_a_step_that_overflows_the_buffer_runs_the_same_program():
+    """The engine's step (each layer under ``jax.checkpoint``, the state
+    donated) on 512 tokens with experts 3 and 4 of 8 held: a step that the
+    router spreads fits the buffer of 512 rows; with a bias that sends every
+    token to the two held experts each expert layer takes two trips, in the
+    program that was compiled, and its gradients are the reference's."""
+    cfg = lm.LFM2MoeConfig.tiny(num_layers=3, num_local_experts=2,
+                                expert_offset=3)
+    pcfg = gh.ParallelConfig(**F32)
+    ids = jnp.asarray(np.random.RandomState(2).randint(
+        0, cfg.vocab_size, (2, 256)))
+    with obs.window() as w:
+        mesh, params, opt_state, step = lm.setup(
+            cfg, pcfg, seed=1, devices=jax.devices()[:1])
+        params, opt_state, loss = step(params, opt_state, (ids, ids))
+    built = _row_buffers(w.delta)
+    assert built["held"] >= cfg.num_expert_layers
+    held_load = np.asarray(params["expert_load"])[:, 3:5].sum(axis=1)
+    assert (held_load <= 512).all()
+    crowd = jax.device_put(
+        jnp.zeros((cfg.num_expert_layers, 8)).at[:, 3:5].set(10.0),
+        params["expert_bias"].sharding)
+    crowded = dict(params, expert_bias=crowd)
+    want_loss, want, _own, _margin = arch.reference_loss_and_grads(
+        crowded, ids, cfg.num_heads, num_experts_per_tok=2, expert_offset=3)
+    train, frozen = lm.TRAIN_MODEL.split(crowded)
+    with mesh:
+        got_loss, got = jax.value_and_grad(
+            lambda t: lm.loss_fn({**t, **frozen}, (ids, ids), cfg, pcfg,
+                                 mesh))(train)
+    assert abs(float(got_loss) - float(want_loss)) < 1e-5
+    for g, w_ in zip(jax.tree_util.tree_leaves(got),
+                     jax.tree_util.tree_leaves(want)):
+        assert float(jnp.linalg.norm(g - w_)) \
+            <= 1e-4 * float(jnp.linalg.norm(w_)) + 1e-7
+    params, opt_state, loss = step(crowded, opt_state, (ids, ids))
+    assert np.isfinite(float(loss)) and step._cache_size() == 1
+    assert (np.asarray(params["expert_load"])[:, 3:5].sum(axis=1)
+            == held_load + 1024).all()
+
+
 # ------------------------------------------------------------ the bias rule
 
 def test_bias_rule_moves_each_bias_by_the_rate_towards_the_mean_load():

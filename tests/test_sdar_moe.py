@@ -2,9 +2,11 @@
 layer and the session's generation by diffusion over blocks, each held to
 the plain float32 reference that the benchmark keeps
 (``benchmarks/ledger/arch/sdar_moe.py``), at tiny sizes on the CPU."""
+import functools
 import importlib.util
 import os
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -234,6 +236,138 @@ def test_routing_is_softmax_top_k_renormalised():
     np.testing.assert_allclose(np.asarray(weights).sum(-1), 1.0, atol=1e-6)
     assert (np.asarray(weights)[:, 0] >= np.asarray(weights)[:, 1]).all()
     assert (np.asarray(index)[:, 0] != np.asarray(index)[:, 1]).all()
+
+
+# ------------------------- (e') the rows of the experts held (ISSUE 37)
+
+#: 512 tokens, 2 of 8 experts a token: 1,024 pairs; experts 3 and 4 held,
+#: whose buffer is 512 rows (their even share of 256 x 1.5, up to the row
+#: tile). A case: the slots that go to a held expert, and to which
+ROWS_TOKENS, ROWS_OFFSET, ROWS_HELD = 512, 3, 2
+ROWS_CASES = {
+    # a subset in the middle of the experts, routed as the router routes
+    "as_routed": None,
+    "an_empty_held_group": (300, (3,)),
+    "the_buffer_is_full": (512, (3, 4)),
+    "one_pair_over": (513, (3, 4)),             # a second trip
+    "every_pair_is_held": (1024, (3, 4)),       # dropless, for a subset
+}
+
+
+def _rows_inputs(case, hidden, inter):
+    h, weights, index, gate_up, down = _layer_inputs(
+        tokens=ROWS_TOKENS, hidden=hidden, inter=inter, seed=3)
+    if ROWS_CASES[case] is not None:
+        n, held = ROWS_CASES[case]
+        rng = np.random.RandomState(5)
+        flat = rng.choice([0, 1, 2, 5, 6, 7], 2 * ROWS_TOKENS)
+        # one held expert: every other slot, so no token has it twice
+        slots = np.arange(n) if len(held) == 2 else 2 * np.arange(n)
+        flat[slots] = np.asarray(held)[np.arange(n) % len(held)]
+        index = jnp.asarray(flat.reshape(ROWS_TOKENS, 2), jnp.int32)
+    share = slice(ROWS_OFFSET, ROWS_OFFSET + ROWS_HELD)
+    n_held = int(np.isin(np.asarray(index), [3, 4]).sum())
+    return (h, weights, gate_up[share], down[share]), index, n_held
+
+
+def _dense_share(index, offset, h, weights, gate_up, down):
+    """Every token through every held expert, weighted by the routing."""
+    held, inter = down.shape[:2]
+    gu = jnp.einsum("th,ehn->etn", h, gate_up)
+    y = jnp.einsum("eti,eih->eth",
+                   jax.nn.silu(gu[..., :inter]) * gu[..., inter:], down)
+    p = jnp.sum(weights[:, :, None] * (
+        index[:, :, None] == offset + jnp.arange(held)), axis=1)
+    return jnp.einsum("te,eth->th", p, y)
+
+
+def _value_and_grads(fn, operands, probe):
+    """-> (the layer's output, its gradients along ``probe``)."""
+    def loss(*ops):
+        y = fn(*ops)
+        return jnp.sum(y * probe), y
+    (_loss, y), grads = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1, 2, 3), has_aux=True))(*operands)
+    return y, grads
+
+
+def _assert_same(got, want, atol):
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-4, atol=atol)
+    for g, w in zip(got[1], want[1]):
+        np.testing.assert_allclose(g, w, rtol=1e-4, atol=atol)
+
+
+@pytest.mark.parametrize("case", sorted(ROWS_CASES))
+@pytest.mark.parametrize("path", ["ragged_dot", "kernels"])
+def test_a_share_of_the_experts_works_on_its_rows_and_drops_no_pair(
+        case, path, monkeypatch):
+    """Value and the gradients of h, weights, w_gate_up and w_down: the
+    layer (which walks the held experts' pairs a buffer of rows a trip)
+    against the dense reference and against one pass over all T x k rows,
+    and the trips' parts add up to that pass."""
+    hidden, inter = (16, 8) if path == "ragged_dot" else (128, 128)
+    if path == "kernels":
+        real = sdar_moe._grouped
+        monkeypatch.setattr(
+            sdar_moe, "_grouped",
+            lambda *a, **kw: real(*a, interpret=True, **kw))
+    operands, index, n_held = _rows_inputs(case, hidden, inter)
+    pairs = index.size
+    rows = sdar_moe._held_rows(pairs, ROWS_HELD, 8)
+    assert (rows, pairs) == (512, 1024)
+    probe = jnp.asarray(np.random.RandomState(7).randn(ROWS_TOKENS, hidden),
+                        jnp.float32)
+    atol = 1e-4 if path == "ragged_dot" else 2e-3
+
+    def layer(h, weights, gate_up, down):
+        return sdar_moe.expert_ffn(h, weights, index, gate_up, down,
+                                   ROWS_OFFSET, 8)
+
+    def form(m, first=0):
+        return functools.partial(
+            sdar_moe._ffn_rows, m, first,
+            *sdar_moe._sort_pairs(index, ROWS_OFFSET, 8))
+
+    want = _value_and_grads(
+        functools.partial(_dense_share, index, ROWS_OFFSET), operands, probe)
+    got = _value_and_grads(layer, operands, probe)
+    _assert_same(got, want, atol)
+    whole = _value_and_grads(form(pairs), operands, probe)
+    _assert_same(whole, want, atol)
+    trips = [_value_and_grads(form(rows, first), operands, probe)
+             for first in range(0, max(n_held, 1), rows)]
+    assert len(trips) == -(-max(n_held, 1) // rows)
+    if len(trips) > 1:  # the first trip alone would drop a pair
+        assert np.abs(np.asarray(trips[0][0] - whole[0])).max() > 1e-2
+    _assert_same(jax.tree_util.tree_map(lambda *parts: sum(parts), *trips),
+                 whole, atol)
+    if case == "every_pair_is_held":    # no row of the output is zero
+        assert np.abs(np.asarray(got[0])).min(axis=1).max() > 0
+    if case == "an_empty_held_group":   # and it gets no gradient
+        assert float(jnp.max(jnp.abs(got[1][2][1]))) == 0.0
+
+
+@pytest.mark.parametrize("case", ["as_routed", "one_pair_over"])
+def test_the_branch_differentiates_under_a_checkpoint(case):
+    """As ``lfm2_moe._remat`` wraps the layer: ``jax.checkpoint`` with a
+    policy that saves names only, then ``jax.grad``."""
+    operands, index, _n = _rows_inputs(case, 16, 8)
+    probe = jnp.ones((ROWS_TOKENS, 16), jnp.float32)
+    layer = jax.checkpoint(
+        lambda *ops: sdar_moe.expert_ffn(ops[0], ops[1], index, *ops[2:],
+                                         ROWS_OFFSET, 8),
+        policy=jax.checkpoint_policies.save_only_these_names("routing"))
+    _assert_same(
+        _value_and_grads(layer, operands, probe),
+        _value_and_grads(functools.partial(_dense_share, index, ROWS_OFFSET),
+                         operands, probe), 1e-4)
+
+
+def test_the_buffer_is_the_even_share_and_a_half_in_row_tiles():
+    assert sdar_moe._held_rows(65536, 16, 64) == 24576     # the train cell
+    assert sdar_moe._held_rows(1024, 2, 8) == 512          # 384, a tile up
+    assert sdar_moe._held_rows(48, 4, 8) == 48             # never over T x k
+    assert sdar_moe._held_rows(4096, 128, 128) == 4096     # every expert held
 
 
 # --------------------------------------------- (f) the default mode
