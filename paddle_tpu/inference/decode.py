@@ -130,8 +130,9 @@ class LatentCache(_ByPosition, collections.namedtuple(
     heads, only what they are expanded from. c: [B, C, kv_lora_rank], the
     normed latent of each position; k_rope: [B, C, qk_rope_head_dim], its
     rotated key, one for all heads; length: [B] int32. Written through
-    ``latent_write`` (``_write_kv``'s two buffers) and read by the model's
-    own attention, never through ``_cache_attention``'s kernel gate.
+    ``latent_write`` and read by the model's own attention (on a TPU its
+    own kernel, ``decode_attention.latent_decode_attention``), never
+    through ``_cache_attention``.
     ``take`` ([B] int32; None: all), as ``RecurrentCache``'s: around a
     prefill, the positions of the call that count in each lane, so that
     the model need not compute far past the prompt; of one call, not a
@@ -281,13 +282,27 @@ def _write_kv(kbuf, vbuf, kn, vn, lens):
     return put(kbuf, kn), put(vbuf, vn)
 
 
+@jax.named_scope("write_kv")
 def latent_write(cbuf, krbuf, c, k_rope, lens):
     """A latent entry's write, for a model's layer to call inside its own
-    op: c [B, s, R], k_rope [B, s, r] into the entry's buffers at each
-    sequence's length, cast to the buffers' dtype. Returns (cbuf',
-    krbuf')."""
-    return _write_kv(cbuf, krbuf, c.astype(cbuf.dtype),
-                     k_rope.astype(krbuf.dtype), lens)
+    op: c [B, s, R], k_rope [B, s, r] into the entry's buffers at offsets
+    ``clip(lens, 0, C - s)``, cast to the buffers' dtype. Returns (cbuf',
+    krbuf'). The update form on every backend (``_write_kv``'s reason: a
+    [B, C, W] buffer's positions lie in the two dims a TPU tiles). The rope
+    key goes through its [B, r, C] view, where the latent kernel reads it:
+    a TPU keeps a [B, C, 64] buffer with the positions in lanes (a 64-wide
+    minor dim would pad to 128), and a write in rows would have XLA copy
+    the whole buffer from one layout to the other at every step."""
+    if _met._ENABLED:
+        _met.REGISTRY.counter("cache.write_dispatch",
+                              kernel="update_slice").inc()
+    c, k_rope = c.astype(cbuf.dtype), k_rope.astype(krbuf.dtype)
+    kr_t, kr_new = jnp.swapaxes(krbuf, 1, 2), jnp.swapaxes(k_rope, 1, 2)
+    cbuf = jax.vmap(lambda b, n, l: lax.dynamic_update_slice(b, n, (l, 0)))(
+        cbuf, c, lens)
+    kr_t = jax.vmap(lambda b, n, l: lax.dynamic_update_slice(b, n, (0, l)))(
+        kr_t, kr_new, lens)
+    return cbuf, jnp.swapaxes(kr_t, 1, 2)
 
 
 def _attend_einsum(q, kbuf, vbuf, lens, block=None):

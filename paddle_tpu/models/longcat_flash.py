@@ -38,8 +38,12 @@ computes the ABSORBED form over the cache: ``q~ = q_nope W_UK^T`` (the
 ``k_nope`` columns of ``W_kvb``), ``score = q~ . c + q_rope . k_rope``,
 ``out = (sum p c) W_UV``; its products take their operands in the cache's
 dtype and accumulate in float32, and ``W_UK`` / ``W_UV`` are slices of
-``W_kvb`` taken in the call. The cache holds ``c`` and the rotated
-``k_rope`` of each position (``decode.LatentCache``).
+``W_kvb`` taken in the call. On a TPU the score, mask, softmax and ``p . c``
+are one Pallas program that reads each sequence's valid rows of the cache
+and keeps the scores on chip (``decode_attention.latent_decode_attention``;
+``attn.latent_dispatch`` counts the form a traced step took). The cache
+holds ``c`` and the rotated ``k_rope`` of each position
+(``decode.LatentCache``).
 
 The model is pure functions over a dict of its parameters
 (``forward_arrays``, ``double_layer``, ``mla``, ``moe``); the ``nn.Layer``s
@@ -51,6 +55,7 @@ contract (``ContinuousBatchingSession``): ``init_cache``,
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 
 import jax
@@ -198,6 +203,42 @@ def _expanded(cfg, p, q_nope, q_rope, c, k_rope):
          for first in range(0, s, _QUERY_CHUNK)], axis=1)
 
 
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+@jax.named_scope("latent_attention")    # a profile names the kernel after it
+def _latent_kernel(q_lat, q_rope, cbuf, krbuf, lens, scale, interpret):
+    """The kernel as a program of its own, traced once a shape and not
+    once a sublayer (as ``decode._decode_kernel``)."""
+    from paddle_tpu.ops.pallas.decode_attention import latent_decode_attention
+    return latent_decode_attention(q_lat, q_rope, cbuf, krbuf, lens,
+                                   scale=scale, interpret=interpret)
+
+
+def _attend_latent_kernel(q_lat, q_rope, cbuf, krbuf, valid, scale):
+    """The absorbed step's attention on a TPU: the length-aware kernel,
+    which reads each slot's valid blocks of the latent cache and keeps the
+    scores on chip. None on every other backend, or where its gate refuses
+    the shape (counted, as ``decode._attend_decode_kernel`` counts its
+    own); ``attn.latent_dispatch`` says which form took the call."""
+    from paddle_tpu.inference import decode
+    take = decode._kernel_backend()
+    if take:
+        from paddle_tpu.ops.pallas.decode_attention import latent_gate_reason
+        reason = latent_gate_reason(q_lat.shape, cbuf.shape, krbuf.shape[2],
+                                    cbuf.dtype)
+        take = reason is None
+        if not take and _met._ENABLED:
+            _met.REGISTRY.counter("attn.dispatch_fallback",
+                                  reason=reason).inc()
+    if _met._ENABLED:
+        _met.REGISTRY.counter("attn.latent_dispatch",
+                              kernel="ragged" if take else "einsum").inc()
+    if not take:
+        return None
+    return _latent_kernel(q_lat, q_rope[:, 0].astype(cbuf.dtype), cbuf,
+                          krbuf, valid - 1, scale,
+                          interpret=jax.default_backend() != "tpu")
+
+
 @jax.named_scope("mla_absorbed")
 def _absorbed(cfg, p, q_nope, q_rope, cbuf, krbuf, valid):
     """One position a sequence against its cached latents: q_nope [B, 1,
@@ -210,18 +251,21 @@ def _absorbed(cfg, p, q_nope, q_rope, cbuf, krbuf, valid):
     dt = cbuf.dtype
     q_lat = jnp.einsum("bhd,chd->bhc", q_nope[:, 0], w_uk,
                        preferred_element_type=jnp.float32).astype(dt)
-    score = jnp.einsum("bhc,btc->bht", q_lat, cbuf,
-                       preferred_element_type=jnp.float32) \
-        + jnp.einsum("bhr,btr->bht", q_rope[:, 0].astype(dt), krbuf,
-                     preferred_element_type=jnp.float32)
-    score = score / math.sqrt(dn + cfg.qk_rope_head_dim)
-    col = jnp.arange(cbuf.shape[1])
-    score = jnp.where(col[None, None, :] < valid[:, None, None], score,
-                      -1e30)
-    prob = jax.nn.softmax(score, axis=-1).astype(dt)
-    o_lat = jnp.einsum("bht,btc->bhc", prob, cbuf,
-                       preferred_element_type=jnp.float32).astype(w.dtype)
-    out = jnp.einsum("bhc,chd->bhd", o_lat, w_uv,
+    o_lat = _attend_latent_kernel(q_lat, q_rope, cbuf, krbuf, valid,
+                                  1.0 / math.sqrt(dn + cfg.qk_rope_head_dim))
+    if o_lat is None:
+        score = jnp.einsum("bhc,btc->bht", q_lat, cbuf,
+                           preferred_element_type=jnp.float32) \
+            + jnp.einsum("bhr,btr->bht", q_rope[:, 0].astype(dt), krbuf,
+                         preferred_element_type=jnp.float32)
+        score = score / math.sqrt(dn + cfg.qk_rope_head_dim)
+        col = jnp.arange(cbuf.shape[1])
+        score = jnp.where(col[None, None, :] < valid[:, None, None], score,
+                          -1e30)
+        prob = jax.nn.softmax(score, axis=-1).astype(dt)
+        o_lat = jnp.einsum("bht,btc->bhc", prob, cbuf,
+                           preferred_element_type=jnp.float32)
+    out = jnp.einsum("bhc,chd->bhd", o_lat.astype(w.dtype), w_uv,
                      preferred_element_type=jnp.float32)
     return out.astype(q_nope.dtype)[:, None]
 

@@ -268,6 +268,12 @@ CATALOG = {
                         "one-token step over the latent cache; "
                         "mla_expanded, a prefill)",
                         ("kernel",)),
+    "attn.latent_dispatch": _m(
+        "counter", "absorbed latent-attention steps at trace time, by the "
+        "form that took them (ragged: the Pallas kernel over each slot's "
+        "valid rows, scores on chip; einsum: XLA over the whole capacity, "
+        "every backend but the TPU and a shape the kernel's gate refuses)",
+        ("kernel",)),
     "attn.dispatch_fallback": _m(
         "counter", "shape-gate rejections falling back to XLA",
         ("reason",)),
@@ -343,6 +349,7 @@ SCOPES = {
     # models/longcat_flash.py (latent attention)
     "mla_expanded": "latent attention expanded to heads: a prefill",
     "mla_absorbed": "latent attention over the latent cache: one step",
+    "latent_attention": "the absorbed step's kernel (score, softmax, p . c)",
 }
 
 

@@ -37,6 +37,32 @@ A length at or past the capacity ``C`` reads all ``C`` positions and no
 more, as the XLA path's mask does: a serving session steps a lane up to a
 block past its budget before the host retires it.
 
+**Latent attention** (``latent_decode_attention``, ``_kernel_latent``):
+the one-token step of an MLA sublayer in its absorbed form
+(``models/longcat_flash._absorbed``) is multi-query attention with one
+latent head. Its cache is ``c`` ``[B, C, R]`` (the normed latent of each
+position) beside ``k_rope`` ``[B, C, r]`` (its rotated rope key), and ``c``
+is both the key, beside ``k_rope``, and the value. The same ring walks the
+valid blocks of every slot, its two refs ``c`` and ``k_rope``, the block
+from ``c``'s row bytes (``sizes``: 256 positions at 1 KiB a row).
+``k_rope`` is read where XLA keeps it: a 64-wide minor dim would pad to
+128 lanes, so the chip lays ``[B, C, 64]`` out with the positions in lanes;
+the kernel takes the ``[B, r, C]`` view (a bitcast, no copy) and copies a
+block's lanes (``_block_ring``'s ``v_last``). A block reads ``c`` once for
+both products, the queries as the rows: ``s = q_lat [H, R] . c^T + q_rope
+[H, r] . k_rope`` and ``acc += p . c``, the operands in the cache's dtype
+and the sums in float32 on the MXU; the scale (with ``log2(e)``), the mask
+and the online softmax in float32, ``p`` cast to the cache's dtype before
+its product; rows of ``c`` past the length are zeroed, so nothing stored
+there reaches the result. The scores stay in VMEM: nothing of size ``C`` is
+written to HBM. The output ``acc / l`` is in the cache's dtype (the
+``q_lat`` and output tiles of 48 slots x 64 heads x 512 take 6 MiB of the
+budget in bf16; in float32 they would not fit). ``latent_gate_reason``
+takes a call whose latent width is whole lanes (a multiple of 128), whose
+heads and rope width fill whole registers of the cache's dtype (8 rows in
+float32, 16 in bf16), whose blocks of rope keys are whole lanes (or the
+whole capacity), and whose tiles and ring fit ``_VMEM_BUDGET``.
+
 Block and chunk come from the cache's shape and itemsize (``sizes``).
 ``interpret=True`` runs the same kernel through the Pallas interpreter
 (tests/test_decode_attention_kernel.py).
@@ -98,10 +124,12 @@ def gate_reason(q_shape, cache_shape, cache_dtype):
     return None if need <= _VMEM_BUDGET else "vmem"
 
 
-def _block_ring(lens_ref, k_hbm, v_hbm, kbuf, vbuf, sems, nslots, block):
-    """What both kernels walk the cache with: (held, nblocks, copies, ring,
+def _block_ring(lens_ref, k_hbm, v_hbm, kbuf, vbuf, sems, nslots, block,
+                v_last=False):
+    """What the kernels walk the cache with: (held, nblocks, copies, ring,
     fetch, primed) over the valid blocks of all slots, ``primed`` the
-    cursor after the first ``_DEPTH - 1`` copies have been started."""
+    cursor after the first ``_DEPTH - 1`` copies have been started.
+    ``v_last``: ``v_hbm``'s positions lie along its last dim."""
     capacity = k_hbm.shape[1]
 
     def held(b):
@@ -114,9 +142,10 @@ def _block_ring(lens_ref, k_hbm, v_hbm, kbuf, vbuf, sems, nslots, block):
 
     def copies(b, j, buf):
         rows = pl.ds(pl.multiple_of(j * block, block), block)
+        v_rows = v_hbm.at[b, :, rows] if v_last else v_hbm.at[b, rows]
         return (pltpu.make_async_copy(k_hbm.at[b, rows], kbuf.at[buf],
                                       sems.at[0, buf]),
-                pltpu.make_async_copy(v_hbm.at[b, rows], vbuf.at[buf],
+                pltpu.make_async_copy(v_rows, vbuf.at[buf],
                                       sems.at[1, buf]))
 
     def ring(buf):
@@ -258,6 +287,110 @@ def _kernel_shared(lens_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems,
         return cursor, buf
 
     lax.fori_loop(0, nslots, slot_body, (primed(), jnp.int32(0)))
+
+
+def latent_gate_reason(q_shape, cache_shape, rope_dim, cache_dtype):
+    """None where ``latent_decode_attention`` takes the call, else the
+    label of the ``attn.dispatch_fallback`` counter. q_lat: [B, H, R];
+    the latent cache: [B, C, R], beside a rope key of ``rope_dim``."""
+    b, c, width = cache_shape
+    heads = q_shape[1]
+    itemsize = jnp.dtype(cache_dtype).itemsize
+    if width % 128 != 0:
+        return "latent_width"       # a position's latent must fill lanes
+    rows = 32 // itemsize           # the sublanes of a register
+    if heads % rows != 0:
+        return "heads"              # the queries fill whole registers
+    if rope_dim % rows != 0:
+        return "rope_dim"           # so do the rope keys, a block's rows
+    block, _ = sizes(c, 1, width, itemsize)
+    if block % 128 != 0 and block != c:
+        return "capacity"           # a block of rope keys fills lanes
+    # q_lat and the output, q_rope (its lanes padded), the two rings
+    need = (2 * b * heads * width + b * heads * -(-rope_dim // 128) * 128
+            + _DEPTH * block * (width + rope_dim)) * itemsize
+    return None if need <= _VMEM_BUDGET else "vmem"
+
+
+def _kernel_latent(lens_ref, ql_ref, qr_ref, c_hbm, kr_hbm, o_ref, cbuf,
+                   krbuf, sems, *, block, scale):
+    """One latent head: ql_ref/o_ref [B, H, R], qr_ref [B, H, r], c_hbm
+    [B, C, R], kr_hbm [B, r, C] (the positions in lanes), the ring
+    [_DEPTH, block, R] and [_DEPTH, r, block]."""
+    nslots, rows, width = ql_ref.shape
+    held, nblocks, copies, ring, fetch, primed = _block_ring(
+        lens_ref, c_hbm, kr_hbm, cbuf, krbuf, sems, nslots, block,
+        v_last=True)
+    f32 = dict(preferred_element_type=jnp.float32)
+
+    col = lax.broadcasted_iota(jnp.int32, (rows, block), 1)
+    row = lax.broadcasted_iota(jnp.int32, (block, width), 0)
+
+    def slot_body(b, carry):
+        n = held(b)
+        ql, qr = ql_ref[b], qr_ref[b]
+
+        def block_body(j, carry):
+            cursor, buf, (m, l, acc) = carry
+            cursor = fetch(cursor)      # into the buffer freed a block ago
+            for cp in copies(b, j, buf):
+                cp.wait()
+            here = n - j * block        # positions of the block that count
+            # nothing stored past the length reaches the result
+            c = jnp.where(row < here, cbuf[buf], 0)
+            s = (lax.dot_general(ql, c, (((1,), (1,)), ((), ())), **f32)
+                 + jnp.dot(qr, krbuf[buf], **f32)) * scale
+            s = jnp.where(col < here, s, NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp2(m - m_new)
+            p = jnp.exp2(s - m_new)
+            l = alpha * l + jnp.sum(p, axis=1, keepdims=True)
+            acc = alpha * acc + jnp.dot(p.astype(c.dtype), c, **f32)
+            return cursor, ring(buf), (m_new, l, acc)
+
+        init = (jnp.full((rows, 1), NEG_INF, jnp.float32),
+                jnp.zeros((rows, 1), jnp.float32),
+                jnp.zeros((rows, width), jnp.float32))
+        cursor, buf, (_m, l, acc) = lax.fori_loop(0, nblocks(b), block_body,
+                                                  (*carry, init))
+        o_ref[b] = (acc / l).astype(o_ref.dtype)
+        return cursor, buf
+
+    lax.fori_loop(0, nslots, slot_body, (primed(), jnp.int32(0)))
+
+
+def latent_decode_attention(q_lat, q_rope, cbuf, krbuf, lens, *, scale,
+                            block=None, interpret=False):
+    """The absorbed one-token step of latent attention. q_lat: [B, H, R]
+    (``q_nope W_UK^T``), q_rope: [B, H, r], both in the cache's dtype;
+    cbuf: [B, C, R], krbuf: [B, C, r], the new position's rows already
+    written at ``min(lens, C - 1)``; lens: [B] lengths before the write.
+    Returns [B, H, R] in the cache's dtype: softmax((q_lat . c + q_rope .
+    k_rope) * scale) . c over positions ``0 .. min(lens, C - 1)``."""
+    b, h, width = q_lat.shape
+    c, r = cbuf.shape[1], krbuf.shape[2]
+    block = block or sizes(c, 1, width, cbuf.dtype.itemsize)[0]
+    if c % block:
+        raise ValueError(f"latent_decode_attention: capacity {c} and "
+                         f"block {block} must divide")
+    return pl.pallas_call(
+        functools.partial(_kernel_latent, block=block,
+                          scale=scale * math.log2(math.e)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM),
+                      pl.BlockSpec(memory_space=pltpu.VMEM),
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+            scratch_shapes=[pltpu.VMEM((_DEPTH, block, width), cbuf.dtype),
+                            pltpu.VMEM((_DEPTH, r, block), krbuf.dtype),
+                            pltpu.SemaphoreType.DMA((2, _DEPTH))]),
+        out_shape=jax.ShapeDtypeStruct((b, h, width), cbuf.dtype),
+        interpret=interpret,
+    )(lens.astype(jnp.int32), q_lat.astype(cbuf.dtype),
+      q_rope.astype(cbuf.dtype), cbuf, jnp.swapaxes(krbuf, 1, 2))
 
 
 def _decode_shared(q, kbuf, vbuf, lens, block, interpret):

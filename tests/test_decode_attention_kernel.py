@@ -3,8 +3,8 @@ against ``_cache_attention``'s einsum path, in float32 through the Pallas
 interpreter: ragged lengths around the block edges, lanes shown at length 0,
 poisoned positions past the valid length, the dispatch and its shape gate,
 and the kernel compiled for the v5e at the serving cells' cache shapes (the
-cache write's program and the expert layer beside it: one process describes
-the chip)."""
+latent attention kernel, the cache write's program and the expert layer
+beside it: one process describes the chip)."""
 import functools
 
 import jax
@@ -197,6 +197,28 @@ def test_kernel_compiles_for_the_v5e(one_chip, no_compile_cache, b, c, hkv,
         spec((b, 1, hkv * g, 128), jnp.bfloat16), cache, cache,
         spec((b,), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("b, c", [(48, 8192), (4, 512)],
+                         ids=["longcat", "reference_check"])
+def test_latent_kernel_compiles_for_the_v5e(one_chip, no_compile_cache, b,
+                                            c):
+    """The absorbed latent step's kernel at LongCat's widths (64 heads,
+    latent 512, rope 64, bf16), in the cell and in its check before the
+    window: the rope keys are read where the chip keeps them (positions in
+    lanes: the transposed view is a bitcast, with no copy of the cache) and
+    nothing of size C is written beside the result."""
+    def spec(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    assert da.latent_gate_reason((b, 64, 512), (b, c, 512), 64,
+                                 jnp.bfloat16) is None
+    compiled = jax.jit(functools.partial(
+        da.latent_decode_attention, scale=192 ** -0.5)).lower(
+        spec((b, 64, 512)), spec((b, 64, 64)), spec((b, c, 512)),
+        spec((b, c, 64)), spec((b,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and " copy(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
 @pytest.mark.parametrize("b, c, hkv, s, dtype", [

@@ -2,7 +2,8 @@
 its two forms over a latent cache, the router over routed and zero-compute
 experts, and the session, each held to the plain float32 reference that the
 benchmark keeps (``benchmarks/ledger/arch/longcat_flash.py``), at tiny
-sizes on the CPU, float32 on both sides."""
+sizes on the CPU, float32 on both sides (the latent kernel's check also
+in bf16)."""
 import importlib.util
 import os
 
@@ -347,16 +348,37 @@ def test_the_decode_program_counts_the_expert_load(tiny):
                                  + 3 * 4)
 
 
-def test_the_latent_cache_is_written_by_update_and_asks_no_kernel_gate(
-        tiny, monkeypatch):
+#: cache dtype: (heads that fill a register of it, tolerance on the decode
+#: steps' logits, kernel against einsums, over their largest value).
+#: float32: the order of the online softmax's sums (read 1.5e-6). bf16:
+#: ``p`` rounded before its normalisation where the einsums round it after,
+#: through two double layers' later products (read 7.7e-3; the kernel
+#: without its rope product reads 1.3)
+KERNEL_CACHES = {"float32": (8, 1e-5), "bfloat16": (16, 2.0 ** -6)}
+
+
+@pytest.mark.parametrize("cache_dtype", KERNEL_CACHES)
+def test_the_latent_cache_is_written_by_update_and_read_by_its_kernel(
+        monkeypatch, cache_dtype):
     """On a TPU (here: the backend predicate turned on) a latent entry's
-    rows are written through ``_write_kv``'s update form, never its
-    row-DMA program (a [B, C, W] buffer's positions lie in the two dims a
-    TPU tiles, where a copy cannot start at any row), while a KV entry
-    still takes the row-DMA; the decode attention kernel's gate is never
-    asked, so nothing falls back; the tokens are those of the CPU's
-    path."""
-    model, _ = tiny
+    rows are written in the update form (``latent_write``), never through
+    ``_write_kv``'s row-DMA program (a [B, C, W] buffer's positions lie in
+    the two dims a TPU tiles, where a copy cannot start at any row), while
+    a KV entry still takes the row-DMA; the absorbed step reads the cache through the
+    latent kernel (a latent width of 128 and heads that fill a register:
+    the kernel's gate takes the shape) in every call, the decode attention
+    kernel's gate is never asked and nothing falls back. The decode steps'
+    logits are the CPU einsum path's within the cache dtype's tolerance,
+    and in float32 a session's tokens are too (in bf16 a near tie may
+    round either way)."""
+    heads, tol = KERNEL_CACHES[cache_dtype]
+    cfg = LongCatFlashConfig.tiny(kv_lora_rank=128, qk_rope_head_dim=64,
+                                  num_attention_heads=heads,
+                                  initializer_range=0.3,
+                                  cache_dtype=cache_dtype)
+    paddle.seed(1)
+    model = LongCatFlashForCausalLM(cfg)
+    model.eval()
     prompts = [_ids(7, 50), _ids(12, 51)]
 
     def generate():
@@ -370,11 +392,28 @@ def test_the_latent_cache_is_written_by_update_and_asks_no_kernel_gate(
                  for c in w.delta.changed() if c["type"] == "counter"}
         return [out[r].tolist() for r in rids], moved
 
+    def step_logits():
+        """A prefill of 13 positions, then 4 steps through the cache."""
+        ids = _ids(17, 52, batch=2)
+        caches = model.init_cache(2, max_length=32)
+        _, caches = model.forward_with_cache(paddle.to_tensor(ids[:, :13]),
+                                             caches)
+        got = []
+        for t in range(13, 17):
+            logits, caches = model.forward_with_cache(
+                paddle.to_tensor(ids[:, t:t + 1]), caches)
+            got.append(logits.numpy())
+        return np.concatenate(got, 1)
+
     obs.enable()
-    want, _ = generate()
+    want, moved = generate()
+    want_logits = step_logits()
+    assert ("attn.latent_dispatch", "ragged") not in moved
+    assert moved[("attn.latent_dispatch", "einsum")] >= 1
     monkeypatch.setattr(decode, "_kernel_backend", lambda: True)
     got, moved = generate()
-    assert got == want
+    if cache_dtype == "float32":
+        assert got == want
     assert moved[("cache.write_dispatch", "update_slice")] >= 1
     assert ("cache.write_dispatch", "row_dma") not in moved
     kv = decode.init_static_cache(2, 32, 2, 128)
@@ -383,9 +422,13 @@ def test_the_latent_cache_is_written_by_update_and_asks_no_kernel_gate(
         == (2, 32, 2, 128)
     assert obs.counter("cache.write_dispatch", kernel="row_dma").value >= 1
     assert not any(name == "attn.dispatch_fallback" for name, _ in moved)
-    assert ("attn.dispatch", "decode_ragged") not in moved
-    assert moved[("attn.dispatch", "latent_absorbed")] >= 1
-    assert moved[("attn.dispatch", "mla_expanded")] >= 1
+    assert moved[("attn.latent_dispatch", "ragged")] >= 1
+    assert ("attn.latent_dispatch", "einsum") not in moved
+    assert {k for name, k in moved if name == "attn.dispatch"} \
+        == {"latent_absorbed", "mla_expanded"}
+    got_logits = step_logits()
+    assert np.abs(got_logits - want_logits).max() \
+        <= tol * np.abs(want_logits).max()
 
 
 def test_named_scopes_reach_the_lowered_decode_program(tiny):
